@@ -62,7 +62,7 @@ class TransientError(RuntimeError):
 
 
 class DeviceBusyError(TransientError):
-    """The accelerator (or its tunnel) refused/was busy — the transient
+    """The accelerator refused/was busy — the transient
     device-fault class chaos injects at worker.execute / worker.device."""
 
 

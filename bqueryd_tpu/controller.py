@@ -2749,9 +2749,14 @@ class ControllerNode:
         allowed.update(
             info.get("data_dir") for info in self.worker_map.values()
         )
-        cache_path = controller_section["compile_cache"].get("path")
-        if cache_path:
-            allowed.add(cache_path)
+        # the compile cache belongs to the jax-owning processes: each
+        # worker's slice reports where ITS cache resolved (a JAX-free
+        # controller has none of its own)
+        for section in [controller_section] + [
+            (absorbed.get("data") or {})
+            for absorbed in self._worker_debug.values()
+        ]:
+            allowed.add((section.get("compile_cache") or {}).get("path"))
         return obs.build_bundle(
             controller_section,
             snapshots,

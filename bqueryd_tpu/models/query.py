@@ -248,9 +248,8 @@ _measured_floor = None
 
 def device_dispatch_floor(remeasure=False):
     """Measured wall of one trivial jitted dispatch + host fetch on the
-    default backend (min of 3, cached per process).  On a remote/tunneled
-    device this is tens of ms of pure transport; on local hardware,
-    microseconds.  The fetch is included because the device query path ends
+    default backend (min of 3, cached per process): microseconds to a
+    fraction of a millisecond on a local chip, more behind a remote one.  The fetch is included because the device query path ends
     in a ``device_get`` — that is the cost host routing competes against.
 
     A measurement taken while another thread holds the backend (e.g. the
@@ -390,10 +389,10 @@ def host_kernel_rows(ns_per_row=None):
     """Row threshold below which mergeable aggregations run on the HOST
     (:func:`ops.host_partial_tables`) instead of paying a device round-trip.
 
-    Latency-aware routing: when the device sits behind a network tunnel the
-    dispatch+fetch floor dwarfs the kernel for small inputs, so the host is
-    strictly faster; on local chips the measured floor is microseconds and
-    the threshold collapses to ~10k rows.  ``ns_per_row`` lets the caller
+    Latency-aware routing: for small inputs the dispatch+fetch floor dwarfs
+    the kernel, so the host is strictly faster; the threshold is derived
+    from the MEASURED floor (a local chip's microseconds collapse it to
+    ~10k rows).  ``ns_per_row`` lets the caller
     pass a per-query cost estimate (:func:`_host_ns_estimate`); default is
     the fast-path rate.  Override with BQUERYD_TPU_HOST_KERNEL_ROWS
     (0 disables host routing)."""

@@ -130,8 +130,8 @@ class WorkingSet:
     def evict_under_pressure(self, sample=None, watermark=None):
         """Shed LRU device-segment entries while HBM usage sits above the
         watermark.  ``sample`` is a ``{"bytes_in_use", "bytes_limit", ...}``
-        dict (default: the live profiler sample; None — CPU backends,
-        unproven tunnels — is a no-op).  Returns bytes freed (accounted
+        dict (default: the live profiler sample; None — CPU backends, a
+        backend no kernel call has proven alive yet — is a no-op).  Returns bytes freed (accounted
         cache bytes, a proxy for the HBM the dropped references release at
         the allocator's next sweep).
 

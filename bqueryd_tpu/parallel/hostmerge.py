@@ -1,7 +1,7 @@
 """Host-side (NumPy-only, JAX-free) merge of value-keyed result payloads.
 
 This is the cross-worker half of the merge architecture: within a worker,
-shard partials merge on-device over the ICI mesh (``ops.psum_partials``);
+shard partials merge on-device over the ICI mesh (``parallel.devicemerge``);
 across workers — the DCN boundary — payloads carry actual key values, and this
 module aligns and combines them on the host.  It deliberately imports no JAX
 so the client and controller processes stay accelerator-free.

@@ -21,31 +21,65 @@ _has_groupby = False
 _has_groupby_minmax = False
 
 
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_REPO = os.path.dirname(os.path.dirname(_HERE))
+#: where native/build.sh puts the library of a checkout
+_IN_TREE_LIB = os.path.join(_REPO, "native", "build", "libtpucolz.so")
+
+
 def _candidate_paths():
     env = os.environ.get("BQUERYD_TPU_NATIVE_LIB")
     if env:
         yield env
-    here = os.path.dirname(os.path.abspath(__file__))
-    repo = os.path.dirname(os.path.dirname(here))
-    yield os.path.join(repo, "native", "build", "libtpucolz.so")
-    yield os.path.join(here, "libtpucolz.so")
+    yield _IN_TREE_LIB
+    yield os.path.join(_HERE, "libtpucolz.so")
 
 
-def _try_build():
-    """Attempt a one-shot build of the native lib (g++ is in the base image)."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    repo = os.path.dirname(os.path.dirname(here))
-    script = os.path.join(repo, "native", "build.sh")
+def build(check=False):
+    """Build ``native/build/libtpucolz.so`` from ``native/tpucolz.cpp`` with
+    ``native/build.sh`` (g++, or cmake+ninja when present).  Returns True
+    when the build ran and succeeded.  A failed build is logged with the
+    compiler's last lines — the library is optional at runtime (pure-Python
+    codec fallback) — unless ``check`` is set, which raises instead
+    (``chip_smoke.py``: what runs on the chip is built from what git
+    commits, or the run fails)."""
+    script = os.path.join(_REPO, "native", "build.sh")
     if not os.path.exists(script):
-        return
+        if check:
+            raise FileNotFoundError(script)
+        return False
     import subprocess
 
     try:
         subprocess.run(
-            ["/bin/sh", script], capture_output=True, timeout=120, check=True
+            ["/bin/sh", script], capture_output=True, timeout=300, check=True
         )
-    except Exception:
-        pass
+    except (subprocess.SubprocessError, OSError) as exc:
+        if check:
+            raise
+        import logging
+
+        logging.getLogger("bqueryd_tpu").warning(
+            "native codec build failed (%s): %s — using the pure-Python "
+            "codec", exc,
+            (getattr(exc, "stderr", b"") or b"").decode(errors="replace")[
+                -400:
+            ],
+        )
+        return False
+    return True
+
+
+def _in_tree_lib_stale():
+    """True when the in-tree library is missing or older than its source:
+    a ``.so`` left lying in the tree (ignored by git, copied along with a
+    checkout) must not outlive an edit to ``tpucolz.cpp``."""
+    source = os.path.join(_REPO, "native", "tpucolz.cpp")
+    if not os.path.exists(source):
+        return False  # installed without the source: nothing to build
+    if not os.path.exists(_IN_TREE_LIB):
+        return True
+    return os.path.getmtime(source) > os.path.getmtime(_IN_TREE_LIB)
 
 
 def get_lib():
@@ -55,8 +89,8 @@ def get_lib():
         return _lib
     _searched = True
     paths = list(_candidate_paths())
-    if not any(os.path.exists(p) for p in paths):
-        _try_build()
+    if not os.environ.get("BQUERYD_TPU_NATIVE_LIB") and _in_tree_lib_stale():
+        build()
     for path in paths:
         if not os.path.exists(path):
             continue

@@ -431,9 +431,10 @@ def shard_tables(tmp_path):
 
     df = taxi_like_df()
     tables = []
-    for i, part in enumerate(np.array_split(df, 3)):
+    bounds = np.linspace(0, len(df), 4).astype(int)
+    for i, (lo, hi) in enumerate(zip(bounds[:-1], bounds[1:])):
         root = str(tmp_path / f"t{i}.bcolzs")
-        ctable.fromdataframe(part.reset_index(drop=True), root)
+        ctable.fromdataframe(df.iloc[lo:hi].reset_index(drop=True), root)
         tables.append(ctable(root, mode="r"))
     return tables
 

@@ -427,6 +427,43 @@ def test_memory_watchdog_shed_recovery_keeps_running(
     worker.socket.close()
 
 
+def test_memory_watchdog_excludes_the_accelerator_runtime(
+    tmp_path, mem_store_url, monkeypatch
+):
+    """A process that has initialised the TPU backend shows ~13.5 GB of RSS
+    before serving a row (device mappings + premapped staging buffers; seen
+    on the v5e, where the 2048 MB default stopped the worker after its
+    first query).  The limit bounds what the WORKER holds above that."""
+    import psutil
+
+    from bqueryd_tpu.worker import WorkerNode
+
+    worker = WorkerNode(
+        coordination_url=mem_store_url,
+        data_dir=str(tmp_path),
+        loglevel=logging.WARNING,
+        restart_check=True,
+        memory_limit_mb=2048,
+    )
+    rss = {"mb": 14_611}
+    monkeypatch.setattr(
+        psutil.Process, "memory_info",
+        lambda self: type("m", (), {"rss": int(rss["mb"] * 1e6)})(),
+    )
+    monkeypatch.setattr(worker, "_shed_caches", lambda: rss["mb"])
+    worker.running = True
+    worker._check_mem()
+    assert worker.running is False, "raw RSS over the limit stops it"
+    worker._runtime_rss_mb = 13_557.0   # measured around backend init
+    worker.running = True
+    worker._check_mem()
+    assert worker.running is True, "1054 MB of its own is under the limit"
+    rss["mb"] = 13_557 + 2_500
+    worker._check_mem()
+    assert worker.running is False, "2500 MB of its own is not"
+    worker.socket.close()
+
+
 def test_two_controllers_both_get_heartbeats_during_long_work(
     tmp_path, mem_store_url
 ):

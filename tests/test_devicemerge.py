@@ -1,7 +1,7 @@
 """Device-resident distributed merge over the ICI mesh (ISSUE 7).
 
 Covers the span partitioner and bucketized partial emission, parity of the
-span-owned reduce-scatter merge against the ``BQUERYD_TPU_DEVICE_MERGE=0``
+span-owned collective merge against the ``BQUERYD_TPU_DEVICE_MERGE=0``
 hostmerge fallback across the fuzz-shaped dtype mix (limb-straddling int64,
 narrow-wire min/max, float32 mean, float64 sum), the kill switch actually
 routing through ``hostmerge.merge_payloads``, the D2H byte accounting, the
@@ -139,8 +139,8 @@ def _assert_mode_parity(dev, host, query):
             # integer aggregates (the north-star axis) and keys: bit-exact
             np.testing.assert_array_equal(a, b)
         else:
-            # float sums reassociate across the reduce-scatter vs the host
-            # merge's sequential fold: equal to reassociation ulps
+            # float sums may reassociate between the device merge and the
+            # host merge's sequential fold: equal to reassociation ulps
             np.testing.assert_allclose(
                 np.asarray(a, dtype=np.float64),
                 np.asarray(b, dtype=np.float64), rtol=1e-9, equal_nan=True,
@@ -249,6 +249,42 @@ def test_device_merge_per_leaf_fetch(merge_shards, monkeypatch):
     for col in packed.columns:
         np.testing.assert_array_equal(
             packed[col].to_numpy(), unpacked[col].to_numpy()
+        )
+
+
+def test_packed_fetch_f64_as_f32_pair(merge_shards, monkeypatch):
+    """On a TPU a float64 leaf has no bytes to cast (the compiler refuses
+    ``bitcast-convert`` on f64), so it crosses the packed fetch as its
+    float32 (hi, lo) split.  Forced on here: the packed table must agree
+    with the per-leaf fetch to the pair's precision — float64 sums, a
+    float mean, and the +-inf extrema fills of groups a filter emptied."""
+    from bqueryd_tpu.parallel import executor as ex_mod
+
+    _df, tables = merge_shards
+    query = GroupByQuery(
+        ["g"],
+        [["f64", "sum", "s"], ["f64", "min", "lo"], ["f64", "max", "hi"],
+         ["f32", "mean", "m"], ["big", "sum", "b"]],
+        [["sel", ">", 0.97]],
+    )
+    monkeypatch.setenv("BQUERYD_TPU_PACKED_FETCH", "0")
+    per_leaf = _run_mode(tables, query, True, monkeypatch)
+    monkeypatch.setenv("BQUERYD_TPU_PACKED_FETCH", "1")
+    monkeypatch.setattr(ex_mod, "_f64_rides_as_f32_pair", lambda: True)
+    # the encoding is traced into the program: no stale bitcast variant
+    ex_mod._mesh_program.cache_clear()
+    try:
+        packed = _run_mode(tables, query, True, monkeypatch)
+    finally:
+        ex_mod._mesh_program.cache_clear()
+    assert len(packed) == len(per_leaf) > 0
+    np.testing.assert_array_equal(
+        packed["b"].to_numpy(), per_leaf["b"].to_numpy()
+    )
+    for col in ("s", "lo", "hi", "m"):
+        np.testing.assert_allclose(
+            packed[col].to_numpy(), per_leaf[col].to_numpy(),
+            rtol=2.0**-45, equal_nan=True,
         )
 
 

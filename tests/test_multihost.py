@@ -54,15 +54,6 @@ def _free_port():
 
 def test_two_process_mesh_psum_merge(tmp_path):
     # bounded by the communicate(timeout=240) below
-    import jax
-
-    if not hasattr(jax, "shard_map"):
-        # pre-0.6 jax: XLA:CPU rejects cross-process computations outright
-        # ("Multiprocess computations aren't implemented on the CPU
-        # backend"), so the two-process simulation cannot run — the
-        # multi-host path is still exercised single-process by
-        # test_mesh_executor on the 8-device virtual mesh
-        pytest.skip("multiprocess CPU collectives unsupported on this jax")
     from bqueryd_tpu.storage.ctable import ctable
 
     rng = np.random.default_rng(9)

@@ -224,14 +224,30 @@ def test_program_registry_evicts_least_recently_called(monkeypatch):
         profile._reset_for_tests()
 
 
-def test_compile_cache_info_follows_env(monkeypatch, tmp_path):
-    monkeypatch.setenv("BQUERYD_TPU_COMPILE_CACHE", str(tmp_path))
-    info = profile.compile_cache_info()
-    assert info["enabled"] is True
-    assert info["path"] == str(tmp_path)
-    assert info["writable"] is True
-    monkeypatch.setenv("BQUERYD_TPU_COMPILE_CACHE", "0")
-    assert profile.compile_cache_info()["enabled"] is False
+def test_compile_cache_info_reads_jax_config(tmp_path):
+    """compile_cache_info() reports what jax.config HOLDS (ops/__init__.py
+    placed it at import; tests/conftest.py pins the cache off) — it does
+    not re-derive the decision from the environment."""
+    import jax
+
+    from bqueryd_tpu import ops  # noqa: F401  (places the cache config)
+
+    assert profile.compile_cache_info() == {
+        "enabled": False, "path": None, "writable": False,
+    }
+    saved = (
+        jax.config.jax_compilation_cache_dir,
+        jax.config.jax_enable_compilation_cache,
+    )
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_enable_compilation_cache", True)
+        assert profile.compile_cache_info() == {
+            "enabled": True, "path": str(tmp_path), "writable": True,
+        }
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_enable_compilation_cache", saved[1])
 
 
 def test_runtime_versions_reports_jax():

@@ -147,22 +147,23 @@ def test_pallas_kernel_matches_xla_path(op, g, monkeypatch):
 
 
 def test_pallas_high_cardinality_tile_shrinks(monkeypatch):
-    """Above 8192 groups the one-hot tile must shrink to _MIN_TILE instead of
-    overflowing the VMEM budget (the round-3 hole: _tile_k bottomed at 256,
-    so raising BQUERYD_TPU_MATMUL_GROUPS past ~8k overflowed ~4 MB)."""
+    """Towards the route's group ceiling the one-hot tile must shrink to
+    _MIN_TILE instead of overflowing the VMEM budget — and no further:
+    Mosaic refuses a 1-D codes load off the 1024-element tile grid (seen on
+    the v5e), so the floor is that tile and the ceiling follows from it."""
     import jax
 
     from bqueryd_tpu import ops
     from bqueryd_tpu.ops import pallas_groupby as pg
 
-    g = 12_289  # > the old 8k ceiling, <= pallas_groups_limit()
+    g = 1_700  # pads to 1792 lanes: the budget no longer fits a 2048 tile
     assert g <= pg.pallas_groups_limit()
-    tile = pg._tile_k(g)
-    assert tile == pg._MIN_TILE
+    tile = pg._tile_k(-(-g // 128) * 128)
+    assert tile == pg._MIN_TILE == pg._CODES_TILE
     assert tile * g <= pg._ONEHOT_BUDGET
     assert pg.BLOCK_K % tile == 0
+    assert pg._hicard_kt() % pg._CODES_TILE == 0
 
-    monkeypatch.setenv("BQUERYD_TPU_MATMUL_GROUPS", "16384")
     rng = np.random.RandomState(3)
     n = pg.BLOCK_K  # one grid block keeps interpret mode fast
     codes = rng.randint(-1, g, n).astype(np.int32)
