@@ -143,11 +143,8 @@ ENV_REGISTRY = {
         _v("S3_ENDPOINT", "str", "-",
            "S3 endpoint override (localstack testing)"),
         _v("BLOB_DIR", "path", "-", "local-dir blob backend root (testing)"),
-        _v("PROFILE", "flag", "0", "jax.profiler span annotations",
-           related=("PROFILE_DIR",)),
-        _v("PROFILE_DIR", "path", "-",
-           "capture a TensorBoard trace around each query",
-           related=("PROFILE",)),
+        _v("PROFILE", "flag", "0",
+           "jax.profiler annotations + detail spans (traced runs only)"),
         _v("DIST_COORDINATOR", "str", "-",
            "host:port to join a multi-host JAX job"),
         _v("DIST_NPROCS", "int", "auto",

@@ -19,6 +19,7 @@ against two declared truths:
 
 Span sites are: ``<x>.phase("name")`` / ``<x>._phase("name")`` /
 ``<x>.span("name")`` (PhaseTimer / SpanRecorder context managers),
+``<x>.detail("name")`` (``utils.tracing.detail``),
 ``make_span(trace_id, "name", ...)`` (second positional), and
 ``SpanRecorder(root_name="name")``.  Non-literal names are fine — they can
 only re-emit already-declared names (the generic passthroughs in
@@ -31,7 +32,7 @@ import ast
 from bqueryd_tpu.analysis.core import Finding, module_literal
 
 #: method names whose first literal argument opens a span/phase
-_PHASE_ATTRS = ("phase", "_phase", "span")
+_PHASE_ATTRS = ("phase", "_phase", "span", "detail")
 
 
 def _literal_dict(tree, name):

@@ -1992,6 +1992,10 @@ class ControllerNode:
             merge_mode = msg.get("merge_mode")
             if isinstance(merge_mode, str):
                 segment.setdefault("merge", {})[key] = merge_mode
+            compiled = msg.get("compiled")
+            if isinstance(compiled, int):
+                # the compile mark (absent from a steady-state reply)
+                segment["compiled"] = segment.get("compiled", 0) + compiled
             # worker-side spans (calc root + phases) fold into the timeline;
             # shared dispatches land on every subscriber's segment
             spans = msg.get("spans")
@@ -2092,6 +2096,10 @@ class ControllerNode:
             merge_mode = msg.get("merge_mode")
             if isinstance(merge_mode, str):
                 segment.setdefault("merge", {})[key] = merge_mode
+            compiled = msg.get("compiled")
+            if isinstance(compiled, int):
+                # the compile mark (absent from a steady-state reply)
+                segment["compiled"] = segment.get("compiled", 0) + compiled
             spans = msg.get("spans")
             if isinstance(spans, list) and segment.get("obs"):
                 obs_state = segment["obs"]
@@ -2175,31 +2183,31 @@ class ControllerNode:
         else:
             answer_source = "recompute"
         self._count_answer(answer_source)
-        reply = pickle.dumps(
-            {
-                "ok": True,
-                "payloads": payloads,
-                "timings": timings,
-                # PR-16 provenance: how this answer was produced, and (for
-                # subsumption serves) which materialized view proved it
-                "answer_source": answer_source,
-                "subsumed_from": None,
-                # planner visibility end to end: the hints issued and the
-                # routes the workers actually compiled post-guards (bench's
-                # chosen_strategy / regret accounting read these)
-                "strategies": {
-                    "hints": dict(segment.get("strategies", {})),
-                    "effective": self._compact_timings(
-                        segment.get("effective")
-                    ),
-                },
-                # per shard-group: how the worker merged the payload
-                # (device = ICI-mesh collective, host = hostmerge fallback,
-                # none = single payload)
-                "merge_modes": self._compact_timings(segment.get("merge")),
+        envelope = {
+            "ok": True,
+            "payloads": payloads,
+            "timings": timings,
+            # PR-16 provenance: how this answer was produced, and (for
+            # subsumption serves) which materialized view proved it
+            "answer_source": answer_source,
+            "subsumed_from": None,
+            # planner visibility end to end: the hints issued and the
+            # routes the workers actually compiled post-guards (bench's
+            # chosen_strategy / regret accounting read these)
+            "strategies": {
+                "hints": dict(segment.get("strategies", {})),
+                "effective": self._compact_timings(
+                    segment.get("effective")
+                ),
             },
-            protocol=4,
-        )
+            # per shard-group: how the worker merged the payload
+            # (device = ICI-mesh collective, host = hostmerge fallback,
+            # none = single payload)
+            "merge_modes": self._compact_timings(segment.get("merge")),
+        }
+        if segment.get("compiled"):
+            envelope["compiled"] = segment["compiled"]
+        reply = pickle.dumps(envelope, protocol=4)
         self._finish_segment(parent, segment, reply)
 
     def _finish_segment(self, parent, segment, reply_bytes=None, error=None):

@@ -37,6 +37,11 @@ without them interoperate):
   ``serialize``, ``hostmerge``, ...); the synthetic whole-call wall lives
   under the underscore-namespaced ``_total`` key precisely so it can never
   collide with (and silently overwrite) a real phase named ``total``.
+  A worker running with ``BQUERYD_TPU_PROFILE=1`` adds ONE key,
+  ``post_prev``: the seconds of reply send + Done + gc + RSS check that
+  followed the unit(s) replied to since its last calc reply.  It is no
+  phase of this reply's wall: whatever sums the phases against ``_total``
+  skips it, as it skips ``_total``.
 * on WorkerRegisterMessages (all optional; controllers ignore what they
   don't know): ``backend_wedged`` (bool, the device-health latch),
   ``work_errors`` (cumulative error-counter total — the controller's
@@ -141,6 +146,12 @@ ENVELOPE_SCHEMA = {
                           "maintained refresh: only appended chunks "
                           "re-aggregated, merged into the cached result) — "
                           "hints may normalize",
+    "compiled": "on calc replies, ONLY when the unit compiled: how many "
+                "instrumented jitted calls compiled a new program (or "
+                "loaded it from the persistent cache) inside it — the "
+                "compile registry's jit_cache_misses delta.  Absent from "
+                "a steady-state reply; the controller sums it into the "
+                "client result envelope (rpc.last_call_compiled)",
     "merge_mode": "how the reply's partials merged: 'device' (ICI-mesh "
                   "collective, final table only fetched — classic groupbys "
                   "since PR 7, batched extended-DAG dispatches since "
@@ -232,6 +243,9 @@ RESULT_ENVELOPE_SCHEMA = {
                   "shard-group->executed kernel route}",
     "merge_modes": "shard-group -> merge_mode the worker reported "
                    "(device/host/none; see the merge_mode envelope key)",
+    "compiled": "programs the query's workers compiled for it (sum of the "
+                "calc replies' compiled key); key absent when none did — "
+                "rpc.last_call_compiled reads 0 then",
     "error": "failure reason when ok is False",
     "error_class": "structured failure class when ok is False (e.g. "
                    "'DispatchExhausted' once the retry/failover budget is "
@@ -338,6 +352,42 @@ SPAN_SCHEMA = {
     "collect": "raw name of merge (device-path materialization)",
     "hostmerge": "raw name of merge (host value-keyed merge)",
     "serialize": "raw name of reply_serialization",
+    # DETAIL names (utils.tracing.detail): exist only on a worker running
+    # with BQUERYD_TPU_PROFILE=1, as a jax.profiler annotation and — where
+    # marked "span" — a span nested by its interval in the phase named.
+    # Never a phase_timings key.
+    "parse": "detail span in calc: envelope args, plan fragment / DAG "
+             "round trip, up to the first table open",
+    "cache_probe": "detail span in calc: result-cache key + get and the "
+                   "delta key before the execution; the delta cache's own "
+                   "look-up (_serve_delta); cache.put + delta_cache.store "
+                   "after serialize",
+    "table_keys": "detail span in calc, between the executor's prune and "
+                  "align: the identity of every table of the unit (a stat "
+                  "and a realpath each) for the executor's cache keys",
+    "mem_sample": "detail span in calc: each device-memory sample around "
+                  "the execution (with note_devices)",
+    "layout_fold": "detail span in h2d_transfer: the row mask folded into "
+                   "the codes (or the codes narrowed) on the host",
+    "layout_pack": "detail span in h2d_transfer: _pack of codes, stacked "
+                   "masks or an inline-built measure column",
+    "layout_h2d": "detail span in h2d_transfer: a host->device placement "
+                  "(returns at once; a name for the device trace)",
+    "layout_columns": "detail span in h2d_transfer: decode + narrow of a "
+                      "missing measure column on the loop thread, or its "
+                      "wait for one built on the pool",
+    "aggregate_launch": "detail span in kernel: the program call until it "
+                        "returns (jit look-up, enqueue; a compile when "
+                        "there is one)",
+    "aggregate_wait": "detail span in kernel: block_until_ready — the "
+                      "device's time as the host waits for it; tagged "
+                      "effective_strategy",
+    "send": "detail annotation: the reply's send; its seconds ride the "
+            "next calc reply's phase_timings['post_prev']",
+    "post": "detail annotation: Done + throttled gc.collect() + RSS check "
+            "after the reply; seconds in post_prev like send",
+    "wait_for_work": "detail annotation: the worker loop's poller.poll",
+    "heartbeat": "detail annotation: the worker loop's heartbeat check",
 }
 
 

@@ -75,6 +75,25 @@ SPAN_CATEGORIES = {
     "d2h_fetch": "d2h_fetch",
     "merge": "collective_merge",
     "reply_serialization": "reply_serialization",
+    # detail spans (utils.tracing.detail; BQUERYD_TPU_PROFILE=1 workers
+    # only) keep their own name as segment and outrank the phase they nest
+    # in, so the sweep still sums to the wall
+    "parse": "parse",
+    "cache_probe": "cache_probe",
+    "mem_sample": "mem_sample",
+    "table_keys": "table_keys",
+    "layout_fold": "layout_fold",
+    "layout_pack": "layout_pack",
+    "layout_h2d": "layout_h2d",
+    "layout_columns": "layout_columns",
+    "aggregate_launch": "aggregate_launch",
+    "aggregate_wait": "aggregate_wait",
+    # annotation-only detail names (after the reply / the worker's loop):
+    # on no timeline, declared for the lint
+    "send": "worker_other",
+    "post": "worker_other",
+    "wait_for_work": "worker_other",
+    "heartbeat": "worker_other",
 }
 
 #: segments synthesized by attribution (or the client) without a recorded
@@ -94,8 +113,14 @@ SYNTHETIC_SEGMENTS = (
 #: everything (its exclusive residue is what ``unattributed`` reports).
 SEGMENT_PRIORITY = (
     "d2h_fetch",
+    "aggregate_launch",
+    "aggregate_wait",
     "kernel",
     "collective_merge",
+    "layout_fold",
+    "layout_pack",
+    "layout_h2d",
+    "layout_columns",
     "h2d_transfer",
     "filter",
     "join_probe",
@@ -103,6 +128,10 @@ SEGMENT_PRIORITY = (
     "align",
     "storage_decode",
     "reply_serialization",
+    "parse",
+    "cache_probe",
+    "mem_sample",
+    "table_keys",
     "worker_other",
     "bundle_demux",
     "retry_backoff",

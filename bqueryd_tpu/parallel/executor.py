@@ -57,7 +57,7 @@ import time
 import numpy as np
 
 from bqueryd_tpu.models.query import GroupByQuery, ResultPayload
-from bqueryd_tpu.utils import devicehealth
+from bqueryd_tpu.utils import devicehealth, tracing
 
 
 def make_mesh(n_devices=None, axis_name="shards"):
@@ -553,7 +553,8 @@ class MeshQueryExecutor:
 
         from bqueryd_tpu.parallel import pipeline
 
-        tables_key = tuple(_table_key(t) for t in tables)
+        with tracing.detail("table_keys", self.timer):
+            tables_key = tuple(_table_key(t) for t in tables)
         cols_key = tuple(query.groupby_cols)
         mesh = self.mesh
         n_dev = mesh.devices.size
@@ -674,21 +675,27 @@ class MeshQueryExecutor:
                 # Folds into fresh arrays — cached dense stays unmasked.
                 with pipeline.stage("align"):
                     cdt = _codes_dtype(n_groups)
-                    folded = [
-                        np.where(mask, d, -1).astype(cdt)
-                        if mask is not None
-                        else d.astype(cdt)
-                        for d, mask in zip(dense, masks)
-                    ]
-                    packed = self._pack(
-                        folded, n_dev, cdt.type(-1), dtype=cdt
-                    )
-                with pipeline.stage("h2d"):
+                    with tracing.detail("layout_fold", self.timer):
+                        folded = [
+                            np.where(mask, d, -1).astype(cdt)
+                            if mask is not None
+                            else d.astype(cdt)
+                            for d, mask in zip(dense, masks)
+                        ]
+                    with tracing.detail("layout_pack", self.timer):
+                        packed = self._pack(
+                            folded, n_dev, cdt.type(-1), dtype=cdt
+                        )
+                with pipeline.stage("h2d"), tracing.detail(
+                    "layout_h2d", self.timer
+                ):
                     codes_d = _put(packed, sharding)
                 self._codes_cache.put(codes_key, codes_d)
 
         with self._phase("layout"):
-            def build_packed(col):
+            def build_packed(col, timer=None):
+                # ``timer``: given by the loop thread's inline call alone —
+                # a build on the pool records no detail span
                 # wait for this column's prefetched decodes first: they
                 # populate the storage cache, and racing a duplicate decode
                 # here would burn the cores the pipeline is trying to share
@@ -697,14 +704,18 @@ class MeshQueryExecutor:
                 with pipeline.stage("decode"):
                     # decode (C++ chunk threads, GIL released) + narrow +
                     # pack into the [n_dev, width] device layout
-                    wire = (
-                        _wire_dtype(tables, col)
-                        or _stored_dtype(tables, col)
-                    )
-                    cols = [np.asarray(t.column_raw(col)) for t in tables]
-                    if wire is not None:
-                        cols = [c.astype(wire, copy=False) for c in cols]
-                    return self._pack(cols, n_dev, 0, dtype=wire)
+                    with tracing.detail("layout_columns", timer):
+                        wire = (
+                            _wire_dtype(tables, col)
+                            or _stored_dtype(tables, col)
+                        )
+                        cols = [
+                            np.asarray(t.column_raw(col)) for t in tables
+                        ]
+                        if wire is not None:
+                            cols = [c.astype(wire, copy=False) for c in cols]
+                    with tracing.detail("layout_pack", timer):
+                        return self._pack(cols, n_dev, 0, dtype=wire)
 
             # cold path with several columns: overlap the NEXT column's
             # decode+pack with the CURRENT column's host->device transfer
@@ -737,11 +748,14 @@ class MeshQueryExecutor:
                 arr = self._hbm_cache.get(mkey)
                 if arr is None:
                     if col in futures:
-                        packed = futures.pop(col).result()
+                        with tracing.detail("layout_columns", self.timer):
+                            packed = futures.pop(col).result()
                         submit_next()
                     else:
-                        packed = build_packed(col)
-                    with pipeline.stage("h2d"):
+                        packed = build_packed(col, self.timer)
+                    with pipeline.stage("h2d"), tracing.detail(
+                        "layout_h2d", self.timer
+                    ):
                         arr = _put(packed, sharding)
                     self._hbm_cache.put(mkey, arr)
                 measures_d.append(arr)
@@ -975,7 +989,8 @@ class MeshQueryExecutor:
 
         from bqueryd_tpu.parallel import devicemerge, pipeline
 
-        tables_key = tuple(_table_key(t) for t in tables)
+        with tracing.detail("table_keys", self.timer):
+            tables_key = tuple(_table_key(t) for t in tables)
         cols_key = tuple(gcols)
         mesh = self.mesh
         n_dev = mesh.devices.size
@@ -1046,11 +1061,15 @@ class MeshQueryExecutor:
             with self._phase("layout"):
                 with pipeline.stage("align"):
                     cdt = _codes_dtype(n_groups)
-                    packed = self._pack(
-                        [d.astype(cdt) for d in dense], n_dev,
-                        cdt.type(-1), dtype=cdt,
-                    )
-                with pipeline.stage("h2d"):
+                    with tracing.detail("layout_fold", self.timer):
+                        folded = [d.astype(cdt) for d in dense]
+                    with tracing.detail("layout_pack", self.timer):
+                        packed = self._pack(
+                            folded, n_dev, cdt.type(-1), dtype=cdt,
+                        )
+                with pipeline.stage("h2d"), tracing.detail(
+                    "layout_h2d", self.timer
+                ):
                     codes_d = _put(packed, sharding)
                 self._codes_cache.put(codes_key, codes_d)
 
@@ -1071,30 +1090,36 @@ class MeshQueryExecutor:
                         if mask is None else np.asarray(mask)
                     )
                 mask_idx_of[qi] = len(mask_rows)
-                mask_rows.append(
-                    self._pack(shard_masks, n_dev, False, dtype=np.bool_)
-                )
+                with tracing.detail("layout_pack", self.timer):
+                    mask_rows.append(
+                        self._pack(shard_masks, n_dev, False, dtype=np.bool_)
+                    )
         masks_d = None
         if mask_rows:
             with self._phase("layout"), pipeline.stage("h2d"):
-                masks_d = _put(
-                    np.stack(mask_rows),
-                    NamedSharding(mesh, P(None, self.axis_name, None)),
-                )
+                with tracing.detail("layout_h2d", self.timer):
+                    masks_d = _put(
+                        np.stack(mask_rows),
+                        NamedSharding(mesh, P(None, self.axis_name, None)),
+                    )
 
         with self._phase("layout"):
-            def build_packed(col):
+            def build_packed(col, timer=None):
                 for fut in prefetch.get(col, ()):
                     fut.result()
                 with pipeline.stage("decode"):
-                    wire = (
-                        _wire_dtype(tables, col)
-                        or _stored_dtype(tables, col)
-                    )
-                    cols = [np.asarray(t.column_raw(col)) for t in tables]
-                    if wire is not None:
-                        cols = [c.astype(wire, copy=False) for c in cols]
-                    return self._pack(cols, n_dev, 0, dtype=wire)
+                    with tracing.detail("layout_columns", timer):
+                        wire = (
+                            _wire_dtype(tables, col)
+                            or _stored_dtype(tables, col)
+                        )
+                        cols = [
+                            np.asarray(t.column_raw(col)) for t in tables
+                        ]
+                        if wire is not None:
+                            cols = [c.astype(wire, copy=False) for c in cols]
+                    with tracing.detail("layout_pack", timer):
+                        return self._pack(cols, n_dev, 0, dtype=wire)
 
             missing = [
                 col
@@ -1118,11 +1143,14 @@ class MeshQueryExecutor:
                 arr = self._hbm_cache.get(mkey)
                 if arr is None:
                     if col in futures:
-                        packed = futures.pop(col).result()
+                        with tracing.detail("layout_columns", self.timer):
+                            packed = futures.pop(col).result()
                         submit_next()
                     else:
-                        packed = build_packed(col)
-                    with pipeline.stage("h2d"):
+                        packed = build_packed(col, self.timer)
+                    with pipeline.stage("h2d"), tracing.detail(
+                        "layout_h2d", self.timer
+                    ):
                         arr = _put(packed, sharding)
                     self._hbm_cache.put(mkey, arr)
                 measures_d.append(arr)
@@ -1331,7 +1359,8 @@ class MeshQueryExecutor:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        tables_key = tuple(_table_key(t) for t in tables)
+        with tracing.detail("table_keys", self.timer):
+            tables_key = tuple(_table_key(t) for t in tables)
         derive_sig = dag.derive_signature()
         mesh = self.mesh
         n_dev = mesh.devices.size
@@ -1438,11 +1467,15 @@ class MeshQueryExecutor:
             with self._phase("layout"):
                 with pipeline.stage("align"):
                     cdt = _codes_dtype(n_groups)
-                    packed = self._pack(
-                        [d.astype(cdt) for d in dense], n_dev,
-                        cdt.type(-1), dtype=cdt,
-                    )
-                with pipeline.stage("h2d"):
+                    with tracing.detail("layout_fold", self.timer):
+                        folded = [d.astype(cdt) for d in dense]
+                    with tracing.detail("layout_pack", self.timer):
+                        packed = self._pack(
+                            folded, n_dev, cdt.type(-1), dtype=cdt,
+                        )
+                with pipeline.stage("h2d"), tracing.detail(
+                    "layout_h2d", self.timer
+                ):
                     codes_d = _put(packed, sharding)
                 self._codes_cache.put(codes_key, codes_d)
 
@@ -1454,21 +1487,29 @@ class MeshQueryExecutor:
                     arr = self._hbm_cache.get(mkey)
                     if arr is None:
                         with pipeline.stage("decode"):
-                            wire = (
-                                _wire_dtype(tables, col)
-                                or _stored_dtype(tables, col)
-                            )
-                            cols = [
-                                np.asarray(t.column_raw(col))
-                                for t in tables
-                            ]
-                            if wire is not None:
+                            with tracing.detail(
+                                "layout_columns", self.timer
+                            ):
+                                wire = (
+                                    _wire_dtype(tables, col)
+                                    or _stored_dtype(tables, col)
+                                )
                                 cols = [
-                                    c.astype(wire, copy=False)
-                                    for c in cols
+                                    np.asarray(t.column_raw(col))
+                                    for t in tables
                                 ]
-                            packed = self._pack(cols, n_dev, 0, dtype=wire)
-                        with pipeline.stage("h2d"):
+                                if wire is not None:
+                                    cols = [
+                                        c.astype(wire, copy=False)
+                                        for c in cols
+                                    ]
+                            with tracing.detail("layout_pack", self.timer):
+                                packed = self._pack(
+                                    cols, n_dev, 0, dtype=wire
+                                )
+                        with pipeline.stage("h2d"), tracing.detail(
+                            "layout_h2d", self.timer
+                        ):
                             arr = _put(packed, sharding)
                         self._hbm_cache.put(mkey, arr)
                 else:
@@ -1476,19 +1517,25 @@ class MeshQueryExecutor:
                     arr = self._hbm_cache.get(mkey)
                     if arr is None:
                         with pipeline.stage("decode"):
-                            vals = []
-                            for entry in get_derived():
-                                _m, _pk, row_pos, window_ints = entry
-                                if col_source(col) == "window":
-                                    vals.append(np.asarray(window_ints))
-                                else:
-                                    vals.append(
-                                        opexec.gathered_dim_values(
-                                            dag.join.table[col], row_pos
+                            with tracing.detail(
+                                "layout_columns", self.timer
+                            ):
+                                vals = []
+                                for entry in get_derived():
+                                    _m, _pk, row_pos, window_ints = entry
+                                    if col_source(col) == "window":
+                                        vals.append(np.asarray(window_ints))
+                                    else:
+                                        vals.append(
+                                            opexec.gathered_dim_values(
+                                                dag.join.table[col], row_pos
+                                            )
                                         )
-                                    )
-                            packed = self._pack(vals, n_dev, 0)
-                        with pipeline.stage("h2d"):
+                            with tracing.detail("layout_pack", self.timer):
+                                packed = self._pack(vals, n_dev, 0)
+                        with pipeline.stage("h2d"), tracing.detail(
+                            "layout_h2d", self.timer
+                        ):
                             arr = _put(packed, sharding)
                         self._hbm_cache.put(mkey, arr)
                 slot_of[col] = len(measures_d)
@@ -2053,8 +2100,10 @@ def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what):
         try:
             program, spec = run(True)
             with _collective_guard():
-                out = call(program)
-                jax.block_until_ready(out)
+                with tracing.detail("aggregate_launch", timer):
+                    out = call(program)
+                with tracing.detail("aggregate_wait", timer):
+                    jax.block_until_ready(out)
                 with _fetch_phase(timer):
                     flat = np.asarray(jax.device_get(out))
         except Exception as exc:
@@ -2096,8 +2145,10 @@ def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what):
             return finish(merged, flat.nbytes)
     program, _spec = run(False)
     with _collective_guard():
-        out = call(program)
-        jax.block_until_ready(out)
+        with tracing.detail("aggregate_launch", timer):
+            out = call(program)
+        with tracing.detail("aggregate_wait", timer):
+            jax.block_until_ready(out)
         with _fetch_phase(timer):
             result = jax.device_get(out)
     if latch_pending:

@@ -94,6 +94,9 @@ class RPC:
         #: ("device" = ICI-mesh collective merge, "host" = hostmerge
         #: fallback, "none" = single payload) — how the answer was merged
         self.last_call_merge_modes = None
+        #: programs the workers compiled for the most recent groupby (the
+        #: calc replies' ``compiled`` mark, summed); 0 = steady state
+        self.last_call_compiled = None
         #: answer provenance of the most recent groupby reply (PR 16):
         #: "recompute" | "cached" | "delta" | "rollup" | "subsume" — and,
         #: for subsumption serves, the materialized view that proved it.
@@ -324,6 +327,7 @@ class RPC:
         self.last_call_timings = envelope.get("timings")
         self.last_call_strategies = envelope.get("strategies")
         self.last_call_merge_modes = envelope.get("merge_modes")
+        self.last_call_compiled = envelope.get("compiled", 0)
         self.last_call_answer_source = envelope.get("answer_source")
         self.last_call_subsumed_from = envelope.get("subsumed_from")
         if self.legacy_merge:

@@ -5,7 +5,16 @@ The reference's only timing surface is a per-call wall clock on the client
 latency to its phases — storage decode, host→device transfer, kernel, and
 collective merge — so workers attach a :class:`PhaseTimer` to every calc result
 (surfaced in the reply under ``phase_timings``; schema documented in
-:mod:`bqueryd_tpu.messages`) and expose an opt-in ``jax.profiler`` trace hook.
+:mod:`bqueryd_tpu.messages`).
+
+Two levels.  The coarse phases are always on (:meth:`PhaseTimer.phase`).
+:func:`detail` names what happens INSIDE and BETWEEN them, and exists only
+under ``BQUERYD_TPU_PROFILE=1`` (the traced run's switch, the one
+:func:`trace_span` obeys): a ``jax.profiler.TraceAnnotation`` on the device
+trace's clock plus, where the timer carries a SpanRecorder, a span on the
+``rpc.trace()`` timeline — never a ``phase_timings`` key, a debit or a
+histogram, so every number read with the switch off reads the same with it
+on.  With the switch unset a detail site costs one shared no-op context.
 
 A PhaseTimer may carry an :class:`bqueryd_tpu.obs.trace.SpanRecorder`: each
 phase then also records a distributed-tracing span (wall-clock start +
@@ -84,6 +93,27 @@ class PhaseTimer:
         return out
 
 
+def _annotation(name, args):
+    """A ``jax.profiler.TraceAnnotation`` on the calling thread, or None
+    where JAX cannot be imported.  Without a ``trace_id`` among ``args``
+    the active distributed TraceContext (obs.trace contextvar) gives its
+    own, so device profiler timelines line up with the RPC waterfall."""
+    try:
+        import jax.profiler
+    except ImportError:
+        return None
+    if "trace_id" not in args:
+        try:
+            from bqueryd_tpu.obs.trace import current_trace
+
+            ctx = current_trace()
+            if ctx is not None:
+                args["trace_id"] = ctx.trace_id
+        except Exception:
+            pass
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
 @contextlib.contextmanager
 def trace_span(name):
     """A ``jax.profiler.TraceAnnotation`` span when JAX is importable and
@@ -94,21 +124,7 @@ def trace_span(name):
     line up with the RPC trace waterfall."""
     annotation = None
     if os.environ.get("BQUERYD_TPU_PROFILE") == "1":
-        try:
-            import jax.profiler
-        except ImportError:
-            pass
-        else:
-            kwargs = {}
-            try:
-                from bqueryd_tpu.obs.trace import current_trace
-
-                ctx = current_trace()
-                if ctx is not None:
-                    kwargs["trace_id"] = ctx.trace_id
-            except Exception:
-                pass
-            annotation = jax.profiler.TraceAnnotation(name, **kwargs)
+        annotation = _annotation(name, {})
     if annotation is not None:
         with annotation:
             yield
@@ -116,14 +132,37 @@ def trace_span(name):
         yield
 
 
-@contextlib.contextmanager
-def profiler_trace(log_dir):
-    """Capture a full ``jax.profiler`` trace (TensorBoard format) around a
-    block — the TPU-side replacement for eyeballing ``last_call_duration``."""
-    import jax.profiler
+#: what every detail site gets with the switch unset: ONE object, entered
+#: and left, nothing else
+_NO_DETAIL = contextlib.nullcontext()
 
-    jax.profiler.start_trace(log_dir)
+
+def detail(name, timer=None, **args):
+    """A DETAIL span: exists only under BQUERYD_TPU_PROFILE=1 (read per
+    call), else the shared no-op.  Under the switch it opens a
+    ``jax.profiler.TraceAnnotation`` on the calling thread (``args`` ride
+    it; the request's trace id is added) and, when ``timer`` carries a
+    SpanRecorder, records ONE span — wall-clock start, ``perf_counter``
+    duration, parent = the recorder's root like every phase span; it shows
+    as nested by its interval.  It never touches
+    ``timer.timings``, a debit or a histogram.  Loop thread only: a
+    recorder's span list is not locked."""
+    if os.environ.get("BQUERYD_TPU_PROFILE") != "1":
+        return _NO_DETAIL
+    return _detail(name, getattr(timer, "recorder", None), args)
+
+
+@contextlib.contextmanager
+def _detail(name, recorder, args):
+    args = {k: v for k, v in args.items() if v is not None}
+    if recorder is not None and recorder.trace_id:
+        args.setdefault("trace_id", recorder.trace_id)
+    annotation = _annotation(name, args) or _NO_DETAIL
+    start_ts = time.time()
+    t0 = time.perf_counter()
     try:
-        yield
+        with annotation:
+            yield
     finally:
-        jax.profiler.stop_trace()
+        if recorder is not None:
+            recorder.record(name, start_ts, time.perf_counter() - t0)

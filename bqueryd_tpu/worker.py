@@ -49,6 +49,7 @@ from bqueryd_tpu.messages import (
     WorkerRegisterMessage,
     msg_factory,
 )
+from bqueryd_tpu.utils import tracing
 from bqueryd_tpu.utils.net import get_my_ip
 from bqueryd_tpu.utils.tracing import PhaseTimer
 
@@ -149,6 +150,15 @@ class WorkerBase:
         # its tail rides WRMs so the controller can assemble a cross-node
         # artifact even after this worker dies
         self.flight = obs.FlightRecorder(node_id=self.worker_id)
+        # where the ``send`` / ``post`` detail spans of handle() land: they
+        # follow the reply, so their seconds ride the NEXT calc reply
+        # (phase_timings["post_prev"]), which empties it.  Stays empty
+        # unless BQUERYD_TPU_PROFILE=1
+        self._after_reply = PhaseTimer(
+            recorder=obs.SpanRecorder(
+                trace_id=None, node=self.worker_id, root_name="post"
+            )
+        )
         self.metrics.gauge(
             "bqueryd_tpu_flight_evictions",
             "flight-ring events evicted by the entry/byte bounds (monotonic)",
@@ -206,8 +216,12 @@ class WorkerBase:
         self._start_heartbeat_thread()
         while self.running:
             try:
-                self.heartbeat()
-                events = dict(self.poller.poll(int(self.poll_timeout * 1000)))
+                with tracing.detail("heartbeat"):
+                    self.heartbeat()
+                with tracing.detail("wait_for_work"):
+                    events = dict(
+                        self.poller.poll(int(self.poll_timeout * 1000))
+                    )
                 if self.socket in events:
                     self.handle_in()
             except zmq.ZMQError:
@@ -674,7 +688,16 @@ class WorkerBase:
         if msg.isa("info"):
             self.send(sender, self.prepare_wrm())
             return
-        self.handle(msg, sender)
+        # detail (BQUERYD_TPU_PROFILE=1 only): the whole unit of work, Busy
+        # ack to the end of gc / the RSS check, on the device trace.
+        # ``wall_ts`` is the clock of every span's ``start_ts``, so this one
+        # event maps the profiler's clock onto the spans'
+        with tracing.detail(
+            "calc",
+            trace_id=(msg.get_trace() or {}).get("trace_id"),
+            wall_ts=time.time(),
+        ):
+            self.handle(msg, sender)
 
     def _set_loglevel(self, msg):
         import logging
@@ -796,22 +819,24 @@ class WorkerBase:
                 result = None
         if result is not None:
             try:
-                self.send(sender, result)
+                with tracing.detail("send", self._after_reply):
+                    self.send(sender, result)
             except zmq.ZMQError:
                 self.logger.exception("could not send result to %r", sender)
-        self.send_to_all(DoneMessage({"worker_id": self.worker_id}))
-        # The reference collects after EVERY task (reference
-        # bqueryd/worker.py:226) — necessary for its per-query bcolz
-        # allocations, but here steady-state serving is cache-resident and a
-        # full gen-2 collect walks those caches: ~17 ms per query at 10 M
-        # rows, a measured ~20% of the fixed per-query cost.  Throttle to
-        # one collect per interval; the RSS watchdog (_check_mem) remains
-        # the backstop between collects.
-        now = time.time()
-        if now - self._last_gc >= self.gc_interval:
-            self._last_gc = now
-            gc.collect()
-        self._check_mem()
+        with tracing.detail("post", self._after_reply):
+            self.send_to_all(DoneMessage({"worker_id": self.worker_id}))
+            # The reference collects after EVERY task (reference
+            # bqueryd/worker.py:226) — necessary for its per-query bcolz
+            # allocations, but here steady-state serving is cache-resident
+            # and a full gen-2 collect walks those caches: ~17 ms per query
+            # at 10 M rows, a measured ~20% of the fixed per-query cost.
+            # Throttle to one collect per interval; the RSS watchdog
+            # (_check_mem) remains the backstop between collects.
+            now = time.time()
+            if now - self._last_gc >= self.gc_interval:
+                self._last_gc = now
+                gc.collect()
+            self._check_mem()
 
     def _chaos_die(self):
         """die_after_ack: simulate a hard crash after accepting work — the
@@ -1321,8 +1346,9 @@ class WorkerNode(WorkerBase):
         from bqueryd_tpu.models.query import ResultPayload
         from bqueryd_tpu.parallel import hostmerge
 
-        key = self._delta_key(tables, query)
-        entry = cache.get(key)
+        with tracing.detail("cache_probe", timer):
+            key = self._delta_key(tables, query)
+            entry = cache.get(key)
         if entry is None:
             return None
         per_table_ids = cache.refresh_ids(entry, tables)
@@ -1347,9 +1373,9 @@ class WorkerNode(WorkerBase):
         for view in tails:
             payloads.append(self.engine.execute_local(view, query))
             delta_rows += int(view.nrows)
-        with timer.phase("hostmerge"):
+        with tracing.trace_span("hostmerge"), timer.phase("hostmerge"):
             merged = ResultPayload(hostmerge.merge_payloads(payloads))
-        with timer.phase("serialize"):
+        with tracing.trace_span("serialize"), timer.phase("serialize"):
             data = merged.to_bytes()
         cache.store(key, tables, data)
         cache.refreshes += 1
@@ -1496,7 +1522,9 @@ class WorkerNode(WorkerBase):
                 tail_payload = self.engine.execute_local(
                     table.chunk_view(new_ids), query
                 )
-                with timer.phase("hostmerge"):
+                with tracing.trace_span("hostmerge"), timer.phase(
+                    "hostmerge"
+                ):
                     merged = hostmerge.merge_payloads(
                         [ResultPayload.from_bytes(prior), tail_payload]
                     )
@@ -1508,7 +1536,7 @@ class WorkerNode(WorkerBase):
                 payload = self.engine.execute_local(table, query)
             else:
                 payload = self._execute_dag([table], dag, timer)
-            with timer.phase("serialize"):
+            with tracing.trace_span("serialize"), timer.phase("serialize"):
                 data = payload.to_bytes()
         self.flight.record(
             "rollup_build", filename=filename, mode=mode,
@@ -1564,7 +1592,7 @@ class WorkerNode(WorkerBase):
             from bqueryd_tpu.ops import predicates
 
             if predicates.chunk_prune_enabled():
-                with timer.phase("prune"):
+                with tracing.trace_span("prune"), timer.phase("prune"):
                     pruned = [
                         predicates.chunk_pruned_table(t, query.where_terms)
                         for t in tables
@@ -1661,7 +1689,7 @@ class WorkerNode(WorkerBase):
         # for the group (a host/device split across shards reports the last)
         self._last_effective_strategy = self.engine.last_effective_strategy
         self._last_merge_mode = "host"
-        with timer.phase("hostmerge"):
+        with tracing.trace_span("hostmerge"), timer.phase("hostmerge"):
             merged = hostmerge.merge_payloads(payloads)
         from bqueryd_tpu.models.query import ResultPayload
 
@@ -1779,6 +1807,7 @@ class WorkerNode(WorkerBase):
 
         from bqueryd_tpu import obs
         from bqueryd_tpu.models.query import GroupByQuery
+        from bqueryd_tpu.obs import profile as obs_profile
 
         # distributed tracing: phases double as spans (PhaseTimer records
         # into the recorder), the worker's "calc" root span parents to the
@@ -1793,106 +1822,114 @@ class WorkerNode(WorkerBase):
                 root_parent=ctx.span_id if ctx else None,
             )
         timer = PhaseTimer(recorder=recorder, span_names=obs.PHASE_SPAN_NAMES)
-        args, kwargs = msg.get_args_kwargs()
-        filename, groupby_cols, agg_list, where_terms = args[:4]
-        from bqueryd_tpu.plan import dag as dagmod
+        # the compile mark: jit misses of the compile registry before and
+        # after this unit (reply key ``compiled``, set only when it rose)
+        misses_before = obs_profile.profiler().jit_cache_misses
+        with tracing.detail("parse", timer):
+            args, kwargs = msg.get_args_kwargs()
+            filename, groupby_cols, agg_list, where_terms = args[:4]
+            from bqueryd_tpu.plan import dag as dagmod
 
-        # EVERY groupby now compiles through the operator-DAG layer
-        # (plan.dag).  A `dag` envelope key is the authoritative program
-        # (the rpc.query verb's richer shapes: joins, top-k, sketches,
-        # windows); otherwise the classic fragment/params build a plain
-        # DAG, whose plain_groupby_query() round trip is field-exact — the
-        # engine path below executes it bit-identically to the pre-DAG
-        # sequence (proven over the fuzz corpus).
-        dag = None
-        if msg.get("dag"):
-            dag = dagmod.OperatorDAG.from_wire(msg.get_from_binary("dag"))
-            dag.sole_payload = bool(msg.get("sole_shard"))
-            query = dag.plain_groupby_query()
-            strategy = None
-        else:
-            # a planning controller ships the compiled plan fragment
-            # alongside the reference-shaped params: the fragment is
-            # authoritative (it carries the rewritten query + the
-            # kernel-strategy hint); bare params keep working for
-            # mixed-version clusters and direct tests
-            fragment = (
-                msg.get_from_binary("plan") if msg.get("plan") else None
-            )
-            strategy = None
-            if fragment:
-                from bqueryd_tpu.plan import calibrate, fragment_to_query
-
-                query = fragment_to_query(fragment)
-                strategy = fragment.get("strategy")
-                if strategy in (None, "auto"):
-                    strategy = None
-                elif strategy == "matmul" and fragment.get(
-                    "strategy_binding"
-                ):
-                    # calibration-backed promotion rides the wire as
-                    # advisory "matmul" + this flag (old workers ignore it
-                    # — see plan.logical.fragment_for); reconstruct the
-                    # binding form unless BQUERYD_TPU_CALIB=0, the kill
-                    # switch that restores pre-calibration behaviour
-                    # exactly on this worker even when a calibrating
-                    # controller emitted the promotion
-                    if calibrate.enabled():
-                        strategy = "matmul!"
+            # EVERY groupby now compiles through the operator-DAG layer
+            # (plan.dag).  A `dag` envelope key is the authoritative program
+            # (the rpc.query verb's richer shapes: joins, top-k, sketches,
+            # windows); otherwise the classic fragment/params build a plain
+            # DAG, whose plain_groupby_query() round trip is field-exact —
+            # the engine path below executes it bit-identically to the
+            # pre-DAG sequence (proven over the fuzz corpus).
+            dag = None
+            if msg.get("dag"):
+                dag = dagmod.OperatorDAG.from_wire(msg.get_from_binary("dag"))
+                dag.sole_payload = bool(msg.get("sole_shard"))
+                query = dag.plain_groupby_query()
+                strategy = None
             else:
-                query = GroupByQuery(
-                    groupby_cols,
-                    agg_list,
-                    where_terms or [],
-                    aggregate=kwargs.get("aggregate", True),
-                    expand_filter_column=kwargs.get("expand_filter_column"),
-                    sole_payload=bool(msg.get("sole_shard")),
+                # a planning controller ships the compiled plan fragment
+                # alongside the reference-shaped params: the fragment is
+                # authoritative (it carries the rewritten query + the
+                # kernel-strategy hint); bare params keep working for
+                # mixed-version clusters and direct tests
+                fragment = (
+                    msg.get_from_binary("plan") if msg.get("plan") else None
                 )
-            # round-trip through the DAG layer: compile, then rebuild the
-            # query from the compiled form — the pair is field-exact, so
-            # execution (and the result-cache key) stays bit-identical
-            dag = dagmod.dag_from_query(query)
-            query = dag.plain_groupby_query()
-        filenames = filename if isinstance(filename, list) else [filename]
+                strategy = None
+                if fragment:
+                    from bqueryd_tpu.plan import calibrate, fragment_to_query
+
+                    query = fragment_to_query(fragment)
+                    strategy = fragment.get("strategy")
+                    if strategy in (None, "auto"):
+                        strategy = None
+                    elif strategy == "matmul" and fragment.get(
+                        "strategy_binding"
+                    ):
+                        # calibration-backed promotion rides the wire as
+                        # advisory "matmul" + this flag (old workers ignore it
+                        # — see plan.logical.fragment_for); reconstruct the
+                        # binding form unless BQUERYD_TPU_CALIB=0, the kill
+                        # switch that restores pre-calibration behaviour
+                        # exactly on this worker even when a calibrating
+                        # controller emitted the promotion
+                        if calibrate.enabled():
+                            strategy = "matmul!"
+                else:
+                    query = GroupByQuery(
+                        groupby_cols,
+                        agg_list,
+                        where_terms or [],
+                        aggregate=kwargs.get("aggregate", True),
+                        expand_filter_column=kwargs.get(
+                            "expand_filter_column"
+                        ),
+                        sole_payload=bool(msg.get("sole_shard")),
+                    )
+                # round-trip through the DAG layer: compile, then rebuild the
+                # query from the compiled form — the pair is field-exact, so
+                # execution (and the result-cache key) stays bit-identical
+                dag = dagmod.dag_from_query(query)
+                query = dag.plain_groupby_query()
+            filenames = filename if isinstance(filename, list) else [filename]
         tables = []
-        with timer.phase("open"):
+        with tracing.trace_span("open"), timer.phase("open"):
             for name in filenames:
                 rootdir = os.path.join(self.data_dir, name)
                 if not os.path.exists(rootdir):
                     raise ValueError(f"Path {rootdir} does not exist")
                 tables.append(self._open_table(rootdir))
-        cache = self.result_cache
-        cache_key = None
-        data = None
-        if cache is not None:
-            from bqueryd_tpu.parallel.executor import _table_key
+        with tracing.detail("cache_probe", timer):
+            cache = self.result_cache
+            cache_key = None
+            data = None
+            if cache is not None:
+                from bqueryd_tpu.parallel.executor import _table_key
 
-            cache_key = (
-                tuple(_table_key(t) for t in tables),
-                # extended DAGs have no GroupByQuery form; their identity
-                # is the DAG signature (join table / window / sketch
-                # params included).  Plain shapes keep the historical
-                # query-signature key, so warm caches survive the DAG
-                # refactor untouched.
-                query.signature() if query is not None else dag.signature(),
-            )
-            data = cache.get(cache_key)
-            if data is not None:
-                timer.timings["result_cache"] = 0.0
-        mem_tags = None
-        # a result-cache hit compiled nothing: "cached" keeps the reply's
-        # route report honest instead of silently dropping the key
-        effective = "cached" if data is not None else None
-        # delta-maintained serving: on a result-cache miss for a
-        # delta-eligible shape, try refreshing a cached result by
-        # aggregating ONLY the chunks appended since it was computed
-        # (ops.workingset; "delta" in the route report)
-        delta_cache = None
-        delta_key = None
-        if query is not None and self._delta_eligible(query):
-            delta_cache = self.delta_cache()
-            if delta_cache is not None:
-                delta_key = self._delta_key(tables, query)
+                cache_key = (
+                    tuple(_table_key(t) for t in tables),
+                    # extended DAGs have no GroupByQuery form; their identity
+                    # is the DAG signature (join table / window / sketch
+                    # params included).  Plain shapes keep the historical
+                    # query-signature key, so warm caches survive the DAG
+                    # refactor untouched.
+                    query.signature() if query is not None
+                    else dag.signature(),
+                )
+                data = cache.get(cache_key)
+                if data is not None:
+                    timer.timings["result_cache"] = 0.0
+            mem_tags = None
+            # a result-cache hit compiled nothing: "cached" keeps the reply's
+            # route report honest instead of silently dropping the key
+            effective = "cached" if data is not None else None
+            # delta-maintained serving: on a result-cache miss for a
+            # delta-eligible shape, try refreshing a cached result by
+            # aggregating ONLY the chunks appended since it was computed
+            # (ops.workingset; "delta" in the route report)
+            delta_cache = None
+            delta_key = None
+            if query is not None and self._delta_eligible(query):
+                delta_cache = self.delta_cache()
+                if delta_cache is not None:
+                    delta_key = self._delta_key(tables, query)
         if data is None and delta_cache is not None:
             self._last_merge_mode = None
             data = self._serve_delta(delta_cache, tables, query, timer)
@@ -1905,28 +1942,16 @@ class WorkerNode(WorkerBase):
             if effective == "delta" else None
         )  # otherwise only freshly computed queries merged anything
         if data is None:
-            import contextlib
-
-            from bqueryd_tpu.obs import profile as obs_profile
-
-            profile_dir = os.environ.get("BQUERYD_TPU_PROFILE_DIR")
-            # opt-in: capture a full TensorBoard trace of this query
-            if profile_dir:
-                from bqueryd_tpu.utils.tracing import profiler_trace
-
-                profiling = profiler_trace(profile_dir)
+            with tracing.detail("mem_sample", timer):
+                mem_before = obs_profile.profiler().memory_sample()
+            if query is not None:
+                # plain shape: the unchanged engine/mesh path —
+                # bit-identical to the pre-DAG hardwired sequence
+                payload = self._execute(
+                    tables, query, timer, strategy=strategy
+                )
             else:
-                profiling = contextlib.nullcontext()
-            mem_before = obs_profile.profiler().memory_sample()
-            with profiling:
-                if query is not None:
-                    # plain shape: the unchanged engine/mesh path —
-                    # bit-identical to the pre-DAG hardwired sequence
-                    payload = self._execute(
-                        tables, query, timer, strategy=strategy
-                    )
-                else:
-                    payload = self._execute_dag(tables, dag, timer)
+                payload = self._execute_dag(tables, dag, timer)
             effective = getattr(self, "_last_effective_strategy", None)
             merge_mode = getattr(self, "_last_merge_mode", None)
             if recorder is not None and effective:
@@ -1934,7 +1959,7 @@ class WorkerNode(WorkerBase):
                 # compiled post-guards — rpc.trace() waterfalls can now
                 # tell a promoted matmul from a silently-normalized hint
                 for span in recorder.spans:
-                    if span.get("name") == "kernel":
+                    if span.get("name") in ("kernel", "aggregate_wait"):
                         span.setdefault("tags", {})[
                             "effective_strategy"
                         ] = effective
@@ -1950,8 +1975,9 @@ class WorkerNode(WorkerBase):
                         break
             # the execute above is proof the backend answered: safe to
             # (lazily) enumerate devices for HBM sampling from now on
-            obs_profile.profiler().note_devices()
-            mem_after = obs_profile.profiler().memory_sample()
+            with tracing.detail("mem_sample", timer):
+                obs_profile.profiler().note_devices()
+                mem_after = obs_profile.profiler().memory_sample()
             if mem_after is not None:
                 # device-memory attribution on the calc root span (visible
                 # in rpc.trace waterfalls).  peak_bytes_in_use is the
@@ -1971,15 +1997,16 @@ class WorkerNode(WorkerBase):
                         mem_after["bytes_in_use"] - before["bytes_in_use"]
                     ),
                 }
-            with timer.phase("serialize"):
+            with tracing.trace_span("serialize"), timer.phase("serialize"):
                 data = payload.to_bytes()
-            if cache is not None and len(data) <= cache.max_bytes // 8:
-                cache.put(cache_key, data, nbytes=len(data))
-            if delta_cache is not None:
-                # record the delta base: the snapshots of the very table
-                # instances this result was computed from, so a later
-                # append refreshes it from the tail alone
-                delta_cache.store(delta_key, tables, data)
+            with tracing.detail("cache_probe", timer):
+                if cache is not None and len(data) <= cache.max_bytes // 8:
+                    cache.put(cache_key, data, nbytes=len(data))
+                if delta_cache is not None:
+                    # record the delta base: the snapshots of the very table
+                    # instances this result was computed from, so a later
+                    # append refreshes it from the tail alone
+                    delta_cache.store(delta_key, tables, data)
         if obs.enabled():
             # result-payload size per reply — observed for cache hits too,
             # so this histogram and its controller-side twin
@@ -2000,7 +2027,12 @@ class WorkerNode(WorkerBase):
         # controller only consults the key on ERROR replies, which keep it)
         reply.pop("dag", None)
         reply["data"] = data
-        reply["phase_timings"] = timer.as_dict()
+        reply["phase_timings"] = self._with_post_prev(timer.as_dict())
+        compiled = obs_profile.profiler().jit_cache_misses - misses_before
+        if compiled > 0:
+            # this unit compiled (or loaded from the persistent cache) that
+            # many programs; the key is absent from a steady-state reply
+            reply["compiled"] = compiled
         if recorder is not None:
             # the span list rides the JSON reply; the controller folds it
             # into the query timeline behind rpc.trace(trace_id); device
@@ -2032,6 +2064,17 @@ class WorkerNode(WorkerBase):
             reply["merge_mode"] = merge_mode
         self.logger.debug("calc %s done: %s", filename, timer.as_dict())
         return reply
+
+    def _with_post_prev(self, timings):
+        """Under BQUERYD_TPU_PROFILE=1 the unit BEFORE this one left its
+        ``send`` / ``post`` detail spans behind (handle()): their seconds
+        ride this reply as ``post_prev``.  No phase of this reply's wall —
+        whatever sums ``phase_timings`` against ``_total`` skips it."""
+        spans = self._after_reply.recorder.spans
+        if spans:
+            timings["post_prev"] = sum(s["duration_s"] for s in spans)
+            del spans[:]
+        return timings
 
     def _observe_phase_histograms(self, timer):
         """One ``bqueryd_tpu_query_phase_seconds{phase=...}`` observation
@@ -2093,6 +2136,7 @@ class WorkerNode(WorkerBase):
         import pickle
 
         from bqueryd_tpu import chaos, obs
+        from bqueryd_tpu.obs import profile as obs_profile
         from bqueryd_tpu.parallel.executor import _table_key
         from bqueryd_tpu.plan import bundle as bundlemod
 
@@ -2106,40 +2150,43 @@ class WorkerNode(WorkerBase):
                 root_parent=ctx.span_id if ctx else None,
             )
         timer = PhaseTimer(recorder=recorder, span_names=obs.PHASE_SPAN_NAMES)
-        fragment = msg.get_from_binary("bundle")
-        members = bundlemod.bundle_to_queries(fragment)
-        strategy = bundlemod.fragment_strategy(fragment)
-        filename = msg.get("filename") or fragment.get("filenames")
-        filenames = filename if isinstance(filename, list) else [filename]
+        misses_before = obs_profile.profiler().jit_cache_misses
+        with tracing.detail("parse", timer):
+            fragment = msg.get_from_binary("bundle")
+            members = bundlemod.bundle_to_queries(fragment)
+            strategy = bundlemod.fragment_strategy(fragment)
+            filename = msg.get("filename") or fragment.get("filenames")
+            filenames = filename if isinstance(filename, list) else [filename]
         tables = []
-        with timer.phase("open"):
+        with tracing.trace_span("open"), timer.phase("open"):
             for name in filenames:
                 rootdir = os.path.join(self.data_dir, name)
                 if not os.path.exists(rootdir):
                     raise ValueError(f"Path {rootdir} does not exist")
                 tables.append(self._open_table(rootdir))
 
-        cache = self.result_cache
-        tables_sig = tuple(_table_key(t) for t in tables)
-        payloads = {}      # member_id -> serialized ResultPayload bytes
-        errors = {}        # member_id -> failure text (member-only abort)
-        active = []        # (member_id, query) still needing execution
-        now = time.time()
-        for member_id, deadline, query in members:
-            if deadline is not None and float(deadline) <= now:
-                # the member's budget is gone: drop it from the stack, not
-                # the bundle — its bundle-mates keep their answers
-                errors[member_id] = (
-                    f"deadline exceeded "
-                    f"{now - float(deadline):.3f}s before execution"
-                )
-                continue
-            if cache is not None:
-                hit = cache.get((tables_sig, query.signature()))
-                if hit is not None:
-                    payloads[member_id] = hit
+        with tracing.detail("cache_probe", timer):
+            cache = self.result_cache
+            tables_sig = tuple(_table_key(t) for t in tables)
+            payloads = {}      # member_id -> serialized ResultPayload bytes
+            errors = {}        # member_id -> failure text (member-only abort)
+            active = []        # (member_id, query) still needing execution
+            now = time.time()
+            for member_id, deadline, query in members:
+                if deadline is not None and float(deadline) <= now:
+                    # the member's budget is gone: drop it from the stack, not
+                    # the bundle — its bundle-mates keep their answers
+                    errors[member_id] = (
+                        f"deadline exceeded "
+                        f"{now - float(deadline):.3f}s before execution"
+                    )
                     continue
-            active.append((member_id, query))
+                if cache is not None:
+                    hit = cache.get((tables_sig, query.signature()))
+                    if hit is not None:
+                        payloads[member_id] = hit
+                        continue
+                active.append((member_id, query))
 
         # per-member segment shares (messages.py `member_shares`): measured
         # walls on the fallback path, an equal split on the one-program
@@ -2205,7 +2252,7 @@ class WorkerNode(WorkerBase):
                             f"{type(exc).__name__}: {exc}"
                         )
 
-        with timer.phase("serialize"):
+        with tracing.trace_span("serialize"), timer.phase("serialize"):
             for member_id, payload in results.items():
                 data = payload.to_bytes()
                 payloads[member_id] = data
@@ -2237,7 +2284,10 @@ class WorkerNode(WorkerBase):
             **{mid: 0.0 for mid in cached_ids},
             **bundlemod.member_shares(list(results), walls=member_walls),
         }
-        reply["phase_timings"] = timer.as_dict()
+        reply["phase_timings"] = self._with_post_prev(timer.as_dict())
+        compiled = obs_profile.profiler().jit_cache_misses - misses_before
+        if compiled > 0:
+            reply["compiled"] = compiled
         if recorder is not None:
             reply["spans"] = recorder.export()
             # one CalcMessage executed, whatever its member count (the

@@ -178,7 +178,7 @@ def test_phase_timer_records_spans_with_mapped_names():
         assert child["trace_id"] == "t" * 32
 
 
-# -- trace_span / profiler_trace env gating (satellite: zero tests imported
+# -- trace_span env gating (satellite: zero tests imported
 #    utils/tracing before) ---------------------------------------------------
 
 def test_trace_span_noop_when_profile_unset(monkeypatch):
@@ -242,39 +242,6 @@ def test_trace_span_enabled_without_jax_is_noop(monkeypatch):
     with tracing.trace_span("no-jax"):
         entered.append(True)
     assert entered == [True]
-
-
-def test_profiler_trace_starts_and_stops(monkeypatch, tmp_path):
-    import jax.profiler
-
-    from bqueryd_tpu.utils import tracing
-
-    calls = []
-    monkeypatch.setattr(
-        jax.profiler, "start_trace", lambda d: calls.append(("start", d))
-    )
-    monkeypatch.setattr(
-        jax.profiler, "stop_trace", lambda: calls.append(("stop", None))
-    )
-    with tracing.profiler_trace(str(tmp_path)):
-        pass
-    assert calls == [("start", str(tmp_path)), ("stop", None)]
-
-
-def test_profiler_trace_stops_on_error(monkeypatch, tmp_path):
-    import jax.profiler
-
-    from bqueryd_tpu.utils import tracing
-
-    calls = []
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
-    monkeypatch.setattr(
-        jax.profiler, "stop_trace", lambda: calls.append("stop")
-    )
-    with pytest.raises(RuntimeError):
-        with tracing.profiler_trace(str(tmp_path)):
-            raise RuntimeError("boom")
-    assert calls == ["stop"]
 
 
 # -- trace model -------------------------------------------------------------
