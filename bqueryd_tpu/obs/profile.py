@@ -377,13 +377,19 @@ def _reset_for_tests():
     return _profiler
 
 
-def instrument(name, jitted):
+def instrument(name, jitted, signature_args=None):
     """Wrap a jitted callable with compile/call accounting.
 
     Transparent when: profiling or the obs hot path is disabled, the call
     happens under an outer jax trace (tracer args), or the wrapped object
     does not expose a jit cache.  The wrapper never lets accounting raise
-    into the query path."""
+    into the query path.
+
+    ``signature_args(*args, **kwargs)`` gives what the shape signature is
+    made from in place of the call's own arguments: for a program whose
+    TRACED arguments include Python scalars, which the jit cache keys by
+    type and the default signature by value (one registry entry per
+    constant)."""
     # signatures THIS wrapper has already seen compiled: cache-size growth
     # alone is racy when several threads share one jitted function (an
     # in-process cluster), where thread A's compile of shape X lands inside
@@ -411,7 +417,10 @@ def instrument(name, jitted):
         out = jitted(*args, **kwargs)
         duration = time.perf_counter() - t0
         try:
-            signature = _shape_signature(name, args, kwargs)
+            signature = _shape_signature(name, *(
+                (args, kwargs) if signature_args is None
+                else (signature_args(*args, **kwargs), {})
+            ))
             compiled = cache_size() > before and signature not in seen_sigs
             if len(seen_sigs) > 4096:  # pathological shape drift backstop
                 seen_sigs.clear()

@@ -119,8 +119,14 @@ def build_mask(table, where_terms_list, column_getter=None):
     for an empty term list (no filtering — same contract as the reference
     passing bool_arr=None, reference bqueryd/worker.py:294-309).
 
-    ``column_getter`` overrides physical column access (the executor passes
-    device-resident columns; default reads from the table)."""
+    ``column_getter`` overrides physical column access; no caller in the
+    package passes one (the mesh executor, the DAG executors and the
+    per-shard engine all read from the table).  The default hands
+    :func:`term_mask` the table's HOST copy of each column, which it
+    uploads on EVERY call (``jnp.asarray``): a caller that evaluates fresh
+    filters over the same tables should keep the column on the device, as
+    ``MeshQueryExecutor._fold_on_device`` does with :func:`term_mask`
+    itself under one jit."""
     if not where_terms_list:
         return None
     get = column_getter or (lambda name: table.column_raw(name))

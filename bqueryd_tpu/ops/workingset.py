@@ -9,8 +9,9 @@ telemetry; this promotes them into one named working-set layer:
 * **content-keyed segments** — ``align`` (host: dense codes + global
   dictionaries per (table set, groupby columns)), ``codes`` (device:
   packed+folded group codes per (table set, groupby columns, filter)),
-  ``blocks`` (device: packed wire-dtype measure columns per (table set,
-  column)).  Keys carry the shard identity (rootdir + meta.json
+  ``blocks`` (device: packed wire-dtype measure columns, and the
+  stored-dtype filter columns the device-side fold compares, per (table
+  set, column)).  Keys carry the shard identity (rootdir + meta.json
   inode/mtime + rows, :func:`bqueryd_tpu.storage.ctable.table_cache_key`),
   so activation invalidates naturally and a repeat query with a DIFFERENT
   measure or filter still hits the codes/alignment segments — it skips

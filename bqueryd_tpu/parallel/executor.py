@@ -157,13 +157,57 @@ def _measure_kind(tables, col):
 
 
 def _where_signature(query):
-    """Hashable, canonical identity of a query's row-filter."""
+    """Hashable, canonical identity of a query's row-filter; ``None`` gives
+    that of a query with no filter (the unmasked codes' own)."""
     from bqueryd_tpu.models.query import freeze_value
 
+    if query is None:
+        return (freeze_value([]), None)
     return (
         freeze_value(query.where_terms or []),
         query.expand_filter_column,
     )
+
+
+#: the where ops whose constant is one scalar (``in`` / ``not in`` carry a
+#: list, whose length is a program shape)
+_DEVICE_FOLD_OPS = ("==", "!=", "<", "<=", ">", ">=")
+
+
+def _device_fold_terms(tables, query):
+    """The row filter as ``[(column, op, physical constant), ...]`` when it
+    can fold into the group codes ON THE DEVICE (``_fold_program``), else
+    None and the host builds the masks.  Decided by what the query and the
+    tables show: every op compares against one scalar, no basket expansion,
+    and every term's column is numeric or datetime with ONE stored dtype on
+    every table of the dispatch — so the packed column is the very array
+    each shard's host mask reads, and one constant serves all shards.  A
+    ``dict`` column translates its constant per shard's dictionary; shards
+    of differing widths compare each in its own precision."""
+    from bqueryd_tpu import ops
+
+    if not query.where_terms or query.expand_filter_column:
+        return None
+    terms = []
+    for term in query.where_terms:
+        try:
+            column, op, value = term
+        except (TypeError, ValueError):
+            return None
+        if op not in _DEVICE_FOLD_OPS:
+            return None
+        if not all(column in t for t in tables):
+            return None
+        stored = {(t.kind(column), t.physical_dtype(column)) for t in tables}
+        if len(stored) != 1 or stored.pop()[0] not in ("numeric", "datetime"):
+            return None
+        phys = ops.translate_value(tables[0], column, value, op)
+        # bool is an int: all three are what ``jit`` traces as weak-typed
+        # scalars, the way the host's eager compare takes them
+        if not isinstance(phys, (int, float)):
+            return None
+        terms.append((column, op, phys))
+    return terms
 
 
 def _codes_dtype(n_groups):
@@ -214,7 +258,8 @@ class MeshQueryExecutor:
         #   align:  (tables_key, groupby_cols) -> (dense codes per shard,
         #           combos, cards, key_values) — host side
         #   codes:  folded+packed group codes -> jax.Array [n_dev, width]
-        #   blocks: packed wire-dtype measure columns -> jax.Array
+        #   blocks: packed wire-dtype measure columns, and stored-dtype
+        #           filter columns (_fold_on_device) -> jax.Array
         # On CPU backends the device segments count against host RSS; the
         # RSS watchdog clears them before giving up (worker._check_mem)
         self.workingset = WorkingSet()
@@ -487,6 +532,72 @@ class MeshQueryExecutor:
             off += len(arr)
         return out.reshape(n_devices, width)
 
+    def _fold_on_device(self, tables, codes_key, dense, n_groups,
+                        fold_terms, sharding):
+        """The folded codes of a filtered query, made on the device: one
+        small program (``_fold_program``) over the resident UNMASKED codes
+        of the query's keys — the entry an unfiltered query of the same keys
+        puts under its own ``codes_key``, so one entry serves both — and the
+        resident filter columns, packed like the codes (same concat order,
+        same bucketed width: row *i* of a column is row *i* of the codes)
+        and kept in the ``blocks`` segment at their STORED dtype, which is
+        the dtype each shard's host mask compares in (a measure block may be
+        narrowed by value range, ``_wire_dtype``; a constant outside the
+        narrow range would wrap there).  Both are built once per table set;
+        a steady fresh filter costs the dispatch.  Caches the result under
+        ``codes_key`` and returns it.  Inside the ``layout`` phase."""
+        from bqueryd_tpu.parallel import pipeline
+
+        tables_key, n_dev = codes_key[0], codes_key[-1]
+        unmasked_key = codes_key[:3] + (_where_signature(None), n_dev)
+        # get, not contains: the folded entries of fresh filters pass
+        # through this LRU segment, and the unmasked one must outlive them
+        unmasked = self._codes_cache.get(unmasked_key)
+        if unmasked is None:
+            cdt = _codes_dtype(n_groups)
+            with pipeline.stage("align"), tracing.detail(
+                "layout_pack", self.timer
+            ):
+                packed = self._pack(
+                    [d.astype(cdt) for d in dense], n_dev, cdt.type(-1),
+                    dtype=cdt,
+                )
+            with pipeline.stage("h2d"), tracing.detail(
+                "layout_h2d", self.timer
+            ):
+                unmasked = _put(packed, sharding)
+        term_columns, term_ops, constants = zip(*fold_terms)
+        columns = []
+        for column in term_columns:
+            fkey = (tables_key, "where", column, n_dev)
+            arr = self._hbm_cache.get(fkey)
+            if arr is None:
+                with pipeline.stage("decode"):
+                    with tracing.detail("layout_columns", self.timer):
+                        cols = [
+                            np.asarray(t.column_raw(column)) for t in tables
+                        ]
+                    with tracing.detail("layout_pack", self.timer):
+                        # the pad value is free: pad rows' codes are -1
+                        packed = self._pack(cols, n_dev, 0)
+                with pipeline.stage("h2d"), tracing.detail(
+                    "layout_h2d", self.timer
+                ):
+                    arr = _put(packed, sharding)
+                self._hbm_cache.put(fkey, arr)
+            columns.append(arr)
+        program = _fold_program(term_ops, sharding)
+        # the span times a dispatch that returns at once; ``site`` tells
+        # the device trace which fold ran
+        with tracing.detail("layout_fold", self.timer, site="device"):
+            codes_d = program(unmasked, tuple(columns), constants)
+        self._codes_cache.put(codes_key, codes_d)
+        # after the folded entry, so that where the two do not fit the
+        # budget together the unmasked codes are the ones that stay (a
+        # no-op while they are resident)
+        self._codes_cache.put(unmasked_key, unmasked)
+        return codes_d
+
     # -- execution ----------------------------------------------------------
     def execute(self, tables, query: GroupByQuery,
                 strategy=None) -> ResultPayload:
@@ -653,44 +764,61 @@ class MeshQueryExecutor:
 
         codes_d = self._codes_cache.get(codes_key)
         if codes_d is None:
-            # cold path only: masks + fold + pack + H2D.  On a cache hit the
-            # whole filter evaluation is skipped — the folded codes ARE the
-            # filter.
+            # cold path only.  On a cache hit the whole filter evaluation is
+            # skipped — the folded codes ARE the filter.  A filter of scalar
+            # compares on numeric/datetime columns folds ON THE DEVICE, from
+            # resident unmasked codes and resident filter columns
+            # (_fold_on_device): nothing per row crosses the host.  Every
+            # other filter: masks + fold + pack + H2D on the host, as below.
             with self._phase("mask"):
+                # all the mask-making the device path has: its constants
+                fold_terms = _device_fold_terms(tables, query)
                 masks = []
-                for table in tables:
-                    mask = ops.build_mask(table, query.where_terms)
-                    if query.expand_filter_column:
-                        # cached, with nulls-are-a-basket semantics
-                        bcodes, buniques = engine._basket_codes(
-                            table, query.expand_filter_column
+                if fold_terms is None:
+                    for table in tables:
+                        mask = ops.build_mask(table, query.where_terms)
+                        if query.expand_filter_column:
+                            # cached, with nulls-are-a-basket semantics
+                            bcodes, buniques = engine._basket_codes(
+                                table, query.expand_filter_column
+                            )
+                            mask = ops.expand_mask_by_group(
+                                bcodes, mask, n_groups=len(buniques)
+                            )
+                        masks.append(
+                            None if mask is None else np.asarray(mask)
                         )
-                        mask = ops.expand_mask_by_group(
-                            bcodes, mask, n_groups=len(buniques)
-                        )
-                    masks.append(None if mask is None else np.asarray(mask))
             with self._phase("layout"):
-                # fold the row mask into the codes: masked-out rows become
-                # null (code -1) and vanish from every segment reduction.
-                # Folds into fresh arrays — cached dense stays unmasked.
-                with pipeline.stage("align"):
-                    cdt = _codes_dtype(n_groups)
-                    with tracing.detail("layout_fold", self.timer):
-                        folded = [
-                            np.where(mask, d, -1).astype(cdt)
-                            if mask is not None
-                            else d.astype(cdt)
-                            for d, mask in zip(dense, masks)
-                        ]
-                    with tracing.detail("layout_pack", self.timer):
-                        packed = self._pack(
-                            folded, n_dev, cdt.type(-1), dtype=cdt
-                        )
-                with pipeline.stage("h2d"), tracing.detail(
-                    "layout_h2d", self.timer
-                ):
-                    codes_d = _put(packed, sharding)
-                self._codes_cache.put(codes_key, codes_d)
+                if fold_terms is not None:
+                    codes_d = self._fold_on_device(
+                        tables, codes_key, dense, n_groups, fold_terms,
+                        sharding,
+                    )
+                else:
+                    # fold the row mask into the codes: masked-out rows
+                    # become null (code -1) and vanish from every segment
+                    # reduction.  Folds into fresh arrays — cached dense
+                    # stays unmasked.
+                    with pipeline.stage("align"):
+                        cdt = _codes_dtype(n_groups)
+                        with tracing.detail(
+                            "layout_fold", self.timer, site="host"
+                        ):
+                            folded = [
+                                np.where(mask, d, -1).astype(cdt)
+                                if mask is not None
+                                else d.astype(cdt)
+                                for d, mask in zip(dense, masks)
+                            ]
+                        with tracing.detail("layout_pack", self.timer):
+                            packed = self._pack(
+                                folded, n_dev, cdt.type(-1), dtype=cdt
+                            )
+                    with pipeline.stage("h2d"), tracing.detail(
+                        "layout_h2d", self.timer
+                    ):
+                        codes_d = _put(packed, sharding)
+                    self._codes_cache.put(codes_key, codes_d)
 
         with self._phase("layout"):
             def build_packed(col, timer=None):
@@ -1883,6 +2011,45 @@ def _route_key():
         gb.matmul_groups_limit(),
         gb._matmul_cells_limit(),
         pg.hicard_groups_limit(),
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _fold_program(term_ops, sharding):
+    """Build + cache the jitted mask-and-fold program for one tuple of
+    where ops: ``(codes, columns, constants) -> where(mask, codes, -1)``,
+    the mask made by the ``ops.term_mask`` the host path calls and AND-ed
+    over the terms, so promotion, NaN and weak-type semantics are the host
+    path's.  A program of its own, not part of the mesh program: elementwise
+    over arrays sharded on their first axis, no collective, and its output
+    carries the sharding, shape and dtype ``_put`` gives the host-folded
+    codes, so the mesh program sees the input it always saw.
+
+    The constants are TRACED arguments, passed as the Python scalars
+    ``translate_value`` returns — the weak-typed scalars the host's eager
+    compare hands its own jitted op: a fresh constant is a cache hit, and a
+    Python float against a float32 column compares in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from bqueryd_tpu import ops
+    from bqueryd_tpu.obs import profile as obsprofile
+
+    def fold(codes, columns, constants):
+        mask = None
+        for values, op, constant in zip(columns, term_ops, constants):
+            m = ops.term_mask(values, op, constant)
+            mask = m if mask is None else (mask & m)
+        return jnp.where(mask, codes, -1)
+
+    return obsprofile.instrument(
+        "executor.fold_program",
+        jax.jit(fold, out_shardings=sharding),
+        # the jit cache keys a traced Python scalar by its type: so does
+        # the registry, not by its value
+        signature_args=lambda codes, columns, constants: (
+            codes, columns, tuple(type(c).__name__ for c in constants),
+        ),
     )
 
 
