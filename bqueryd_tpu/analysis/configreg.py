@@ -127,14 +127,6 @@ ENV_REGISTRY = {
            "worker result cache (0=off)"),
         _v("PIPELINE_THREADS", "int", "min(16, cpu)",
            "shard-pipeline pool width (1 = fully serial stages)"),
-        _v("HBM_CACHE_BYTES", "int", "1 GiB",
-           "working-set blocks segment: device-resident measure columns"),
-        _v("CODES_CACHE_BYTES", "int", "256 MiB",
-           "working-set codes segment: device-resident folded group codes"),
-        _v("ALIGN_CACHE_BYTES", "int", "512 MiB",
-           "working-set align segment: host key alignment"),
-        _v("HBM_EVICT_WATERMARK", "float", "0.9",
-           "shed LRU device cache above this fraction of HBM bytes_limit"),
         _v("COLUMN_CACHE_BYTES", "int", "2 GiB",
            "decoded-column cache byte budget", READ_IMPORT),
         _v("NATIVE_LIB", "path", "auto", "path to libtpucolz.so"),

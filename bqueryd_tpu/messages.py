@@ -368,7 +368,7 @@ SPAN_SCHEMA = {
     "mem_sample": "detail span in calc: each device-memory sample around "
                   "the execution (with note_devices)",
     "layout_fold": "detail span in h2d_transfer: the row mask folded into "
-                   "the codes (or the codes narrowed) on the host",
+                   "the codes, on the device or on the host",
     "layout_pack": "detail span in h2d_transfer: _pack of codes, stacked "
                    "masks or an inline-built measure column",
     "layout_h2d": "detail span in h2d_transfer: a host->device placement "

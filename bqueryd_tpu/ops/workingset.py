@@ -1,33 +1,40 @@
 """Device-resident working-set cache: codes + measure blocks + alignment.
 
-The executor's steady-state serving story is cache residency: host key
-alignment (dictionary-sized), factorized+folded group codes (HBM), and
-wire-dtype measure blocks (HBM).  Before this module those were three
-ad-hoc ``BytesCappedCache`` instances with wholesale eviction and no
-telemetry; this promotes them into one named working-set layer:
+What the mesh executor keeps between queries, each thing once and at the
+width it is used, in three content-keyed LRU segments:
 
-* **content-keyed segments** — ``align`` (host: dense codes + global
-  dictionaries per (table set, groupby columns)), ``codes`` (device:
-  packed+folded group codes per (table set, groupby columns, filter)),
-  ``blocks`` (device: packed wire-dtype measure columns, and the
+* ``align`` (host): dense group codes at the narrowest dtype holding the
+  key set's group count, the global combos and the key dictionaries, per
+  (table set, groupby columns).
+* ``codes`` (device): the packed UNMASKED codes per (table set, groupby
+  columns), and the host-folded codes of the filters that cannot fold on
+  the device, per (table set, groupby columns, filter).  A filter that
+  folds on the device caches nothing: the unmasked entry and the resident
+  filter column are the state, the fold is a dispatch.
+* ``blocks`` (device): packed wire-dtype measure columns, and the
   stored-dtype filter columns the device-side fold compares, per (table
-  set, column)).  Keys carry the shard identity (rootdir + meta.json
-  inode/mtime + rows, :func:`bqueryd_tpu.storage.ctable.table_cache_key`),
-  so activation invalidates naturally and a repeat query with a DIFFERENT
-  measure or filter still hits the codes/alignment segments — it skips
-  decode, factorize and the codes H2D entirely instead of requiring an
-  exact serialized-result hit.
-* **LRU byte budgets per segment** (see the env vars below), with
-  hit/miss/eviction counters exported as worker gauges
-  (``bqueryd_tpu_workingset_*{segment=...}``) and into bench.py's
-  ``pipeline`` section.
-* **eviction under device-memory pressure** —
-  :meth:`WorkingSet.evict_under_pressure` reads the PR-3 HBM watermark
-  sample (``obs.profile.profiler().memory_sample()``) and evicts LRU
-  device entries until usage projects below
-  ``BQUERYD_TPU_HBM_EVICT_WATERMARK`` x ``bytes_limit`` — shedding cache
-  before the allocator hits RESOURCE_EXHAUSTED and ``DeviceHealth``
-  latches the backend as wedged.
+  set, column).
+
+Keys carry the shard identity (rootdir + meta.json inode/mtime + rows,
+:func:`bqueryd_tpu.storage.ctable.table_cache_key`), so activation
+invalidates naturally and a repeat query with a DIFFERENT measure or filter
+still hits the codes/alignment segments.  Hit/miss/eviction counters are
+exported as worker gauges (``bqueryd_tpu_workingset_*{segment=...}``).
+
+**What bounds a segment** is memory the worker measures, by the shares in
+:data:`SHARES`: the device segments by the ``bytes_limit`` of the profiler's
+memory sample (``obs.profile.profiler().memory_sample()``, summed over the
+local devices like a global array's ``nbytes``), the host segment by the
+worker's ``memory_limit_mb`` — the number its RSS watchdog enforces.  Where
+no device reports memory (the CPU backend; any backend before a first
+kernel call has proven it alive) the "device" arrays are host memory, and
+the device segments take their shares of the host limit until a sample
+says otherwise; an executor built outside a worker has no watchdog and is
+bounded by the machine's physical memory.
+:meth:`WorkingSet.evict_under_pressure` sheds LRU device entries while the
+sample's ``bytes_in_use`` sits above :data:`EVICT_WATERMARK` of the limit —
+before the allocator hits RESOURCE_EXHAUSTED and ``DeviceHealth`` latches
+the backend as wedged.
 
 A :class:`WorkingSet` is per-executor (the worker owns one mesh executor),
 not process-global: in-process test clusters and bench workers must not
@@ -44,42 +51,28 @@ from bqueryd_tpu.utils.cache import BytesCappedCache
 #: still-cached host alignment
 DEVICE_SEGMENTS = ("blocks", "codes")
 
-#: every segment, in eviction-preference order (device blocks first: they
-#: are the biggest and the cheapest to rebuild from the still-cached host
-#: alignment)
-SEGMENTS = ("blocks", "codes", "align")
-
-_DEFAULT_BUDGETS = {
-    # host alignment cache (dense codes + combos + dictionaries)
-    "align": ("BQUERYD_TPU_ALIGN_CACHE_BYTES", 512 * 1024**2),
-    # HBM folded group codes (one entry per (table set, keys, filter))
-    "codes": ("BQUERYD_TPU_CODES_CACHE_BYTES", 256 * 1024**2),
-    # HBM packed measure blocks (one entry per (table set, column))
-    "blocks": ("BQUERYD_TPU_HBM_CACHE_BYTES", 1024 * 1024**2),
+#: each segment's share of the memory that bounds it (module docstring)
+SHARES = {
+    # the deployment's columns are what the device is for; the other half
+    # holds the codes, the programs' scratch and the runtime
+    "blocks": 1 / 2,
+    # one entry a key set, 1-4 bytes a row where a column block is up to 8:
+    # a quarter of the blocks' room holds as many key sets as columns
+    "codes": 1 / 8,
+    # the decoded-column cache (2 GiB), the factorize, result and delta
+    # caches (under 1 GiB) and a cold query's packs share the other 3/4
+    "align": 1 / 4,
 }
 
-
-def _budget(segment):
-    env, default = _DEFAULT_BUDGETS[segment]
-    try:
-        # bqtpu: allow[config-dynamic-env-key] keys come from _DEFAULT_BUDGETS above; all three are in ENV_REGISTRY
-        return int(os.environ.get(env, default))
-    except ValueError:
-        import logging
-
-        logging.getLogger("bqueryd_tpu").warning(
-            "unparseable %s, using default %d", env, default
-        )
-        return default
+#: shed device entries above this share of ``bytes_limit``.  Full segments
+#: leave 3/8 of the device to a program's scratch (the f64 sort path's is
+#: the largest: PERF.md §6, PR 30, says what it is on the chip); the last
+#: tenth is for what the allocator cannot hand out in one piece
+EVICT_WATERMARK = 0.9
 
 
-def evict_watermark():
-    """Fraction of ``bytes_limit`` above which device cache is shed
-    (``BQUERYD_TPU_HBM_EVICT_WATERMARK``, default 0.9; <=0 disables)."""
-    try:
-        return float(os.environ.get("BQUERYD_TPU_HBM_EVICT_WATERMARK", 0.9))
-    except ValueError:
-        return 0.9
+def _physical_memory():
+    return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
 
 
 def _device_nbytes(value):
@@ -89,7 +82,8 @@ def _device_nbytes(value):
 
 class WorkingSet:
     """Named LRU cache segments + the device-memory-pressure eviction policy
-    (module docstring)."""
+    (module docstring).  ``host_limit_bytes`` is the worker's RSS limit;
+    ``budgets`` fixes a segment's bytes whatever is measured (tests)."""
 
     #: lock discipline, statically checked by bqueryd_tpu.analysis
     #: (lock-unguarded-attr).  ``_segments`` is read-only after __init__
@@ -97,15 +91,17 @@ class WorkingSet:
     #: pressure-eviction counter is guarded.
     _bqtpu_guarded_ = {"_pressure_lock": ("pressure_evictions",)}
 
-    def __init__(self, budgets=None):
+    def __init__(self, budgets=None, host_limit_bytes=None):
         import threading
 
-        budgets = budgets or {}
+        self._fixed = dict(budgets or {})
+        host = host_limit_bytes or _physical_memory()
         self._segments = {
             name: BytesCappedCache(
-                budgets.get(name, _budget(name)), sizeof=_device_nbytes
+                self._fixed.get(name, int(share * host)),
+                sizeof=_device_nbytes,
             )
-            for name in SEGMENTS
+            for name, share in SHARES.items()
         }
         self.pressure_evictions = 0  # entries shed by the watermark policy
         self._pressure_lock = threading.Lock()
@@ -128,22 +124,19 @@ class WorkingSet:
         return out
 
     # -- memory pressure -----------------------------------------------------
-    def evict_under_pressure(self, sample=None, watermark=None):
-        """Shed LRU device-segment entries while HBM usage sits above the
+    def evict_under_pressure(self, sample=None, watermark=EVICT_WATERMARK):
+        """Bound the device segments by the sample's ``bytes_limit`` and
+        shed their LRU entries while its ``bytes_in_use`` sits above the
         watermark.  ``sample`` is a ``{"bytes_in_use", "bytes_limit", ...}``
         dict (default: the live profiler sample; None — CPU backends, a
-        backend no kernel call has proven alive yet — is a no-op).  Returns bytes freed (accounted
-        cache bytes, a proxy for the HBM the dropped references release at
-        the allocator's next sweep).
+        backend no kernel call has proven alive yet — is a no-op).  Returns
+        bytes freed (accounted cache bytes, a proxy for the HBM the dropped
+        references release at the allocator's next sweep).
 
         Eviction order is ``blocks`` before ``codes``: measure blocks are
         the bulk of residency and rebuild from the still-cached host
         alignment with one decode+H2D, while codes rebuilding also re-runs
         mask folding."""
-        if watermark is None:
-            watermark = evict_watermark()
-        if watermark <= 0:
-            return 0
         if sample is None:
             from bqueryd_tpu.obs import profile
 
@@ -152,7 +145,12 @@ class WorkingSet:
             return 0
         limit = sample.get("bytes_limit") or 0
         in_use = sample.get("bytes_in_use") or 0
-        if limit <= 0 or in_use <= watermark * limit:
+        if limit <= 0:
+            return 0
+        for name in DEVICE_SEGMENTS:
+            if name not in self._fixed:
+                self._segments[name].max_bytes = int(SHARES[name] * limit)
+        if in_use <= watermark * limit:
             return 0
         target = int(in_use - watermark * limit)
         freed = 0

@@ -233,7 +233,8 @@ class MeshQueryExecutor:
     falls back to per-shard execution for distinct-count ops and raw rows.
     """
 
-    def __init__(self, mesh=None, axis_name="shards", timer=None):
+    def __init__(self, mesh=None, axis_name="shards", timer=None,
+                 host_limit_bytes=None):
         self._mesh = mesh
         self.axis_name = axis_name
         self.timer = timer
@@ -253,16 +254,18 @@ class MeshQueryExecutor:
         from bqueryd_tpu.ops.workingset import WorkingSet
 
         # the device-resident working-set layer (ops/workingset.py): LRU
-        # byte-budgeted segments with hit/miss/eviction telemetry and
+        # segments bounded by measured memory (``host_limit_bytes``: the
+        # worker's RSS limit), with hit/miss/eviction telemetry and
         # HBM-watermark pressure eviction.
-        #   align:  (tables_key, groupby_cols) -> (dense codes per shard,
-        #           combos, cards, key_values) — host side
-        #   codes:  folded+packed group codes -> jax.Array [n_dev, width]
+        #   align:  (tables_key, groupby_cols) -> (dense codes per shard at
+        #           _codes_dtype, combos, cards, key_values) — host side
+        #   codes:  packed unmasked (or host-folded) group codes
+        #           -> jax.Array [n_dev, width]
         #   blocks: packed wire-dtype measure columns, and stored-dtype
         #           filter columns (_fold_on_device) -> jax.Array
         # On CPU backends the device segments count against host RSS; the
         # RSS watchdog clears them before giving up (worker._check_mem)
-        self.workingset = WorkingSet()
+        self.workingset = WorkingSet(host_limit_bytes=host_limit_bytes)
         self._align_cache = self.workingset.segment("align")
         self._hbm_cache = self.workingset.segment("blocks")
         self._codes_cache = self.workingset.segment("codes")
@@ -335,10 +338,12 @@ class MeshQueryExecutor:
     def _global_key_space(self, tables, query, engine):
         """Remap every shard's per-column key codes into one global space.
 
-        Returns ``(per_shard_packed, combos, cards, key_values)`` where
-        ``combos`` is the sorted global composite-key array, ``cards`` the
-        global per-column cardinalities, and ``key_values[col]`` the global
-        per-column key-value arrays (indexable by unpacked codes).
+        Returns ``(dense, combos, cards, key_values)`` where ``dense`` holds
+        each shard's dense group codes at ``_codes_dtype(len(combos))`` —
+        the width every consumer packs them at — ``combos`` is the sorted
+        global composite-key array, ``cards`` the global per-column
+        cardinalities, and ``key_values[col]`` the global per-column
+        key-value arrays (indexable by unpacked codes).
         """
         n_cols = len(query.groupby_cols)
         shard_codes = [[] for _ in range(n_cols)]   # [col][shard] -> codes
@@ -411,8 +416,9 @@ class MeshQueryExecutor:
             # dictionary.  Skips the former rows-scale unique, which was
             # ~80% of the cold align wall at bench shapes.
             combos = np.arange(len(global_values[0]), dtype=np.int64)
+            cdt = _codes_dtype(max(len(combos), 1))
             dense = self._map_shards(
-                lambda si: mapped_codes(si, 0).astype(np.int64),
+                lambda si: mapped_codes(si, 0).astype(cdt),
                 range(len(tables)),
             )
             key_values = dict(zip(query.groupby_cols, global_values))
@@ -489,11 +495,10 @@ class MeshQueryExecutor:
         )
         # dense codes ride the per-shard dictionary: map each shard's few
         # observed composites into the sorted global combos, then gather
+        cdt = _codes_dtype(max(len(combos), 1))
         dense = []
         for inv, uniq in zip(local_inverse, local_uniques):
-            lut = np.searchsorted(combos, np.clip(uniq, 0, None)).astype(
-                np.int64
-            )
+            lut = np.searchsorted(combos, np.clip(uniq, 0, None)).astype(cdt)
             lut[uniq < 0] = -1
             dense.append(lut[inv])
         key_values = dict(zip(query.groupby_cols, global_values))
@@ -532,46 +537,42 @@ class MeshQueryExecutor:
             off += len(arr)
         return out.reshape(n_devices, width)
 
-    def _fold_on_device(self, tables, codes_key, dense, n_groups,
-                        fold_terms, sharding):
+    def _fold_on_device(self, tables, unmasked_key, dense, fold_terms,
+                        sharding):
         """The folded codes of a filtered query, made on the device: one
         small program (``_fold_program``) over the resident UNMASKED codes
         of the query's keys — the entry an unfiltered query of the same keys
-        puts under its own ``codes_key``, so one entry serves both — and the
-        resident filter columns, packed like the codes (same concat order,
-        same bucketed width: row *i* of a column is row *i* of the codes)
-        and kept in the ``blocks`` segment at their STORED dtype, which is
-        the dtype each shard's host mask compares in (a measure block may be
-        narrowed by value range, ``_wire_dtype``; a constant outside the
-        narrow range would wrap there).  Both are built once per table set;
-        a steady fresh filter costs the dispatch.  Caches the result under
-        ``codes_key`` and returns it.  Inside the ``layout`` phase."""
+        uses, under ``unmasked_key`` — and the resident filter columns,
+        packed like the codes (same concat order, same bucketed width: row
+        *i* of a column is row *i* of the codes) and kept in the ``blocks``
+        segment at their STORED dtype, which is the dtype each shard's host
+        mask compares in (a measure block may be narrowed by value range,
+        ``_wire_dtype``; a constant outside the narrow range would wrap
+        there).  Both are built once per table set and are all the state
+        there is: a steady fresh filter costs the dispatch, and its folded
+        codes are returned, not cached.  Inside the ``layout`` phase."""
         from bqueryd_tpu.parallel import pipeline
 
-        tables_key, n_dev = codes_key[0], codes_key[-1]
-        unmasked_key = codes_key[:3] + (_where_signature(None), n_dev)
-        # get, not contains: the folded entries of fresh filters pass
-        # through this LRU segment, and the unmasked one must outlive them
+        tables_key, n_dev = unmasked_key[0], unmasked_key[-1]
         unmasked = self._codes_cache.get(unmasked_key)
         if unmasked is None:
-            cdt = _codes_dtype(n_groups)
+            self.workingset.evict_under_pressure()
             with pipeline.stage("align"), tracing.detail(
                 "layout_pack", self.timer
             ):
-                packed = self._pack(
-                    [d.astype(cdt) for d in dense], n_dev, cdt.type(-1),
-                    dtype=cdt,
-                )
+                packed = self._pack(dense, n_dev, -1)
             with pipeline.stage("h2d"), tracing.detail(
                 "layout_h2d", self.timer
             ):
                 unmasked = _put(packed, sharding)
+            self._codes_cache.put(unmasked_key, unmasked)
         term_columns, term_ops, constants = zip(*fold_terms)
         columns = []
         for column in term_columns:
             fkey = (tables_key, "where", column, n_dev)
             arr = self._hbm_cache.get(fkey)
             if arr is None:
+                self.workingset.evict_under_pressure()
                 with pipeline.stage("decode"):
                     with tracing.detail("layout_columns", self.timer):
                         cols = [
@@ -590,13 +591,7 @@ class MeshQueryExecutor:
         # the span times a dispatch that returns at once; ``site`` tells
         # the device trace which fold ran
         with tracing.detail("layout_fold", self.timer, site="device"):
-            codes_d = program(unmasked, tuple(columns), constants)
-        self._codes_cache.put(codes_key, codes_d)
-        # after the folded entry, so that where the two do not fit the
-        # budget together the unmasked codes are the ones that stay (a
-        # no-op while they are resident)
-        self._codes_cache.put(unmasked_key, unmasked)
-        return codes_d
+            return program(unmasked, tuple(columns), constants)
 
     # -- execution ----------------------------------------------------------
     def execute(self, tables, query: GroupByQuery,
@@ -697,16 +692,16 @@ class MeshQueryExecutor:
             if (tables_key, "col", col, n_dev) not in self._hbm_cache
         ]
         align_warm = (tables_key, cols_key) in self._align_cache
-        codes_warm = codes_key in self._codes_cache
 
         # shed LRU device cache BEFORE this query adds residency, while the
         # PR-3 HBM watermark sample still reflects the previous steady state
         # (evicting after the allocation failed would be a wedge, not a
-        # plan).  Cold branches only: a fully-warm query adds nothing, and
-        # the memory sample costs a device.memory_stats() round-trip that
-        # must never tax steady-state latency — nor may the shed run before
-        # a warm query's gets refresh their entries' recency.
-        if missing_cols or not codes_warm:
+        # plan).  Only on the branches that add residency — here a missing
+        # column; below a first build of codes or of a filter column: a
+        # warm query, and a fresh filter that folds on the device, add
+        # nothing, and the memory sample costs a device.memory_stats()
+        # round-trip that must never tax steady-state latency.
+        if missing_cols:
             self.workingset.evict_under_pressure()
 
         # chunk-decode prefetch (pipeline stage 1): fire storage decode of
@@ -764,12 +759,14 @@ class MeshQueryExecutor:
 
         codes_d = self._codes_cache.get(codes_key)
         if codes_d is None:
-            # cold path only.  On a cache hit the whole filter evaluation is
-            # skipped — the folded codes ARE the filter.  A filter of scalar
-            # compares on numeric/datetime columns folds ON THE DEVICE, from
-            # resident unmasked codes and resident filter columns
-            # (_fold_on_device): nothing per row crosses the host.  Every
-            # other filter: masks + fold + pack + H2D on the host, as below.
+            # On a cache hit the whole filter evaluation is skipped — the
+            # folded codes ARE the filter.  A filter of scalar compares on
+            # numeric/datetime columns folds ON THE DEVICE, from resident
+            # unmasked codes and resident filter columns (_fold_on_device):
+            # nothing per row crosses the host and nothing is cached, so
+            # such a query misses here every time and hits the unmasked
+            # entry there.  Every other filter: masks + fold + pack + H2D on
+            # the host, as below, cached under ``codes_key``.
             with self._phase("mask"):
                 # all the mask-making the device path has: its constants
                 fold_terms = _device_fold_terms(tables, query)
@@ -791,29 +788,29 @@ class MeshQueryExecutor:
             with self._phase("layout"):
                 if fold_terms is not None:
                     codes_d = self._fold_on_device(
-                        tables, codes_key, dense, n_groups, fold_terms,
-                        sharding,
+                        tables,
+                        (tables_key, "codes", cols_key,
+                         _where_signature(None), n_dev),
+                        dense, fold_terms, sharding,
                     )
                 else:
+                    # a first build of these codes adds residency
+                    self.workingset.evict_under_pressure()
                     # fold the row mask into the codes: masked-out rows
                     # become null (code -1) and vanish from every segment
                     # reduction.  Folds into fresh arrays — cached dense
                     # stays unmasked.
                     with pipeline.stage("align"):
-                        cdt = _codes_dtype(n_groups)
                         with tracing.detail(
                             "layout_fold", self.timer, site="host"
                         ):
                             folded = [
-                                np.where(mask, d, -1).astype(cdt)
-                                if mask is not None
-                                else d.astype(cdt)
+                                d if mask is None
+                                else np.where(mask, d, d.dtype.type(-1))
                                 for d, mask in zip(dense, masks)
                             ]
                         with tracing.detail("layout_pack", self.timer):
-                            packed = self._pack(
-                                folded, n_dev, cdt.type(-1), dtype=cdt
-                            )
+                            packed = self._pack(folded, n_dev, -1)
                     with pipeline.stage("h2d"), tracing.detail(
                         "layout_h2d", self.timer
                     ):
@@ -1187,14 +1184,10 @@ class MeshQueryExecutor:
         codes_d = self._codes_cache.get(codes_key)
         if codes_d is None:
             with self._phase("layout"):
-                with pipeline.stage("align"):
-                    cdt = _codes_dtype(n_groups)
-                    with tracing.detail("layout_fold", self.timer):
-                        folded = [d.astype(cdt) for d in dense]
-                    with tracing.detail("layout_pack", self.timer):
-                        packed = self._pack(
-                            folded, n_dev, cdt.type(-1), dtype=cdt,
-                        )
+                with pipeline.stage("align"), tracing.detail(
+                    "layout_pack", self.timer
+                ):
+                    packed = self._pack(dense, n_dev, -1)
                 with pipeline.stage("h2d"), tracing.detail(
                     "layout_h2d", self.timer
                 ):
@@ -1593,14 +1586,10 @@ class MeshQueryExecutor:
         codes_d = self._codes_cache.get(codes_key)
         if codes_d is None:
             with self._phase("layout"):
-                with pipeline.stage("align"):
-                    cdt = _codes_dtype(n_groups)
-                    with tracing.detail("layout_fold", self.timer):
-                        folded = [d.astype(cdt) for d in dense]
-                    with tracing.detail("layout_pack", self.timer):
-                        packed = self._pack(
-                            folded, n_dev, cdt.type(-1), dtype=cdt,
-                        )
+                with pipeline.stage("align"), tracing.detail(
+                    "layout_pack", self.timer
+                ):
+                    packed = self._pack(dense, n_dev, -1)
                 with pipeline.stage("h2d"), tracing.detail(
                     "layout_h2d", self.timer
                 ):
@@ -1809,9 +1798,10 @@ class MeshQueryExecutor:
         INTO the dense codes here (the derivation signature keys the cache
         entry, so a different filter is a different entry): masked rows
         carry code -1 and vanish from every reduction, exactly like the
-        classic folded codes.  Returns ``(folded dense codes per shard,
-        combo_cols [n_combos, n_cols] global dictionary positions,
-        key_values)`` with combos in sorted composite order."""
+        classic folded codes.  Returns ``(folded dense codes per shard at
+        ``_codes_dtype`` of the combo count, combo_cols [n_combos, n_cols]
+        global dictionary positions, key_values)`` with combos in sorted
+        composite order."""
         from bqueryd_tpu import ops
 
         n_cols = len(dag.group_keys)
@@ -1852,18 +1842,19 @@ class MeshQueryExecutor:
                 codes >= 0, pos[np.clip(codes, 0, None)], np.int64(-1)
             )
 
-        def fold(si, dense_si):
-            m = masks[si]
-            if m is None:
-                return dense_si
-            return np.where(m, dense_si, np.int64(-1))
-
         key_values = dict(zip(dag.group_keys, global_values))
         if n_cols == 1:
-            dense = self._map_shards(
-                lambda si: fold(si, mapped(si, 0).astype(np.int64)),
-                range(n_shards),
-            )
+            cdt = _codes_dtype(max(len(global_values[0]), 1))
+
+            def folded(si):
+                dense_si = mapped(si, 0).astype(cdt)
+                m = masks[si]
+                return (
+                    dense_si if m is None
+                    else np.where(m, dense_si, cdt.type(-1))
+                )
+
+            dense = self._map_shards(folded, range(n_shards))
             combo_cols = np.arange(
                 len(global_values[0]), dtype=np.int64
             )[:, None]
@@ -1895,11 +1886,10 @@ class MeshQueryExecutor:
             if observed
             else np.empty(0, dtype=np.int64)
         )
+        cdt = _codes_dtype(max(len(combos), 1))
         dense = []
         for inv, uniq in composites:
-            lut = np.searchsorted(
-                combos, np.clip(uniq, 0, None)
-            ).astype(np.int64)
+            lut = np.searchsorted(combos, np.clip(uniq, 0, None)).astype(cdt)
             lut[uniq < 0] = -1
             dense.append(lut[inv])
         combo_cols = (

@@ -58,10 +58,11 @@ DEFAULT_POLL_TIMEOUT = 1.0          # seconds per zmq poll tick
 #: RSS suicide threshold, over what the worker itself holds (_check_mem).
 #: The reference capped each of its TEN calc workers per box at 2 GB
 #: (reference bqueryd/worker.py:38, misc/supervisor.conf:19-20); here ONE
-#: calc worker per box owns every chip and every cache, and its default
-#: host cache budgets alone sum past 3 GiB (decode 2 GiB + align 512 MiB +
-#: factorize/result 256 MiB each) — under a 2048 MB limit the watchdog
-#: stopped the worker on the v5e three queries into the 10 M-row dataset.
+#: calc worker per box owns every chip and every cache, and its fixed
+#: host cache budgets alone sum to 2.5 GiB (decode 2 GiB + factorize/result
+#: 256 MiB each; the align segment takes a quarter of THIS limit,
+#: ops/workingset.SHARES) — under a 2048 MB limit the watchdog stopped the
+#: worker on the v5e three queries into the 10 M-row dataset.
 DEFAULT_MEMORY_LIMIT_MB = 10 * 2048
 #: min seconds between post-task gc.collect calls (the reference collected
 #: after every task, reference bqueryd/worker.py:226; see handle())
@@ -1275,7 +1276,10 @@ class WorkerNode(WorkerBase):
         if self._mesh_executor is None:
             from bqueryd_tpu.parallel.executor import MeshQueryExecutor
 
-            self._mesh_executor = MeshQueryExecutor()
+            # memory_limit_mb: what _check_mem holds this process to
+            self._mesh_executor = MeshQueryExecutor(
+                host_limit_bytes=self.memory_limit_mb * 10**6
+            )
         return self._mesh_executor
 
     @property

@@ -113,8 +113,18 @@ def _slow(tables, dag):
 
 
 def _fast(tables, dag, mex=None):
+    from bqueryd_tpu.parallel.executor import _codes_dtype
+
     mex = mex or MeshQueryExecutor()
-    return dict(mex.execute_dag(tables, dag))
+    out = dict(mex.execute_dag(tables, dag))
+    # every DAG alignment holds its folded dense codes at the width they
+    # are packed at (PR 30), for one key and for composites alike
+    for key, entry in list(mex._align_cache._data.items()):
+        if key[1] == "dagalign":
+            dense, combo_cols, _key_values = entry
+            want = _codes_dtype(max(len(combo_cols), 1))
+            assert [d.dtype for d in dense] == [want] * len(dense)
+    return out
 
 
 def _frames(payload_a, payload_b, sort_cols):

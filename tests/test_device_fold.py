@@ -3,7 +3,8 @@ scalar compares on numeric / datetime columns folds into the group codes on
 the device, from resident unmasked codes and resident filter columns, by
 one small jitted program — bit for bit what the host's ``build_mask`` +
 ``np.where`` + ``_pack`` gives, pad rows included; every other filter
-folds on the host as it did.
+folds on the host as it did.  The folded codes of a device fold are not
+kept (PR 30): the two resident arrays are the state.
 """
 
 import numpy as np
@@ -92,12 +93,16 @@ def run(ex, tables, groupby, where, aggs=(("v", "sum", "s"),), **kw):
     )
 
 
-def cached_codes(ex, tables, query):
-    n_dev = ex.mesh.devices.size
-    key = (
+def codes_key(ex, tables, query):
+    return (
         tuple(_table_key(t) for t in tables), "codes",
-        tuple(query.groupby_cols), _where_signature(query), n_dev,
+        tuple(query.groupby_cols), _where_signature(query),
+        ex.mesh.devices.size,
     )
+
+
+def cached_codes(ex, tables, query):
+    key = codes_key(ex, tables, query)
     assert key in ex._codes_cache
     return ex._codes_cache.get(key)
 
@@ -119,8 +124,11 @@ def host_folded(ex, tables, query):
     return PACK(folded, n_dev, cdt.type(-1), dtype=cdt)
 
 
-def assert_folded_like_the_host(ex, tables, query):
-    got = cached_codes(ex, tables, query)
+def assert_folded_like_the_host(spies, ex, tables, query):
+    """``spies["device"].last``: what the last ``_fold_on_device`` returned,
+    which no cache holds."""
+    assert codes_key(ex, tables, query) not in ex._codes_cache
+    got = spies["device"].last
     want = host_folded(ex, tables, query)
     assert got.dtype == want.dtype and got.shape == want.shape
     assert got.sharding == NamedSharding(ex.mesh, P(ex.axis_name, None))
@@ -132,11 +140,12 @@ class Spy:
     """Counts the calls of a function it stands in for."""
 
     def __init__(self, fn):
-        self.fn, self.calls = fn, 0
+        self.fn, self.calls, self.last = fn, 0, None
 
     def __call__(self, *args, **kwargs):
         self.calls += 1
-        return self.fn(*args, **kwargs)
+        self.last = self.fn(*args, **kwargs)
+        return self.last
 
 
 @pytest.fixture
@@ -165,7 +174,7 @@ def test_device_folded_codes_are_the_hosts(data, spies, op, column, n_dev):
     ex = MeshQueryExecutor(mesh=make_mesh(n_dev))
     query, _ = run(ex, tables, ["k2"], [[column, op, constants(df)[column]]])
     assert spies["device"].calls == 1 and spies["host"].calls == 0
-    assert_folded_like_the_host(ex, tables, query)
+    assert_folded_like_the_host(spies, ex, tables, query)
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
@@ -183,7 +192,7 @@ def test_a_constant_outside_the_narrowed_range_compares_in_the_stored_dtype(
                      aggs=[("narrow", "sum", "s")])
     assert spies["device"].calls == 1 and spies["host"].calls == 0
     np.testing.assert_array_equal(
-        np.asarray(cached_codes(ex, tables, query)),
+        np.asarray(spies["device"].last),
         np.asarray(cached_codes(ex, tables, GroupByQuery(["k"], []))),
     )
     want = df.groupby("k")["narrow"].sum()
@@ -201,7 +210,7 @@ def test_and_ed_terms_fold_like_the_hosts(data, spies, where, n_dev):
     ex = MeshQueryExecutor(mesh=make_mesh(n_dev))
     query, _ = run(ex, tables, ["k"], where)
     assert spies["device"].calls == 1 and spies["host"].calls == 0
-    assert_folded_like_the_host(ex, tables, query)
+    assert_folded_like_the_host(spies, ex, tables, query)
 
 
 # -- the answers ---------------------------------------------------------------
@@ -312,7 +321,7 @@ def test_a_fresh_constant_compiles_nothing_and_packs_nothing(data, spies):
     names = set(profiler.programs)
     for constant in (2.5, 3.75, 0.015625, 9.0):
         query, _ = run(ex, tables, ["k2"], [["f32", ">", constant]])
-        assert_folded_like_the_host(ex, tables, query)
+        assert_folded_like_the_host(spies, ex, tables, query)
     assert profiler.jit_cache_misses == misses
     assert spies["pack"].calls == packs
     assert spies["device"].calls == 5
@@ -328,19 +337,17 @@ def test_a_fresh_constant_compiles_nothing_and_packs_nothing(data, spies):
 def test_twenty_fresh_filters_build_the_resident_arrays_once(
         data, spies, budget):
     """Alternating two key sets.  ``tight``: the codes segment holds the two
-    unmasked entries and no more than one folded entry of each beside
-    them, so folded entries are evicted all the time and the unmasked ones
-    must outlive them.  ``one-entry`` (one key set): the unmasked entry
-    and a folded one do not fit together, and the unmasked one stays."""
+    unmasked entries and not a byte more; ``one-entry`` (one key set): the
+    one.  Nothing else enters the segment, so nothing is ever evicted."""
     df, tables = data
     ex = MeshQueryExecutor(mesh=make_mesh(4))
     width = int(ops.program_bucket(-(-CUTS[-1] // 4), fine=True))
     k_bytes, k2_bytes = 4 * width, 4 * width * 2      # int8, int16
     key_sets = [["k"], ["k2"]]
     if budget == "tight":
-        ex._codes_cache.max_bytes = 2 * (k_bytes + k2_bytes) + k_bytes // 2
+        ex._codes_cache.max_bytes = k_bytes + k2_bytes
     elif budget == "one-entry":
-        ex._codes_cache.max_bytes = k2_bytes + k2_bytes // 2
+        ex._codes_cache.max_bytes = k2_bytes
         key_sets = [["k2"]]
     for i in range(20):
         keys = key_sets[i % len(key_sets)]
@@ -350,21 +357,27 @@ def test_twenty_fresh_filters_build_the_resident_arrays_once(
             want = df[df["f32"] > np.float32(constant)].groupby("k2")["v"].sum()
             assert got.sort_values("k2")["s"].tolist() == want.tolist()
         else:
-            assert_folded_like_the_host(ex, tables, query)
+            assert_folded_like_the_host(spies, ex, tables, query)
     # an unmasked codes array a key set, the filter column, the measure
     assert spies["pack"].calls == len(key_sets) + 2
     assert spies["device"].calls == 20 and spies["host"].calls == 0
     stats = ex._codes_cache.stats()
-    assert (stats["evictions"] > 0) == (budget != "roomy")
+    assert stats["evictions"] == 0 and stats["rejected"] == 0
+    assert stats["entries"] == len(key_sets)
     assert ex._hbm_cache.stats()["entries"] == 2
 
 
-def test_the_same_filter_twice_hits_the_codes_cache(data, spies):
+def test_the_same_filter_twice_folds_twice_from_one_resident_entry(
+        data, spies):
+    """A repeat is the worker's result cache's to answer, before the
+    executor runs; here it is one more dispatch."""
     _df, tables = data
     ex = MeshQueryExecutor(mesh=make_mesh(4))
     _, first = run(ex, tables, ["k"], [["wide", "<", 12345]])
+    packs = spies["pack"].calls
     _, again = run(ex, tables, ["k"], [["wide", "<", 12345]])
-    assert spies["device"].calls == 1
+    assert spies["device"].calls == 2 and spies["pack"].calls == packs
+    assert ex._codes_cache.stats()["entries"] == 1
     pd.testing.assert_frame_equal(first, again)
 
 
@@ -383,7 +396,7 @@ def test_one_unmasked_entry_serves_the_unfiltered_query_too(
             kept.groupby("k")["v"].sum().tolist())
     # the unmasked codes once, the filter column, the measure column
     assert spies["pack"].calls == 3
-    assert ex._codes_cache.stats()["entries"] == 2
+    assert ex._codes_cache.stats()["entries"] == 1
 
 
 # -- the tracing: one name, two sites ------------------------------------------------

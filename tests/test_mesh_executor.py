@@ -80,6 +80,24 @@ def assert_frames_match(got, expected, key_cols, **kw):
     )
 
 
+def assert_dense_at_the_codes_dtype(dense, combos):
+    """The alignment hands its dense codes over at the width every consumer
+    packs them at (PR 30): no per-row int64 copy is kept."""
+    from bqueryd_tpu.parallel.executor import _codes_dtype
+
+    want = _codes_dtype(max(len(combos), 1))
+    assert [d.dtype for d in dense] == [want] * len(dense)
+
+
+def aligned(ex, tables, gcols):
+    from bqueryd_tpu.parallel.executor import _table_key
+
+    entry = ex._align_cache.get(
+        (tuple(_table_key(t) for t in tables), tuple(gcols))
+    )
+    return entry[0], entry[1]
+
+
 def test_mesh_uses_all_devices(mesh):
     assert mesh.devices.size == 8
 
@@ -375,6 +393,8 @@ def test_cold_path_hits_disk_sidecars_and_matches(sharded, mesh):
         warm = hostmerge.payload_to_dataframe(
             hostmerge.merge_payloads([ex.execute(tables, query)])
         )
+        # one key; two keys factorized (a composite-sidecar miss)
+        assert_dense_at_the_codes_dtype(*aligned(ex, tables, gcols))
         # sidecars must exist next to the first shard now
         first = tables[0].rootdir
         assert os.path.isfile(
@@ -397,6 +417,8 @@ def test_cold_path_hits_disk_sidecars_and_matches(sharded, mesh):
             )
         finally:
             ops_mod.factorize = real_factorize
+        # one key; two keys read back (a composite-sidecar hit)
+        assert_dense_at_the_codes_dtype(*aligned(ex, tables, gcols))
         assert_frames_match(cold, warm, gcols)
         expected = (
             df.groupby(gcols, as_index=False)["fare_amount"]
@@ -482,6 +504,8 @@ def test_threaded_alignment_matches_sequential(sharded, mesh, monkeypatch):
         )
         s_dense, s_combos, s_cards, s_vals = seq
         p_dense, p_combos, p_cards, p_vals = par
+        assert_dense_at_the_codes_dtype(s_dense, s_combos)
+        assert_dense_at_the_codes_dtype(p_dense, p_combos)
         assert s_cards == p_cards
         np.testing.assert_array_equal(s_combos, p_combos)
         for a, b in zip(s_dense, p_dense):

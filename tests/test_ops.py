@@ -996,6 +996,7 @@ def test_worker_degrades_mesh_overflow_to_engine(tmp_path, caplog):
     worker._engine = None
     worker._mesh_executor = None
     worker._result_cache = None
+    worker.memory_limit_mb = 2048   # what bounds the executor's align segment
     import logging as _logging
 
     worker.logger = _logging.getLogger("test-overflow")

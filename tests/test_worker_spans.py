@@ -199,13 +199,19 @@ def test_with_the_switch_set_detail_spans_nest_in_their_phase(node, monkeypatch,
 
     monkeypatch.setattr(SpanRecorder, "record", noting)
     # a measure column no query has read: decode + pack on this very pass.
-    # (A bundle's codes carry no mask, so they fold once per key: it groups
+    # (A bundle's codes carry no mask, so they pack once per key: it groups
     # by a key no bundle has had.  It takes no memory sample.)
     reply = node["run"](kind, measure=f"cold_{kind}",
                         key="u" if kind == "bundle" else "k")
     names = span_names(reply)
     assert set(names) - set(ENCLOSING) == SPAN_NAMES[kind]
-    expected = set(ENCLOSING) - ({"mem_sample"} if kind == "bundle" else set())
+    # a bundle's and a DAG's codes carry no mask of ``execute``'s kind and
+    # come from the alignment at their width (PR 30): nothing folds there
+    expected = set(ENCLOSING) - {
+        "solo": set(), "bundle": {"mem_sample", "layout_fold"},
+        "dag": {"layout_fold"},
+    }[kind]
+    assert "layout_fold" not in set(names) - expected
     assert expected <= set(names), sorted(expected - set(names))
     # the look-ups (result cache; the delta cache's own, for a plain
     # mergeable shape), then the stores; a bundle probes once
