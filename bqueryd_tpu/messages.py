@@ -381,7 +381,9 @@ SPAN_SCHEMA = {
                         "there is one)",
     "aggregate_wait": "detail span in kernel: block_until_ready — the "
                       "device's time as the host waits for it; tagged "
-                      "effective_strategy",
+                      "effective_strategy and, where the mesh executor "
+                      "summed in float64 on an accelerator, float_sum "
+                      "(dense | sorted)",
     "send": "detail annotation: the reply's send; its seconds ride the "
             "next calc reply's phase_timings['post_prev']",
     "post": "detail annotation: Done + throttled gc.collect() + RSS check "

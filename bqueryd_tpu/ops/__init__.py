@@ -103,6 +103,7 @@ from bqueryd_tpu.ops.groupby import (  # noqa: E402
     combine_partials,
     expand_mask_by_group,
     finalize,
+    float_sum_route,
     groupby_aggregate,
     groupby_count_distinct,
     groupby_sorted_count_distinct,
@@ -139,6 +140,7 @@ __all__ = [
     "expand_mask_by_group",
     "host_partial_tables",
     "host_sorted_count_distinct",
+    "float_sum_route",
     "kernel_route",
     "partial_tables",
     "partial_tables_bucketized",

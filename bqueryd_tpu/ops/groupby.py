@@ -15,12 +15,19 @@ Design:
   bfloat16), and block sums are bounded below 2^24 so the MXU's float32
   accumulation is exact; per-block tables are then recombined in uint64
   (mod-2^64 arithmetic == two's complement) — bit-exact for the full int64
-  range.  Counts ride along as a row of ones in the same matmul.  min/max,
-  float64 measures, and cardinalities above ``matmul_groups_limit()`` use
-  the scatter path: exact 16-bit-limb int32 scatters over 64Ki row blocks
-  (mod-2^32 wrap recovered by a uint32 bitcast), switching to a sort +
-  prefix-diff reduction at extreme cardinality where the blocked table
-  would outgrow ``_MAX_BLOCK_SEGMENTS`` — never the emulated-s64 scatter.
+  range.  Counts ride along as a row of ones in the same matmul.  min/max
+  and cardinalities above ``matmul_groups_limit()`` use the scatter path:
+  exact 16-bit-limb int32 scatters over 64Ki row blocks (mod-2^32 wrap
+  recovered by a uint32 bitcast), switching to a sort + prefix-diff
+  reduction at extreme cardinality where the blocked table would outgrow
+  ``_MAX_BLOCK_SEGMENTS`` — never the emulated-s64 scatter.  A float64 sum
+  (and an integer mean, which accumulates in float64 like pandas) rides
+  neither: on either route it is :func:`_float64_segment_sum` — on an
+  accelerator a dense masked reduction in float64 up to
+  ``_DENSE_SUM_GROUPS`` groups (no sort, no gather, no scatter) and the
+  sort + prefix-diff above — while its ``rows`` and the mean's count take
+  the route's own form, so such a query goes by the MXU route wherever
+  that is allowed: the counts are two rows of the one-hot dot.
   A pure-NumPy twin (:func:`host_partial_tables`) serves latency-aware
   host routing for small inputs;
 * results are produced as **partial tables** (pytrees of fixed-width arrays,
@@ -127,6 +134,17 @@ _SUM_BLOCK = 65536
 #: stops paying for itself in HBM; switch to the sort-based path
 _MAX_BLOCK_SEGMENTS = 1 << 25
 
+#: up to this many groups a float64 per-group sum on an accelerator is the
+#: dense masked reduction (:func:`_dense_segment_sum`), whose cost grows with
+#: the group count; above it the sort + prefix-diff, whose cost does not.
+#: OBSERVED, not set by anyone: standalone timings of the two at 11 010 048
+#: rows on a TPU v5e (PERF.md section 6, PR 31) read 2.3 ms at 10 groups,
+#: 18 at 256, 136 at 2 048, 271 at 4 096 and 406 at 6 144 for the dense sum
+#: (0.066 ms a group) against 346-410 for the sorted one at any count: they
+#: cross at about 5 000 groups, and the constant is the last power of two
+#: where the dense form still wins by more than two to one
+_DENSE_SUM_GROUPS = 2048
+
 
 #: kernel routes partial_tables accepts as a planner hint (None == "auto").
 #: "matmul" is advisory — every profitability/backend guard still applies —
@@ -146,7 +164,12 @@ def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
     and no ``blocks x groups`` table, so cost is independent of ``n_groups``.
     For int64 the wrapping (mod 2^64) prefix sums difference back exactly —
     bit-exact for the full range; for float64 accumulation the prefix-diff
-    matches direct summation to ~1 ulp of the running prefix."""
+    matches direct summation to ~1 ulp of the running prefix.
+
+    Callers left: the int64 sums of :func:`_int64_segment_sum` past the
+    ``blocks x groups`` budget or under a binding ``"sort"`` hint, and the
+    float64 sums of :func:`_float64_segment_sum` above ``_DENSE_SUM_GROUPS``
+    groups on an accelerator or under that hint."""
     codes_s, order = lax.sort(
         (safe, jnp.arange(safe.shape[0], dtype=jnp.int32)), num_keys=1
     )
@@ -159,6 +182,58 @@ def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
     zero = jnp.zeros(1, acc_dtype)
     bounds = jnp.concatenate([zero, prefix])[ends]
     return jnp.diff(jnp.concatenate([zero, bounds]))
+
+
+def _dense_segment_sum(contrib, safe, n_groups):
+    """Per-group float sums with no per-row data movement — no sort, no
+    gather, no scatter: for each group ``g`` the rows of a block whose code
+    is ``g`` are summed (``sum(where(safe == g, contrib, 0))``), then the
+    blocks are.  One fused compare-select-reduce over ``[groups, rows]``
+    that XLA never materialises; every add is in ``contrib.dtype`` (float64
+    for every caller: no float32 partial, no MXU limb), as a tree over
+    ``_SUM_BLOCK``-row blocks, so a group's sum only ever meets its own
+    values — at least as accurate as the prefix difference it replaces.
+    ``contrib`` is already zero on the rows that do not count (null
+    measure, null key, filtered out), where ``safe`` reads group 0.  Cost
+    grows with ``n_groups``: callers bound it by ``_DENSE_SUM_GROUPS``."""
+    n = contrib.shape[0]
+    n_blocks = -(-n // _SUM_BLOCK)
+    pad = n_blocks * _SUM_BLOCK - n
+    v = jnp.pad(contrib, (0, pad)).reshape(n_blocks, _SUM_BLOCK)
+    c = jnp.pad(safe, (0, pad)).reshape(n_blocks, _SUM_BLOCK)
+    hit = c[None] == jnp.arange(n_groups, dtype=c.dtype)[:, None, None]
+    zero = jnp.zeros((), contrib.dtype)
+    return jnp.where(hit, v[None], zero).sum(axis=2).sum(axis=1)
+
+
+def _float_sum_form(n_groups, force_sort=False):
+    """Which reduction a float64 per-group sum takes, from what the trace
+    can observe (the backend and the group count, both static): ``"sorted"``
+    under a binding sort hint and above ``_DENSE_SUM_GROUPS`` groups on an
+    accelerator, ``"dense"`` up to it, None on a CPU backend (native
+    float64: the plain scatter-add is the cheap one there)."""
+    if force_sort:
+        return "sorted"
+    if jax.default_backend() == "cpu":
+        return None
+    return "dense" if n_groups <= _DENSE_SUM_GROUPS else "sorted"
+
+
+def _float64_segment_sum(contrib, safe, n_groups, force_sort=False):
+    """The per-group sum of a float64 ``contrib`` for BOTH kernel routes
+    (the MXU route's ``f64_scatter`` plan and the scatter route's float
+    branch), so a binding hint changes the counts' route and not the sum."""
+    form = _float_sum_form(n_groups, force_sort)
+    if form == "dense":
+        return _dense_segment_sum(contrib, safe, n_groups)
+    if form == "sorted":
+        # no native f64 on TPU: an emulated-f64 scatter is the wide-scatter
+        # cost this module exists to avoid; the sort + prefix-diff uses only
+        # cheap elementwise wide adds
+        return _sorted_segment_sum(
+            contrib, safe, n_groups, acc_dtype=contrib.dtype
+        )
+    return jax.ops.segment_sum(contrib, safe, num_segments=n_groups)
 
 
 def _int64_segment_sum(values, valid, safe, n_groups, force_sort=False):
@@ -284,20 +359,21 @@ def matmul_route_allowed(n, n_groups):
 
 
 def _matmul_profitable(measures, ops, n, n_groups):
-    """MXU path only when within budget AND some sum/count actually rides the
-    matmul (min/max and float64 sums scatter regardless, so a query made only
-    of those gains nothing from building the one-hot)."""
+    """MXU path only when within budget AND some sum/count rides the matmul
+    beside ``rows``: a query made only of min/max gains too little from
+    building the one-hot (its extrema scatter regardless).  A float64 sum
+    does not ride the dot either, but it does not decline the route: its
+    ``rows`` — and a mean's count — are rows of the stacked dot, where the
+    scatter route pays a blocked int32 scatter of every row for each
+    (97 ms each at 11 M rows on a v5e, at any group count, against 2-51 ms
+    for the two-row dot from 10 groups to the top of the allowed range:
+    PERF.md section 6, PR 31), and the sum itself is
+    :func:`_float64_segment_sum` on either route."""
     if not matmul_route_allowed(n, n_groups):
         return False
-    x64 = bool(jax.config.jax_enable_x64)
-    for values, op in zip(measures, ops):
-        if op in ("count", "count_na"):
-            return True
-        if op in ("sum", "mean") and not (
-            x64 and jnp.dtype(values.dtype) == jnp.float64
-        ):
-            return True
-    return not measures  # rows-count-only query still benefits
+    if not measures:
+        return True  # rows-count-only query still benefits
+    return any(op in ("count", "count_na", "sum", "mean") for op in ops)
 
 
 def _hicard_matmul_profitable(measures, ops, n, n_groups):
@@ -365,8 +441,11 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
                        "aggs": tuple of per-measure partial dicts}.
 
     Sums and counts route to the MXU one-hot matmul (module docstring) when
-    the cardinality is within :func:`matmul_groups_limit`; min/max, float64
-    measures, and high-cardinality queries use segment scatters.
+    the cardinality is within :func:`matmul_groups_limit`; min/max-only and
+    high-cardinality queries use segment scatters.  A float64 sum or mean
+    takes the MXU route for its counts too (``auto``, ``"matmul"`` and
+    ``"matmul!"`` are then one traced program) and sums by
+    :func:`_float64_segment_sum` on whichever route it goes.
 
     ``strategy`` is the planner's route hint (:data:`KERNEL_STRATEGIES`):
     ``"scatter"`` goes straight to the blocked scatters, ``"sort"`` to the
@@ -685,6 +764,28 @@ def kernel_route(strategy, measures, ops, n, n_groups):
     return "scatter"
 
 
+def float_sum_route(strategy, measures, ops, n, n_groups):
+    """Which form the float64-accumulated sums of this dispatch take —
+    ``"dense"`` or ``"sorted"`` (:func:`_float_sum_form`) — or None where it
+    has none or the backend scatter-adds float64 natively.  The host-side
+    twin of the kernels' trace-time choice, like :func:`kernel_route` (same
+    arguments): the ``float_sum`` tag of the ``aggregate_wait`` detail span
+    and the label of ``bqueryd_tpu_float_sum_total``."""
+    route = kernel_route(strategy, measures, ops, n, n_groups)
+    wide = False
+    for values, op in zip(measures, ops):
+        if op not in ("sum", "mean"):
+            continue
+        dt = jnp.dtype(values.dtype)
+        if not jnp.issubdtype(dt, jnp.floating):
+            wide = wide or op == "mean"  # integer means float like pandas
+        elif route != "matmul" or dt == jnp.float64:
+            wide = True  # a float32 sum is bf16 limbs on the MXU route only
+    if not (wide and jax.config.jax_enable_x64):
+        return None
+    return _float_sum_form(int(n_groups), force_sort=strategy == "sort")
+
+
 def _segment_extremum(kind, values, present, safe, n_groups):
     """Per-group min/max via segment scatter; absent rows carry the identity
     fill so they never win (empty groups are masked later by count==0)."""
@@ -951,17 +1052,7 @@ def _partial_tables_mm(codes, measures, ops, n_groups, mask=None,
             _, _, values, present_row = plan
             present = valid & ~_null_mask(values)
             contrib = jnp.where(present, values, 0).astype(jnp.float64)
-            if jax.default_backend() != "cpu":
-                # no native f64 on TPU: sort+prefix-diff beats the
-                # emulated-f64 scatter (same choice as the scatter path)
-                s = _sorted_segment_sum(
-                    contrib, safe, n_groups, acc_dtype=jnp.float64
-                )
-            else:
-                s = jax.ops.segment_sum(
-                    contrib, safe, num_segments=n_groups
-                )
-            partial = {"sum": s}
+            partial = {"sum": _float64_segment_sum(contrib, safe, n_groups)}
             if op == "mean":
                 partial["count"] = int_row(present_row).astype(jnp.int64)
             aggs.append(partial)
@@ -1044,18 +1135,12 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
                 # (the x64 default here); a float32 accumulator (x64 off)
                 # stays on the scatter even under a binding "sort" hint —
                 # catastrophic cancellation is worse than the hint miss
-                if contrib.dtype == jnp.float64 and (
-                    force_sort or jax.default_backend() != "cpu"
-                ):
-                    # no native f64 on TPU: an emulated-f64 scatter is the
-                    # wide-scatter cost this module exists to avoid; the
-                    # sort+prefix-diff reduction uses only cheap elementwise
-                    # wide adds (backend read at trace time, outside data
-                    # flow)
+                if contrib.dtype == jnp.float64:
+                    # backend and group count read at trace time, outside
+                    # data flow
                     partial = {
-                        "sum": _sorted_segment_sum(
-                            contrib, safe, n_groups,
-                            acc_dtype=contrib.dtype,
+                        "sum": _float64_segment_sum(
+                            contrib, safe, n_groups, force_sort=force_sort
                         )
                     }
                 else:

@@ -243,6 +243,10 @@ class MeshQueryExecutor:
         #: (post-guards) — the worker surfaces it as ``effective_strategy``
         #: in calc replies and the ``kernel`` trace span
         self.last_effective_strategy = None
+        #: detail (BQUERYD_TPU_PROFILE=1 only, else None): the form the
+        #: float64 sums of the last execute() took, ops.float_sum_route —
+        #: the worker tags the ``aggregate_wait`` span ``float_sum`` with it
+        self.last_float_sum = None
         #: how the last execute() merged partials across the mesh
         #: ("device" | "host") — the worker surfaces it as the reply
         #: envelope's ``merge_mode`` key
@@ -614,6 +618,7 @@ class MeshQueryExecutor:
                 signature=str(query.signature())[:120],
             )
         self.last_effective_strategy = None  # set at the kernel dispatch
+        self.last_float_sum = None           # ditto, under the switch
         self.last_merge_mode = None          # set once the mode resolves
         if strategy in (None, "auto", "host"):
             # "host" is meaningless inside a mesh program; the worker should
@@ -917,6 +922,11 @@ class MeshQueryExecutor:
                 int(codes_d.shape[1]), n_prog,
             )
             self.last_effective_strategy = route
+            if tracing.detail_enabled():
+                self.last_float_sum = ops.float_sum_route(
+                    strategy, per_agg_d, tuple(query.ops),
+                    int(codes_d.shape[1]), n_prog,
+                )
             from bqueryd_tpu.obs import profile as obs_profile
 
             profiler = obs_profile.profiler()

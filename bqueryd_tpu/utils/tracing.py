@@ -137,6 +137,12 @@ def trace_span(name):
 _NO_DETAIL = contextlib.nullcontext()
 
 
+def detail_enabled():
+    """Whether this process runs under the traced run's switch (read per
+    call): what every detail site, span or tag, asks first."""
+    return os.environ.get("BQUERYD_TPU_PROFILE") == "1"
+
+
 def detail(name, timer=None, **args):
     """A DETAIL span: exists only under BQUERYD_TPU_PROFILE=1 (read per
     call), else the shared no-op.  Under the switch it opens a
@@ -147,7 +153,7 @@ def detail(name, timer=None, **args):
     as nested by its interval.  It never touches
     ``timer.timings``, a debit or a histogram.  Loop thread only: a
     recorder's span list is not locked."""
-    if os.environ.get("BQUERYD_TPU_PROFILE") != "1":
+    if not detail_enabled():
         return _NO_DETAIL
     return _detail(name, getattr(timer, "recorder", None), args)
 

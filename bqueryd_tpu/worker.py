@@ -1582,6 +1582,9 @@ class WorkerNode(WorkerBase):
         # kernel span (satellite: hints used to normalize silently and
         # nothing could tell what executed)
         self._last_effective_strategy = None
+        # detail (BQUERYD_TPU_PROFILE=1 only): the form the mesh executor's
+        # float64 sums took, the aggregate_wait span's ``float_sum`` tag
+        self._last_float_sum = None
         # how this query's partials merged ("device" = ICI-mesh collective,
         # "host" = hostmerge.merge_payloads, "none" = single payload, no
         # merge) — the reply envelope's ``merge_mode`` key
@@ -1642,6 +1645,7 @@ class WorkerNode(WorkerBase):
                 self._last_effective_strategy = (
                     self.mesh_executor.last_effective_strategy
                 )
+                self._last_float_sum = self.mesh_executor.last_float_sum
                 self._last_merge_mode = self.mesh_executor.last_merge_mode
                 return result
             except ops_mod.CompositeOverflow:
@@ -1958,15 +1962,31 @@ class WorkerNode(WorkerBase):
                 payload = self._execute_dag(tables, dag, timer)
             effective = getattr(self, "_last_effective_strategy", None)
             merge_mode = getattr(self, "_last_merge_mode", None)
+            # detail: the form the mesh executor's float64 sums took (dense
+            # / sorted), which it reports under the profile switch only —
+            # where the aggregate_wait span that carries it exists
+            float_sum = (
+                getattr(self, "_last_float_sum", None)
+                if query is not None else None
+            )
             if recorder is not None and effective:
                 # the kernel span carries what the executor actually
                 # compiled post-guards — rpc.trace() waterfalls can now
                 # tell a promoted matmul from a silently-normalized hint
                 for span in recorder.spans:
                     if span.get("name") in ("kernel", "aggregate_wait"):
-                        span.setdefault("tags", {})[
-                            "effective_strategy"
-                        ] = effective
+                        tags = span.setdefault("tags", {})
+                        tags["effective_strategy"] = effective
+                        if float_sum and span["name"] == "aggregate_wait":
+                            tags["float_sum"] = float_sum
+            if float_sum:
+                self.metrics.counter(
+                    "bqueryd_tpu_float_sum_total",
+                    "mesh-executor queries by the form their float64 sums "
+                    "took (ops.float_sum_route); counted on workers "
+                    "started with BQUERYD_TPU_PROFILE=1 only",
+                    labels={"form": float_sum},
+                ).inc()
             if recorder is not None and self._last_chunk_prune:
                 # zone-map pruning effect on the trace: the prune span
                 # says how many chunks the decode stages never touched

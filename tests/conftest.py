@@ -91,3 +91,38 @@ os.environ.setdefault("BQUERYD_TPU_HOST_KERNEL_ROWS", "0")
 # far slower than the scatter there); pin it ON for the suite so the CPU
 # test backend keeps exercising the MXU kernel paths (limb plans, Pallas).
 os.environ.setdefault("BQUERYD_TPU_FORCE_MATMUL", "1")
+
+
+@pytest.fixture
+def groupby_as_accelerator(monkeypatch):
+    """Let ``ops.groupby`` — and nothing else — read the backend as "tpu"
+    while it traces: its float64 sums then take the accelerator's forms
+    (dense at few groups, sorted above) where this CPU backend scatter-adds
+    them, and the MXU route needs no force flag.  A trace made under the
+    patch must not answer an unpatched call of the same shapes, nor the
+    reverse, so the kernel entries and the mesh-program cache start and end
+    empty.  Yields the module."""
+    import sys
+
+    import jax
+
+    import bqueryd_tpu.ops.groupby  # noqa: F401
+    from bqueryd_tpu.parallel import executor
+
+    module = sys.modules["bqueryd_tpu.ops.groupby"]
+
+    class AsAccelerator:
+        default_backend = staticmethod(lambda: "tpu")
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+    def clear():
+        module._partial_tables_mm.__wrapped__.clear_cache()
+        module._partial_tables_scatter.__wrapped__.clear_cache()
+        executor._mesh_program.cache_clear()
+
+    clear()
+    monkeypatch.setattr(module, "jax", AsAccelerator())
+    yield module
+    clear()

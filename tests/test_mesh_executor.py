@@ -789,3 +789,47 @@ def test_hicard_pallas_route_through_mesh(tmp_path, monkeypatch):
     )
     np.testing.assert_array_equal(got["k"].to_numpy(), exp["k"].to_numpy())
     np.testing.assert_array_equal(got["s"].to_numpy(), exp["s"].to_numpy())
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("profile", ["1", None], ids=["traced", "untraced"])
+def test_float64_mean_rides_the_matmul_route_with_a_dense_sum(
+        sharded, groupby_as_accelerator, monkeypatch, n_devices, profile):
+    """PR 31: a float64 mean over few groups goes by the MXU route (its
+    counts are rows of the dot) and sums densely, per device of the mesh,
+    whatever matmul hint the planner sends; the form is reported only under
+    the profile switch."""
+    df, tables = sharded
+    if profile:
+        monkeypatch.setenv("BQUERYD_TPU_PROFILE", profile)
+    else:
+        monkeypatch.delenv("BQUERYD_TPU_PROFILE", raising=False)
+    query = GroupByQuery(
+        ["passenger_count"], [["fare_amount", "mean", "m"]],
+        [["trip_distance", ">", 1.5]],
+    )
+    executor = MeshQueryExecutor(mesh=make_mesh(n_devices))
+    kept = df[df["trip_distance"] > 1.5]
+    expected = (
+        kept.groupby("passenger_count")["fare_amount"].mean()
+        .rename("m").reset_index()
+    )
+    from bqueryd_tpu.parallel import executor as ex_mod
+
+    traces = set()
+    for hint in (None, "matmul", "matmul!"):
+        payload = executor.execute(tables, query, strategy=hint)
+        traces.add(ex_mod._mesh_program.cache_info().misses)
+        assert executor.last_effective_strategy == "matmul"
+        assert executor.last_float_sum == ("dense" if profile else None)
+        got = hostmerge.payload_to_dataframe(
+            hostmerge.merge_payloads(
+                [ResultPayload.from_bytes(payload.to_bytes())]
+            )
+        )
+        assert_frames_match(got, expected, ["passenger_count"], rtol=1e-12)
+    assert len(traces) == 1, "three hints, ONE traced program"
+    executor.execute(tables, query, strategy="scatter")
+    assert ex_mod._mesh_program.cache_info().misses not in traces
+    assert executor.last_effective_strategy == "scatter"
+    assert executor.last_float_sum == ("dense" if profile else None)

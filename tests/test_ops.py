@@ -1276,3 +1276,317 @@ def test_term_mask_wedged_rejects_device_arrays(monkeypatch):
     np.testing.assert_array_equal(np.asarray(host), [False, True, False])
     with pytest.raises(TypeError, match="wedged"):
         pred.term_mask(jnp.array([1, 2, 3]), "==", 2)
+
+
+# ---------------------------------------------------------------------------
+# float64 sums on an accelerator: dense at few groups, sorted above (PR 31)
+# ---------------------------------------------------------------------------
+
+def _float_case(name, n_groups=10):
+    """(codes, float64 values) for one named shape of input."""
+    m = _groupby_module()
+    rng = np.random.default_rng(31)
+    n = 3 * m._SUM_BLOCK
+    codes = rng.integers(0, n_groups, n).astype(np.int32)
+    values = np.round(rng.gamma(1.2, 2.0, n), 2)
+    if name == "nan_measures":
+        values[rng.random(n) < 0.05] = np.nan
+    elif name == "null_codes":
+        codes[rng.random(n) < 0.1] = -1
+    elif name == "empty_group":
+        codes[codes == 3] = 4
+    elif name == "one_group":
+        codes[:] = n_groups - 1
+    elif name == "ragged_rows":  # not a multiple of the block
+        n = 2 * m._SUM_BLOCK + 12_345
+        codes, values = codes[:n], values[:n]
+    elif name == "mixed_magnitudes":
+        # both signs, 1e-6 to 1e9: group 0 holds the largest values, the
+        # last group the smallest, three decades a group
+        low = 6.0 - 12.0 * codes / max(n_groups - 1, 1)
+        values = rng.choice([-1.0, 1.0], n) * 10.0 ** rng.uniform(low, low + 3)
+    else:
+        assert name == "plain", name
+    return codes, values
+
+
+def _pandas_mean(codes, values, n_groups, mask=None):
+    frame = pd.DataFrame({"g": codes, "v": values})
+    if mask is not None:
+        frame = frame[mask]
+    frame = frame[frame["g"] >= 0]
+    return frame.groupby("g")["v"].mean().reindex(range(n_groups))
+
+
+def _group_count(n_groups):
+    """A parametrised group count: "C" is the module's boundary, "C+1" the
+    first count past it (read when the test runs, not when it is collected)."""
+    constant = _groupby_module()._DENSE_SUM_GROUPS
+    return {"C": constant, "C+1": constant + 1}.get(n_groups, n_groups)
+
+
+@pytest.mark.parametrize("n_groups", [1, 9, 10, "C", "C+1"])
+def test_dense_sum_matches_add_at_and_the_sorted_sum(n_groups):
+    """The helper itself, at every group count round its boundary: the
+    float64 ``np.add.at`` and ``_sorted_segment_sum`` on the same input."""
+    import jax
+    import jax.numpy as jnp
+
+    m = _groupby_module()
+    n_groups = _group_count(n_groups)
+    codes, values = _float_case("plain", n_groups)
+    if n_groups > 100:   # the boundary: fewer rows, still more than a block
+        codes, values = codes[:m._SUM_BLOCK + 4321], values[:m._SUM_BLOCK + 4321]
+    expect = np.zeros(n_groups)
+    np.add.at(expect, codes, values)
+    # jitted, as every caller has it: the [groups, rows] select is fused
+    # into the reduction and never materialised
+    dense = jax.jit(m._dense_segment_sum, static_argnums=2)(
+        jnp.asarray(values), jnp.asarray(codes), n_groups)
+    assert dense.dtype == jnp.float64 and dense.shape == (n_groups,)
+    np.testing.assert_allclose(np.asarray(dense), expect, rtol=1e-12)
+    by_sort = m._sorted_segment_sum(
+        jnp.asarray(values), jnp.asarray(codes), n_groups,
+        acc_dtype=jnp.float64,
+    )
+    np.testing.assert_allclose(
+        np.asarray(dense), np.asarray(by_sort), rtol=1e-9
+    )
+
+
+@pytest.mark.parametrize("strategy", [None, "scatter"])
+@pytest.mark.parametrize(
+    "case",
+    ["plain", "nan_measures", "null_codes", "empty_group", "one_group",
+     "ragged_rows"],
+)
+def test_dense_mean_through_partial_tables_matches_pandas(
+        groupby_as_accelerator, case, strategy):
+    """``mean`` = sum / count of the accelerator's trace equals pandas to
+    1e-12 on either route: NaN measures and null keys skipped, an empty
+    group NaN, a row count off the block grid, a filter on top."""
+    import jax
+
+    m = groupby_as_accelerator
+    codes, values = _float_case(case)
+    n_groups = 10
+    mask = np.random.default_rng(7).random(len(codes)) < 0.8
+    assert m.float_sum_route(
+        strategy, (values,), ("mean",), len(codes), n_groups
+    ) == "dense"
+    out = jax.device_get(m.partial_tables(
+        codes, (values,), ("mean",), n_groups, mask=mask, strategy=strategy,
+    ))
+    agg = out["aggs"][0]
+    with np.errstate(invalid="ignore", divide="ignore"):
+        mean = np.asarray(agg["sum"]) / np.asarray(agg["count"])
+    expect = _pandas_mean(codes, values, n_groups, mask)
+    np.testing.assert_allclose(mean, expect.to_numpy(), rtol=1e-12)
+    kept = mask & (codes >= 0)
+    np.testing.assert_array_equal(
+        np.asarray(out["rows"]),
+        np.bincount(codes[kept], minlength=n_groups),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(agg["count"]),
+        np.bincount(codes[kept & ~np.isnan(values)], minlength=n_groups),
+    )
+
+
+@pytest.mark.parametrize("strategy", [None, "scatter", "sort"])
+def test_float64_mean_matches_pandas_on_the_cpu_backend(strategy):
+    """Unpatched: this backend's own forms (a segment sum; the sort under
+    its binding hint) against pandas to 1e-12, NaNs and null keys in."""
+    import jax
+
+    codes, values = _float_case("nan_measures")
+    codes[::17] = -1
+    out = jax.device_get(gb.partial_tables(
+        codes, (values,), ("mean",), 10, strategy=strategy,
+    ))
+    agg = out["aggs"][0]
+    mean = np.asarray(agg["sum"]) / np.asarray(agg["count"])
+    np.testing.assert_allclose(
+        mean, _pandas_mean(codes, values, 10).to_numpy(), rtol=1e-12
+    )
+
+
+def test_dense_sum_is_no_less_accurate_than_the_sorted_sum():
+    """Values of both signs over fifteen decades, the small ones in the
+    later groups: against the exactly rounded sum (``math.fsum``, scaled
+    by the group's sum of magnitudes) the dense form — a group's sum only
+    meets its own values — errs no more than the prefix difference, which
+    carries the earlier groups' totals through the later ones."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    m = _groupby_module()
+    n_groups = 10
+    codes, values = _float_case("mixed_magnitudes")
+    exact = np.array(
+        [math.fsum(values[codes == g]) for g in range(n_groups)]
+    )
+    scale = np.array(
+        [math.fsum(np.abs(values[codes == g])) for g in range(n_groups)]
+    )
+    dense = np.asarray(jax.jit(m._dense_segment_sum, static_argnums=2)(
+        jnp.asarray(values), jnp.asarray(codes), n_groups))
+    by_sort = np.asarray(m._sorted_segment_sum(
+        jnp.asarray(values), jnp.asarray(codes), n_groups,
+        acc_dtype=jnp.float64))
+    dense_err = np.max(np.abs(dense - exact) / scale)
+    sort_err = np.max(np.abs(by_sort - exact) / scale)
+    assert dense_err <= sort_err, (dense_err, sort_err)
+    assert dense_err < 1e-13
+
+
+@pytest.mark.parametrize("strategy", [None, "scatter"])
+def test_integer_mean_takes_the_dense_sum(groupby_as_accelerator, strategy):
+    """An integer mean accumulates in float64 like pandas, so it is the
+    same plan and the same helper as a float64 one."""
+    import unittest.mock as mock
+
+    import jax
+
+    m = groupby_as_accelerator
+    rng = np.random.default_rng(32)
+    n, n_groups = m._SUM_BLOCK + 777, 9
+    codes = rng.integers(-1, n_groups, n).astype(np.int32)
+    values = rng.integers(-(2**40), 2**40, n).astype(np.int64)
+    assert m.float_sum_route(
+        strategy, (values,), ("mean",), n, n_groups) == "dense"
+    with mock.patch.object(
+        m, "_dense_segment_sum", wraps=m._dense_segment_sum
+    ) as dense:
+        out = jax.device_get(m.partial_tables(
+            codes, (values,), ("mean",), n_groups, strategy=strategy))
+    assert [call.args[2] for call in dense.call_args_list] == [n_groups]
+    agg = out["aggs"][0]
+    mean = np.asarray(agg["sum"]) / np.asarray(agg["count"])
+    np.testing.assert_allclose(
+        mean, _pandas_mean(codes, values, n_groups).to_numpy(), rtol=1e-12
+    )
+
+
+@pytest.mark.parametrize(
+    "n_groups, strategy, form",
+    [
+        pytest.param(10, None, "dense", id="few_groups"),
+        pytest.param("C", None, "dense", id="at_the_constant"),
+        pytest.param("C+1", None, "sorted", id="one_past_the_constant"),
+        pytest.param("C+1", "scatter", "sorted", id="one_past_by_scatter"),
+        pytest.param(10, "sort", "sorted", id="binding_sort_hint"),
+    ],
+)
+def test_the_group_count_decides_the_form_of_the_float64_sum(
+        groupby_as_accelerator, n_groups, strategy, form):
+    """Dense up to ``_DENSE_SUM_GROUPS`` groups, the sort + prefix-diff
+    above and under its binding hint: what the kernels trace is what
+    ``float_sum_route`` says from the host side, and both are right."""
+    import unittest.mock as mock
+
+    import jax
+
+    m = groupby_as_accelerator
+    n_groups = _group_count(n_groups)
+    rng = np.random.default_rng(33)
+    n = m._SUM_BLOCK + 99
+    codes = rng.integers(0, n_groups, n).astype(np.int32)
+    values = rng.random(n) * 40
+    assert m.float_sum_route(
+        strategy, (values,), ("sum",), n, n_groups) == form
+    with mock.patch.object(
+        m, "_dense_segment_sum", wraps=m._dense_segment_sum
+    ) as dense, mock.patch.object(
+        m, "_sorted_segment_sum", wraps=m._sorted_segment_sum
+    ) as by_sort:
+        out = jax.device_get(m.partial_tables(
+            codes, (values,), ("sum",), n_groups, strategy=strategy))
+    # (a binding sort hint sorts the row count too, so no call counts)
+    assert (dense.called, by_sort.called) == (form == "dense", form == "sorted")
+    expect = np.zeros(n_groups)
+    np.add.at(expect, codes, values)
+    np.testing.assert_allclose(
+        np.asarray(out["aggs"][0]["sum"]), expect, rtol=1e-9)
+
+
+# -- the route of a float64 mean ---------------------------------------------
+
+def _mesh_cache_key(strategy, n_groups=10, width=4096):
+    from bqueryd_tpu.parallel.executor import _effective_mesh_strategy
+
+    return _effective_mesh_strategy(
+        strategy, ("mean",), n_groups, (np.zeros(8, np.float64),), width
+    )
+
+
+@pytest.mark.parametrize("hint", [None, "auto", "matmul", "matmul!"])
+def test_a_float64_mean_goes_by_matmul_under_every_matmul_hint(
+        monkeypatch, hint):
+    """Where ``matmul_route_allowed`` holds, ``rows`` and the mean's count
+    are two rows of the stacked dot: auto, the planner's advisory hint and
+    the calibration-backed one are ONE route, ONE traced program (one mesh
+    cache key) and one calibration label."""
+    monkeypatch.setenv("BQUERYD_TPU_FORCE_MATMUL", "1")
+    m = _groupby_module()
+    floats = (np.zeros(8, np.float64),)
+    assert m._matmul_profitable(floats, ("mean",), 4096, 10)
+    assert m._matmul_profitable(floats, ("sum",), 4096, 10)
+    assert gb.kernel_route(hint, floats, ("mean",), 4096, 10) == "matmul"
+    assert _mesh_cache_key(hint) is None
+
+
+@pytest.mark.parametrize("hint", ["scatter", "sort"])
+def test_scatter_and_sort_stay_binding_for_a_float64_mean(monkeypatch, hint):
+    monkeypatch.setenv("BQUERYD_TPU_FORCE_MATMUL", "1")
+    floats = (np.zeros(8, np.float64),)
+    assert gb.kernel_route(hint, floats, ("mean",), 4096, 10) == hint
+    assert _mesh_cache_key(hint) == hint
+
+
+@pytest.mark.parametrize("hint", [None, "matmul", "matmul!"])
+def test_a_float64_mean_still_scatters_on_the_cpu_backend(monkeypatch, hint):
+    """The backend guard stands: without the force flag nothing changes
+    route here, and the sum is this backend's own segment sum (no tag)."""
+    monkeypatch.delenv("BQUERYD_TPU_FORCE_MATMUL", raising=False)
+    floats = (np.zeros(8, np.float64),)
+    assert gb.kernel_route(hint, floats, ("mean",), 4096, 10) == "scatter"
+    assert _mesh_cache_key(hint) is None
+    assert gb.float_sum_route(hint, floats, ("mean",), 4096, 10) is None
+
+
+def test_a_query_of_extrema_alone_is_still_not_matmul_profitable(monkeypatch):
+    """What the profitability rule still declines: min/max scatter on
+    either route, and alone they do not pay for the one-hot."""
+    monkeypatch.setenv("BQUERYD_TPU_FORCE_MATMUL", "1")
+    m = _groupby_module()
+    floats = (np.zeros(8, np.float64),)
+    assert not m._matmul_profitable(floats, ("min",), 4096, 10)
+    assert not m._matmul_profitable(floats * 2, ("min", "max"), 4096, 10)
+    assert m._matmul_profitable(floats * 2, ("min", "mean"), 4096, 10)
+    assert m._matmul_profitable((), (), 4096, 10)
+
+
+@pytest.mark.parametrize(
+    "dtype, op, route, expect",
+    [
+        (np.float64, "mean", "matmul", "dense"),
+        (np.float64, "sum", "scatter", "dense"),
+        (np.float32, "sum", "matmul", None),      # bf16 limbs on the MXU
+        (np.float32, "sum", "scatter", "dense"),  # accumulates in float64
+        (np.int64, "mean", "matmul", "dense"),
+        (np.int64, "sum", "matmul", None),
+        (np.float64, "min", "scatter", None),
+        (np.float64, "count", "matmul", None),
+    ],
+)
+def test_float_sum_route_names_the_float64_sums_only(
+        groupby_as_accelerator, dtype, op, route, expect):
+    strategy = None if route == "matmul" else "scatter"
+    measures = (np.zeros(8, dtype),)
+    if op == "min" and route == "scatter":
+        strategy = None
+    assert gb.kernel_route(strategy, measures, (op,), 4096, 10) == route
+    assert gb.float_sum_route(strategy, measures, (op,), 4096, 10) == expect

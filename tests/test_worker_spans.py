@@ -266,6 +266,46 @@ def test_the_detail_spans_take_nothing_off_the_aggregate_phase(node, monkeypatch
     assert seconds["kernel"] >= inside
 
 
+@pytest.mark.parametrize("profile", ["1", None], ids=["traced", "untraced"])
+def test_the_float_sum_tag_and_counter_exist_under_the_switch_only(
+        node, groupby_as_accelerator, monkeypatch, profile):
+    """PR 31: which form the float64 sum took (``dense`` here: seven
+    groups) is detail — a tag on the ``aggregate_wait`` span beside
+    ``effective_strategy`` and one labelled counter, both only on a worker
+    under the switch; the reply gains no key either way."""
+    if profile:
+        monkeypatch.setenv("BQUERYD_TPU_PROFILE", profile)
+    else:
+        monkeypatch.delenv("BQUERYD_TPU_PROFILE", raising=False)
+    worker = node["worker"]
+
+    def counted():
+        return {
+            tuple(m.labels.items()): m.value for m in worker.metrics.metrics()
+            if m.name == "bqueryd_tpu_float_sum_total"
+        }
+
+    node["run"]("solo", measure="w")   # the fixture emptied the jit caches
+    before = counted()
+    reply = node["run"]("solo", measure="w")   # a float64 sum
+    assert reply["effective_strategy"] == "matmul"
+    assert set(reply) == REPLY_KEYS["solo"]
+    tags = [s.get("tags", {}) for s in reply["spans"]
+            if s["name"] == "aggregate_wait"]
+    if profile:
+        assert [t["float_sum"] for t in tags] == ["dense"]
+        assert tags[0]["effective_strategy"] == "matmul"
+        key = (("form", "dense"),)
+        assert counted()[key] == before.get(key, 0.0) + 1
+        node["run"]("solo", measure="v")   # an int64 sum: nothing to name
+        assert counted()[key] == before.get(key, 0.0) + 1
+    else:
+        assert tags == []
+        assert counted() == before
+    kernel = next(s for s in reply["spans"] if s["name"] == "kernel")
+    assert "float_sum" not in kernel.get("tags", {})
+
+
 # -- (c) the loop thread's annotations --------------------------------------------
 
 class Annotations:
