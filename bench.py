@@ -2100,10 +2100,7 @@ def main():
     cold_enabled = os.environ.get("BENCH_COLD", "1") == "1"
     # the main-loop configs measure the default XLA kernel path; a pre-set
     # opt-in flag would silently turn the route-vs-route comparisons below
-    # (xla-vs-pallas, scatter-vs-forced-matmul, adaptive-vs-static) into
-    # self-comparisons — or, for a pre-set BQUERYD_TPU_PLANNER=0, let the
-    # per-repeat pop in the planner section clobber the user's setting and
-    # mix routes mid-measurement
+    # (xla-vs-pallas, scatter-vs-forced-matmul) into self-comparisons
     prior_env = {
         flag: os.environ.pop(flag, None)
         for flag in (
@@ -2375,247 +2372,6 @@ def main():
                 # leaking into the forced_matmul measurement); the outer
                 # finally restores every prior after the whole loop
                 os.environ.pop(vflag, None)
-
-        # planner config: the adaptive (plan-driven, calibration-fed) route
-        # vs the static fan-out (BQUERYD_TPU_PLANNER=0) on the headline +
-        # highcard configs — the main-loop numbers ARE the adaptive route
-        # (planner on by default) — plus per-config regret accounting
-        # (adaptive wall minus the best measured static wall, including the
-        # forced-matmul route where that route is legal), the strategy the
-        # workers actually compiled, and a plan-time pruning probe whose
-        # filter no shard can match.
-        planner_detail = {}
-        if os.environ.get("BENCH_PLANNER", "1") == "1" and not wedged:
-            controller_node = nodes[0]
-            # the matmul route's backend guard: on CPU backends (no
-            # FORCE_MATMUL here — bench pops it) forced-matmul is not a
-            # legal static route, so it never enters best-static and the
-            # regret gate compares adaptive vs plain static only
-            matmul_legal = jax.default_backend() != "cpu"
-            for pcfg in ("sharded", "highcard"):
-                if pcfg not in completed:
-                    continue
-                files, gcols, aggs, where = config_query(pcfg, names)
-                # adaptive and static measured BACK-TO-BACK, interleaved per
-                # repeat: the main-loop adaptive wall was taken minutes
-                # earlier under different cache/clock conditions, which made
-                # an identical-program comparison read as a route difference
-                try:
-                    a_walls, s_walls = [], []
-                    rpc.groupby(files, gcols, aggs, where)  # warmup
-                    # more repeats than the headline configs: adaptive and
-                    # static compile to the SAME program on backends that
-                    # normalize hints, so the comparison is noise-bounded —
-                    # a loose min reads scheduler jitter as a route delta
-                    a_strategies = None
-
-                    def one_adaptive():
-                        nonlocal a_strategies
-                        t0 = time.perf_counter()
-                        result = rpc.groupby(files, gcols, aggs, where)
-                        a_walls.append(time.perf_counter() - t0)
-                        # captured INSIDE the loop: after the interleave the
-                        # client's last_call_strategies belongs to the
-                        # static (PLANNER=0) run, whose hints are all auto
-                        a_strategies = getattr(
-                            rpc, "last_call_strategies", None
-                        )
-                        return result
-
-                    def one_static():
-                        os.environ["BQUERYD_TPU_PLANNER"] = "0"
-                        try:
-                            t0 = time.perf_counter()
-                            result = rpc.groupby(files, gcols, aggs, where)
-                            s_walls.append(time.perf_counter() - t0)
-                        finally:
-                            os.environ.pop("BQUERYD_TPU_PLANNER", None)
-                        return result
-
-                    # pairs alternate order (adaptive-first / static-first),
-                    # same as the obs section: always measuring adaptive
-                    # first systematically charged it whatever cost the
-                    # previous pair's tail left behind (GC, page cache churn)
-                    # — the r8 highcard "regret" of 0.55 s on an
-                    # identical-program backend was exactly that bias
-                    for i in range(max(REPEATS, 5)):
-                        if i % 2 == 0:
-                            a_result = one_adaptive()
-                            s_result = one_static()
-                        else:
-                            s_result = one_static()
-                            a_result = one_adaptive()
-                    import statistics as _stats
-
-                    adaptive_wall = min(a_walls)
-                    static_wall = min(s_walls)
-                    adaptive_median = _stats.median(a_walls)
-                    static_median = _stats.median(s_walls)
-                    check_result(
-                        a_result, base_dfs[pcfg], gcols, aggs,
-                        f"{pcfg}+adaptive",
-                    )
-                    check_result(
-                        s_result, base_dfs[pcfg], gcols, aggs,
-                        f"{pcfg}+static",
-                    )
-                except Exception as exc:
-                    print(
-                        f"[bench] planner variant {pcfg} failed: {exc!r}",
-                        file=sys.stderr,
-                        flush=True,
-                    )
-                    continue
-                # what the workers actually compiled for the last adaptive
-                # repeat (effective_strategy, satellite: hints used to
-                # normalize silently and nothing could tell what ran)
-                strategies = a_strategies or {}
-                effective = [
-                    v for v in (strategies.get("effective") or {}).values()
-                ]
-                chosen = (
-                    max(set(effective), key=effective.count)
-                    if effective else None
-                )
-                from bqueryd_tpu.plan import calibrate as calibrate_mod
-
-                calib_stats = calibrate_mod.store().stats()
-                forced_wall = results.get(
-                    f"{pcfg}_forced_matmul", {}
-                ).get("framework_wall_s")
-                # best measured STATIC route: the PLANNER=0 wall always;
-                # the forced-matmul wall only where that route is legal
-                static_routes = {"static": static_wall}
-                if forced_wall is not None and matmul_legal:
-                    static_routes["forced_matmul"] = forced_wall
-                best_static = min(static_routes.values())
-                planner_detail[pcfg] = {
-                    "adaptive_wall_s": round(adaptive_wall, 4),
-                    "main_loop_wall_s": results[pcfg]["framework_wall_s"],
-                    "static_wall_s": round(static_wall, 4),
-                    # the forced-matmul variant wall (measured above when the
-                    # route flag applies): the regression the planner path
-                    # must keep unreachable
-                    "forced_matmul_wall_s": forced_wall,
-                    "chosen_strategy": chosen,
-                    "strategy_hints": dict(strategies.get("hints") or {}),
-                    "calibration_samples": calib_stats["samples_total"],
-                    "calibration_cells": calib_stats["cells"],
-                    # regret: adaptive wall minus the best measured static
-                    # wall (negative = the calibrated route beat every
-                    # static one); the gate below asserts <= 10% wherever
-                    # the matmul route is legal
-                    "best_static_wall_s": round(best_static, 4),
-                    "regret_s": round(adaptive_wall - best_static, 4),
-                    "regret_gate_applies": matmul_legal,
-                    "regret_within_10pct": bool(
-                        adaptive_wall <= 1.10 * best_static
-                    ),
-                    # noise-robust twin: paired-alternated medians.  On
-                    # hint-normalizing backends (CPU: adaptive and static
-                    # run the IDENTICAL program) milli-scale walls are
-                    # noise-dominated, so the every-config gate requires
-                    # BOTH the min AND the median comparison to exceed 10%
-                    # before calling a regression (the r8 highcard regret —
-                    # 0.55 s systematic, 45% — fails both; one-sided
-                    # scheduler noise fails at most one)
-                    "adaptive_median_s": round(adaptive_median, 4),
-                    "static_median_s": round(static_median, 4),
-                    "regret_median_s": round(
-                        adaptive_median - static_median, 4
-                    ),
-                    "median_regret_within_10pct": bool(
-                        adaptive_median <= 1.10 * static_median
-                    ),
-                    "noise_robust_within_10pct": bool(
-                        adaptive_wall <= 1.10 * static_wall
-                        or adaptive_median <= 1.10 * static_median
-                    ),
-                }
-                print(
-                    f"[bench] planner {pcfg}: adaptive {adaptive_wall:.3f}s "
-                    f"vs static {static_wall:.3f}s "
-                    f"(best static {best_static:.3f}s, regret "
-                    f"{adaptive_wall - best_static:+.3f}s, chosen "
-                    f"{chosen}, {calib_stats['samples_total']} calibration "
-                    f"samples)",
-                    file=sys.stderr,
-                    flush=True,
-                )
-            try:
-                before_pruned = controller_node.counters[
-                    "plan_pruned_shards"
-                ]
-                before_disp = controller_node.counters["dispatched_shards"]
-                probe = rpc.groupby(
-                    names,
-                    ["passenger_count"],
-                    [["fare_amount", "sum", "fare_amount"]],
-                    # PULocationID tops out at 265: every shard's min/max
-                    # stats exclude this, so the planner must dispatch NOTHING
-                    [["PULocationID", ">", 10_000]],
-                )
-                planner_detail["prune_probe"] = {
-                    "plan_pruned_shards": controller_node.counters[
-                        "plan_pruned_shards"
-                    ] - before_pruned,
-                    "dispatched_shards": controller_node.counters[
-                        "dispatched_shards"
-                    ] - before_disp,
-                    "result_rows": int(len(probe)),
-                }
-                print(
-                    f"[bench] prune probe: "
-                    f"{planner_detail['prune_probe']}",
-                    file=sys.stderr,
-                    flush=True,
-                )
-            except Exception as exc:
-                print(
-                    f"[bench] prune probe failed: {exc!r}",
-                    file=sys.stderr,
-                    flush=True,
-                )
-            planner_detail["plan_counters"] = dict(controller_node.counters)
-            planner_detail["note"] = (
-                "adaptive = calibration-fed planner (measured kernel walls "
-                "refine the heuristic; matmul promotions bind only inside "
-                "the kernel guards).  On CPU backends the matmul route is "
-                "not legal (backend guard, forced_matmul excluded from "
-                "best_static) and surviving hints normalize to the static "
-                "program, so regret there is run-to-run noise; the regret "
-                "gate certifies adaptive <= 1.10x best-static wherever the "
-                "matmul route IS legal"
-            )
-            # THE GATE (satellite): adaptive must stay within 10% of the
-            # best measured static route on every config where the matmul
-            # route is legal — the calibrated planner may never leave the
-            # forced-matmul-sized win on the table again.  BENCH_PLANNER_
-            # GATE=0 records without asserting (probe runs).
-            if os.environ.get("BENCH_PLANNER_GATE", "1") == "1":
-                for pcfg, entry in planner_detail.items():
-                    if not isinstance(entry, dict):
-                        continue
-                    if entry.get("regret_gate_applies"):
-                        assert entry.get("regret_within_10pct"), (
-                            f"planner regret gate: {pcfg} adaptive "
-                            f"{entry['adaptive_wall_s']}s exceeds 1.10x best "
-                            f"static {entry['best_static_wall_s']}s "
-                            f"(regret {entry['regret_s']}s)"
-                        )
-                    if "noise_robust_within_10pct" in entry:
-                        # this gate applies EVERYWHERE (highcard included):
-                        # a SYSTEMATIC adaptive regression shows in both
-                        # the min and the paired-alternated median; it must
-                        # not exceed 10% in both at once
-                        assert entry.get("noise_robust_within_10pct"), (
-                            f"planner regret gate (all configs): {pcfg} "
-                            f"adaptive min {entry['adaptive_wall_s']}s / "
-                            f"median {entry['adaptive_median_s']}s both "
-                            f"exceed 1.10x static "
-                            f"(min {entry['static_wall_s']}s, median "
-                            f"{entry['static_median_s']}s)"
-                        )
 
         # observability: registry snapshots bracket a headline groupby wall
         # (perf regressions come with phase attribution for free — the
@@ -3841,9 +3597,6 @@ def main():
                 None if floor_s is None else round(floor_s, 4)
             ),
             "configs": results,
-            # adaptive-vs-static route walls + the plan_pruned_shards /
-            # shared-dispatch / admission counters from the controller
-            "planner": planner_detail,
             # registry snapshots bracketing the headline walls + the
             # metrics-hot-path overhead gate + a sample trace waterfall
             "observability": obs_detail,
@@ -3930,15 +3683,6 @@ def main():
                             else round(floor_s * 1e3, 1)
                         ),
                         "configs": compact_configs,
-                        "plan_pruned_shards": planner_detail.get(
-                            "plan_counters", {}
-                        ).get("plan_pruned_shards"),
-                        "planner_regret_s": (
-                            planner_detail.get(HEADLINE) or {}
-                        ).get("regret_s"),
-                        "chosen_strategy": (
-                            planner_detail.get(HEADLINE) or {}
-                        ).get("chosen_strategy"),
                         "obs_overhead_pct": obs_detail.get("overhead_pct"),
                         "slo_coverage_min": slo_detail.get("coverage_min"),
                         "slo_combined_overhead_pct": slo_detail.get(

@@ -187,23 +187,7 @@ ENV_REGISTRY = {
         _v("FORCE_MATMUL", "flag", "0",
            "force the MXU one-hot path on CPU backends (tests)"),
         _v("PLANNER", "flag", "1",
-           "plan-time shard pruning + kernel-strategy hints (0=static)"),
-        _v("CALIB", "flag", "1",
-           "measured-cost strategy calibration feeding the planner "
-           "(0 = PR-5 heuristic hints exactly)",
-           related=("CALIB_PATH", "CALIB_EPSILON", "CALIB_MIN_SAMPLES")),
-        _v("CALIB_PATH", "path", "-",
-           "persist worker calibration cells to this JSON file across "
-           "restarts (- = in-memory only)",
-           related=("CALIB",)),
-        _v("CALIB_EPSILON", "float", "0.05",
-           "bounded exploration rate: ~every 1/eps-th warm-bucket decision "
-           "samples an unmeasured legal route (0 = off)",
-           related=("CALIB",)),
-        _v("CALIB_MIN_SAMPLES", "int", "3",
-           "measured kernel walls a strategy cell needs before calibration "
-           "trusts it",
-           related=("CALIB",)),
+           "plan-time shard pruning (0 = static fan-out)"),
         _v("BATCH_WINDOW_MS", "float", "0",
            "admission micro-batch window: hold admitted groupby plans this "
            "many ms so compatible concurrent queries fuse into one "

@@ -97,13 +97,6 @@ COUNTER_SPECS = {
         "one mesh program serving a whole compatible micro-batch",
     "plan_bundled_queries":
         "member queries that rode a shared-scan bundle dispatch",
-    "plan_strategy_hints": "non-auto kernel-strategy hints issued",
-    "plan_calibrated_overrides":
-        "dispatches where measured walls overrode the heuristic route",
-    "plan_explore_hints":
-        "bounded-exploration dispatches sampling an unmeasured route",
-    "plan_matmul_promotions":
-        "calibration-backed matmul hints made binding inside the guards",
     "admission_busy": "BUSY backpressure replies sent to clients",
     "admission_queued": "plans held in the admission wait queue",
     "admission_superseded": "abandoned queries retired early on resend",
@@ -271,13 +264,6 @@ class ControllerNode:
         self._admitting = False
         self._ticket_sigs = {}        # live ticket -> plan signature
         self.shard_stats = {}         # filename -> advertised planning stats
-        # measured-cost strategy calibration: WRM `calibration` summaries
-        # from workers merge into this model (plan.calibrate), consulted by
-        # select_calibrated at dispatch time; in-memory only — the workers
-        # own persistence (their measurements re-gossip after a restart)
-        from bqueryd_tpu.plan import calibrate as _calibrate
-
-        self.calibration = _calibrate.CalibrationStore()
         # -- semantic serving (PR 16) ---------------------------------------
         # subsumption lattice + materialized-rollup manager (serve/): hit
         # replies skip admission entirely; BQUERYD_TPU_SERVE=0 makes every
@@ -769,22 +755,6 @@ class ControllerNode:
                     and isinstance(entry.get("cols", {}), dict)
                 ):
                     self.shard_stats[fname] = entry
-        # measured-cost calibration gossip rides the same WRM; absorb is
-        # per-cell defensive (plan.calibrate), so a skewed peer degrades to
-        # contributing nothing rather than poisoning the model.  source=
-        # makes each worker's cumulative summary REPLACE its previous one
-        # instead of re-merging every heartbeat (sample double-counting)
-        calibration = info.get("calibration")
-        if isinstance(calibration, dict):
-            try:
-                self.calibration.absorb(
-                    calibration,
-                    source=info.get("worker_id") or "unidentified-worker",
-                )
-            except Exception:
-                self.logger.debug(
-                    "calibration gossip absorb failed", exc_info=True
-                )
 
     def _holder_counts(self):
         """Advertised shards bucketed by live holder count ("1"/"2"/"3plus")
@@ -2191,9 +2161,9 @@ class ControllerNode:
             # subsumption serves) which materialized view proved it
             "answer_source": answer_source,
             "subsumed_from": None,
-            # planner visibility end to end: the hints issued and the
-            # routes the workers actually compiled post-guards (bench's
-            # chosen_strategy / regret accounting read these)
+            # route visibility end to end: "hints" counts the shards
+            # dispatched ({"auto": n} — a dispatch names no kernel),
+            # "effective" is the route each worker's kernel rule took
             "strategies": {
                 "hints": dict(segment.get("strategies", {})),
                 "effective": self._compact_timings(
@@ -2715,13 +2685,9 @@ class ControllerNode:
             "compile_cache": obs_profile.compile_cache_info(),
             # subsystems grown since PR 3 — the forensic artifact must
             # cover the failure surfaces that now shape a query's fate:
-            # measured-cost calibration (PR 6), chaos/fault-injection and
-            # replica placement (PR 8), the micro-batch window (PR 9), and
-            # the SLO/timeline accounting this PR adds
-            "calibration": {
-                **self.calibration.stats(),
-                "sample_cells": self.calibration.summary(max_cells=16),
-            },
+            # chaos/fault-injection and replica placement (PR 8), the
+            # micro-batch window (PR 9), and the SLO/timeline accounting
+            # this PR adds
             "chaos": {
                 "armed": chaos.enabled(),
                 "injected_total": chaos.injected_total(),
@@ -3170,8 +3136,8 @@ class ControllerNode:
         :class:`~bqueryd_tpu.plan.LogicalPlan` (rewrites applied), passes
         admission control (explicit BUSY backpressure instead of unbounded
         inflight growth), and launches via :meth:`_launch_plan`, which
-        prunes shards against advertised stats, fuses identical concurrent
-        work, and stamps each dispatch with a kernel-strategy hint."""
+        prunes shards against advertised stats and fuses identical
+        concurrent work."""
         from bqueryd_tpu import obs
         from bqueryd_tpu import plan as planmod
 
@@ -3792,7 +3758,10 @@ class ControllerNode:
             "pruned": list(pruned),
             "obs": obs_state,
             "plan_sig": str(plan.signature()),
-            "strategies": {},         # hint -> dispatch count
+            # shards dispatched, under the one route a dispatch asks for:
+            # the envelope's ``strategies["hints"]`` ({"auto": n}); which
+            # kernel ran is the worker's report, in "effective"
+            "strategies": {},
             "effective": {},          # shard-group key -> executed route
             "merge": {},              # shard-group key -> merge_mode
         }
@@ -3860,20 +3829,8 @@ class ControllerNode:
                 keep, groupby_cols, agg_list0, kwargs0
             ):
                 target = group if len(group) > 1 else group[0]
-                # no per-bundle strategy selection: the shared-scan kernel
-                # always runs its own batched/auto family (the hint could
-                # only ever reach the worker's rare per-member fallback),
-                # so issuing calibrated hints here would inflate the
-                # planner-hint counters with hints that structurally
-                # cannot run
-                strategy = None
-                hint = "auto"
                 for parent in parents:
-                    segment = self.rpc_segments.get(parent)
-                    if segment is not None:
-                        segment["strategies"][hint] = (
-                            segment["strategies"].get(hint, 0) + len(group)
-                        )
+                    self._count_dispatched_shards(parent, len(group))
                 shard = CalcMessage({"payload": "groupby"})
                 if sole:
                     shard["sole_shard"] = True
@@ -3907,7 +3864,7 @@ class ControllerNode:
                 shard.add_as_binary(
                     "bundle",
                     bundlemod.bundle_fragment(
-                        plan0, group, members, strategy=strategy, sole=sole
+                        plan0, group, members, sole=sole
                     ),
                 )
                 shard["_bundle_parents"] = dict(member_parents)
@@ -3926,20 +3883,21 @@ class ControllerNode:
                 self.abort_parent(parent, "bundle launch failed", reply=False)
             raise
 
+    def _count_dispatched_shards(self, parent_token, n):
+        segment = self.rpc_segments.get(parent_token)
+        if segment is not None:
+            hints = segment["strategies"]
+            hints["auto"] = hints.get("auto", 0) + n
+
     def _dispatch_plan(self, msg, plan, kwargs, parent_token, keep):
         from bqueryd_tpu import plan as planmod
 
         affinity = kwargs.get("affinity")
-        planner_on = planmod.planner_enabled()
         # operator-DAG dispatch (rpc.query): the wire DAG rides every
-        # CalcMessage under the `dag` binary key; calibrated strategy
-        # hints are skipped — the DAG executor routes its own kernels, so
-        # issuing hints here would inflate the planner-hint counters with
-        # hints that structurally cannot run (same reasoning as bundles)
+        # CalcMessage under the `dag` binary key
         dag_wire = kwargs.get("dag")
         dag_blob = None
         if dag_wire is not None:
-            planner_on = False
             # encode ONCE: the wire DAG carries the whole broadcast
             # dimension table, and re-pickling it per shard group would
             # put O(groups x table_bytes) on the dispatch hot path
@@ -3958,32 +3916,7 @@ class ControllerNode:
             keep, groupby_cols, agg_list, kwargs
         ):
             target = group if len(group) > 1 else group[0]
-            # cost-based kernel-strategy selection from advertised stats,
-            # refined by measured kernel walls when the calibration model is
-            # warm (plan.calibrate; cold buckets are bit-identical to the
-            # heuristic); "auto" stays the static default
-            strategy = None
-            if planner_on:
-                strategy, _est, _rows, reason = planmod.select_calibrated(
-                    self.shard_stats, group, groupby_cols,
-                    calibration=self.calibration,
-                )
-                if strategy == planmod.STRATEGY_AUTO:
-                    strategy = None
-                else:
-                    self.counters["plan_strategy_hints"] += 1
-                if reason == "measured":
-                    self.counters["plan_calibrated_overrides"] += 1
-                elif reason == "explore":
-                    self.counters["plan_explore_hints"] += 1
-                if strategy == planmod.STRATEGY_MATMUL_BINDING:
-                    self.counters["plan_matmul_promotions"] += 1
-            segment = self.rpc_segments.get(parent_token)
-            if segment is not None:
-                hint = strategy or "auto"
-                segment["strategies"][hint] = (
-                    segment["strategies"].get(hint, 0) + len(group)
-                )
+            self._count_dispatched_shards(parent_token, len(group))
             # multi-query batching: identical pending work is joined, not
             # re-dispatched.  The deadline is part of the identity: fusing
             # across deadlines would let one client's budget expire (or
@@ -4032,9 +3965,7 @@ class ControllerNode:
                 shard["deadline"] = msg["deadline"]
             shard.add_as_binary(
                 "plan",
-                planmod.fragment_for(
-                    plan, group, strategy=strategy, sole=sole
-                ),
+                planmod.fragment_for(plan, group, sole=sole),
             )
             if dag_blob is not None:
                 # capable workers execute the DAG; pre-DAG workers fall

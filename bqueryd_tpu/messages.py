@@ -46,8 +46,7 @@ without them interoperate):
   don't know): ``backend_wedged`` (bool, the device-health latch),
   ``work_errors`` (cumulative error-counter total — the controller's
   health scorer derives windowed error rates from its deltas),
-  ``metrics`` (histogram snapshot, see obs.metrics), ``calibration`` (the
-  worker's measured-cost strategy cells, see plan.calibrate), and ``debug`` — the
+  ``metrics`` (histogram snapshot, see obs.metrics), and ``debug`` — the
   node's debug-bundle slice (flight-ring tail, compile registry, device
   health, runtime versions; see obs.flightrec) absorbed controller-side
   so ``rpc.debug_bundle()`` can speak for dead peers.
@@ -139,7 +138,6 @@ ENVELOPE_SCHEMA = {
     "phase_timings": "per-phase seconds dict; whole-call wall under _total",
     "spans": "worker span list folded into the query trace timeline",
     "deadline_remaining": "seconds left at reply serialization",
-    "strategy": "the planner's kernel-strategy hint, echoed on the reply",
     "effective_strategy": "physical kernel route the worker ran post-guards "
                           "(matmul/scatter/sort/host; 'cached' = result-"
                           "cache hit, nothing compiled; 'delta' = delta-"
@@ -189,9 +187,7 @@ ENVELOPE_SCHEMA = {
     "backend_wedged": "device-health latch (health scoring + routing)",
     "work_errors": "cumulative error-counter total (health windows)",
     "debug": "node debug-bundle slice (flight tail, compile registry, ...)",
-    "shard_stats": "per-shard planning stats (rows, min/max, cardinality)",
-    "calibration": "measured-cost strategy calibration summary "
-                   "(plan.calibrate cells, absorbed controller-side)",
+    "shard_stats": "per-shard planning stats (rows, min/max)",
     "metrics": "histogram snapshot (bucket-vector mergeable)",
     "pipeline_busy": "cumulative per-stage StageClock busy seconds "
                      "(parallel.pipeline snapshot) — the controller's "
@@ -286,8 +282,6 @@ WIRE_ONE_SIDED_OK = {
     "_obs": "controller-internal rider, intentionally unread elsewhere",
     "deadline_remaining": "informational reply field for clients/tests; "
                           "the controller deliberately ignores it",
-    "strategy": "informational reply field (the hint echo) for "
-                "clients/tests; dispatch accounting happens at send time",
     "others": "written into get_info(); read by rpc.info() clients/tests",
     "ip": "operator-facing WRM field surfaced via rpc.info(); the "
           "controller routes by socket identity, not this",

@@ -561,12 +561,12 @@ class QueryEngine:
     # -- execution ---------------------------------------------------------
     def execute_local(self, table, query: GroupByQuery,
                       strategy=None) -> ResultPayload:
-        """``strategy`` is the planner's kernel-route hint: ``"host"`` forces
-        the NumPy kernels (bypassing the latency threshold), ``"scatter"`` /
-        ``"sort"`` / ``"matmul"`` flow into :func:`ops.partial_tables` (the
-        matmul hint stays advisory there); None/"auto" keeps the adaptive
-        default.  A wedged backend overrides every device hint — survival
-        beats planning."""
+        """``strategy`` is the seam by which a test reaches one kernel:
+        ``"host"`` forces the NumPy kernels (bypassing the latency
+        threshold), ``"scatter"`` / ``"sort"`` / ``"matmul"`` flow into
+        :func:`ops.partial_tables` (matmul stays advisory there); None/"auto"
+        is what every served query runs.  A wedged backend overrides every
+        device route."""
         from bqueryd_tpu import ops
 
         self.last_effective_strategy = None  # set by the kernel dispatch
@@ -701,33 +701,15 @@ class QueryEngine:
                 ):
                     # latency-aware routing: below the threshold the host
                     # beats the device's dispatch+fetch floor (see
-                    # host_kernel_rows); identical partial semantics.  The
-                    # planner's "host" hint forces this branch outright.
-                    import time as _time
-
-                    from bqueryd_tpu.plan import calibrate as _calibrate
-
+                    # host_kernel_rows); identical partial semantics.
+                    # strategy="host" forces this branch outright.
                     self.last_effective_strategy = "host"
-                    host_clock = _time.perf_counter()
                     partials = ops.host_partial_tables(
                         dense.astype(np.int32), measures, mops, n_groups,
                         mask_arr, null_sentinels=sentinels,
                     )
-                    # host walls are calibration data points too (no
-                    # compile taint to filter on this route)
-                    _calibrate.record_sample(
-                        rows=len(dense), groups=n_groups,
-                        dtypes=[np.asarray(m).dtype for m in measures],
-                        backend="host", strategy="host",
-                        wall_s=_time.perf_counter() - host_clock,
-                    )
                 else:
-                    import time as _time
-
                     import jax
-
-                    from bqueryd_tpu.obs import profile as _obs_profile
-                    from bqueryd_tpu.plan import calibrate as _calibrate
 
                     # bucketed group count (ops.program_bucket): program
                     # reuse across cardinality drift; padded groups are
@@ -735,19 +717,14 @@ class QueryEngine:
                     n_prog = ops.program_bucket(n_groups)
                     kernel_strategy = (
                         strategy
-                        if strategy in ("matmul", "scatter", "sort",
-                                        "matmul!")
+                        if strategy in ("matmul", "scatter", "sort")
                         else None
                     )
-                    np_measures = [np.asarray(m) for m in measures]
-                    route = ops.kernel_route(
-                        kernel_strategy, np_measures, mops,
+                    self.last_effective_strategy = ops.kernel_route(
+                        kernel_strategy,
+                        [np.asarray(m) for m in measures], mops,
                         len(dense), n_prog,
                     )
-                    self.last_effective_strategy = route
-                    profiler = _obs_profile.profiler()
-                    misses_before = profiler.jit_cache_misses
-                    kernel_clock = _time.perf_counter()
                     partials = jax.device_get(  # ONE batched D2H round-trip
                         ops.partial_tables(
                             dense.astype(np.int32), measures, mops, n_prog,
@@ -755,20 +732,6 @@ class QueryEngine:
                             strategy=kernel_strategy,
                         )
                     )
-                    # measured-cost calibration sample (plan.calibrate);
-                    # compile-tainted walls are skipped — a first-shape
-                    # compile would poison the route's EWMA
-                    if (
-                        _calibrate.enabled()
-                        and profiler.jit_cache_misses == misses_before
-                    ):
-                        _calibrate.record_sample(
-                            rows=len(dense), groups=n_groups,
-                            dtypes=[m.dtype for m in np_measures],
-                            backend=jax.default_backend(),
-                            strategy=route,
-                            wall_s=_time.perf_counter() - kernel_clock,
-                        )
                     if n_prog != n_groups:
                         partials = jax.tree_util.tree_map(
                             lambda a: a[:n_groups], partials

@@ -32,11 +32,10 @@ import threading
 import time
 
 #: schema /2 (PR 10): additive controller-section keys — ``autopsy`` (the
-#: bundled trace's attributed critical path), ``calibration`` (measured-cost
-#: store summary, PR 6), ``chaos`` (fault-injection stats, PR 8),
-#: ``replication`` (replica placement, PR 8), ``batch_window`` (micro-batch
-#: staging state, PR 9), ``slo`` (per-class accounting), ``timeline_ring``
-#: (periodic registry snapshots).
+#: bundled trace's attributed critical path), ``chaos`` (fault-injection
+#: stats, PR 8), ``replication`` (replica placement, PR 8), ``batch_window``
+#: (micro-batch staging state, PR 9), ``slo`` (per-class accounting),
+#: ``timeline_ring`` (periodic registry snapshots).
 #: schema /3 (PR 12): additive ``capacity`` controller-section key — the
 #: fleet capacity model's freshly-evaluated snapshot (per-worker μ/ρ/state,
 #: shard heat map, predicted-vs-measured queue delay, last shadow

@@ -280,20 +280,6 @@ class ProgramProfiler:
 
         return read
 
-    def last_program(self, name_prefix):
-        """The most recently CALLED registry entry whose ``name`` starts
-        with ``name_prefix`` (a copy), or None — how the calibration layer
-        attaches a program's cost_analysis FLOPs/bytes to the kernel walls
-        it records (plan.calibrate)."""
-        with self._lock:
-            best = None
-            for entry in self.programs.values():
-                if not str(entry.get("name", "")).startswith(name_prefix):
-                    continue
-                if best is None or entry.get("_seq", 0) > best.get("_seq", 0):
-                    best = entry
-            return dict(best) if best is not None else None
-
     # -- export --------------------------------------------------------------
     def snapshot(self, max_programs=32):
         """JSON-safe state for WRM debug snapshots / the debug bundle.

@@ -2,8 +2,8 @@
 
 The tracing stack (PRs 2-3) predates everything that now determines a
 query's latency — batch-window staging (PR 9), retry backoff / hedged
-dispatch / replica failover (PR 8), calibrated strategy selection (PR 6),
-the device-resident collective merge (PR 7) — so a raw span list can no
+dispatch / replica failover (PR 8), the device-resident collective merge
+(PR 7) — so a raw span list can no
 longer answer "where did this query's 4 s go" without a human replaying the
 dispatch state machine.  This module turns an assembled trace timeline
 (:class:`bqueryd_tpu.obs.trace.TraceStore` entries) into an **attribution

@@ -146,14 +146,12 @@ _MAX_BLOCK_SEGMENTS = 1 << 25
 _DENSE_SUM_GROUPS = 2048
 
 
-#: kernel routes partial_tables accepts as a planner hint (None == "auto").
-#: "matmul" is advisory — every profitability/backend guard still applies —
-#: while "scatter"/"sort" are binding (both are always-correct fallbacks).
-#: "matmul!" is the CALIBRATION-BACKED form: binding inside the guards —
-#: it bypasses only the op/dtype profitability heuristic, while the backend
-#: guard and the groups/cells value guards (matmul_route_allowed) stand, so
-#: the forced-matmul regression stays unreachable through any hint.
-KERNEL_STRATEGIES = ("auto", "matmul", "scatter", "sort", "matmul!")
+#: kernel routes partial_tables accepts as ``strategy`` (None == "auto", what
+#: every served query runs; the rest is the seam by which tests and
+#: chip_smoke.py reach one kernel).  "matmul" is advisory — every
+#: profitability/backend guard still applies — while "scatter"/"sort" are
+#: binding (both are always-correct fallbacks).
+KERNEL_STRATEGIES = ("auto", "matmul", "scatter", "sort")
 
 
 def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
@@ -338,14 +336,11 @@ def _matmul_cells_limit():
 
 
 def matmul_route_allowed(n, n_groups):
-    """The MXU route's SAFETY guards, shared by the adaptive dispatcher and
-    the calibration-backed binding hint: backend (the one-hot contraction
+    """The MXU route's SAFETY guards: backend (the one-hot contraction
     emulates ~7x slower than the int32 scatter on CPU backends —
     BQUERYD_TPU_FORCE_MATMUL=1 overrides, pinned by the test suite for
     MXU-path coverage on the CPU test backend), group ceiling, and the
-    rows x groups cells budget.  A "matmul!" hint that fails ANY of these
-    demotes to the adaptive default — only the op/dtype profitability
-    heuristic below yields to measurement."""
+    rows x groups cells budget."""
     if (
         jax.default_backend() == "cpu"
         and os.environ.get("BQUERYD_TPU_FORCE_MATMUL") != "1"
@@ -443,17 +438,17 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
     Sums and counts route to the MXU one-hot matmul (module docstring) when
     the cardinality is within :func:`matmul_groups_limit`; min/max-only and
     high-cardinality queries use segment scatters.  A float64 sum or mean
-    takes the MXU route for its counts too (``auto``, ``"matmul"`` and
-    ``"matmul!"`` are then one traced program) and sums by
+    takes the MXU route for its counts too (``auto`` and ``"matmul"`` are
+    then one traced program) and sums by
     :func:`_float64_segment_sum` on whichever route it goes.
 
-    ``strategy`` is the planner's route hint (:data:`KERNEL_STRATEGIES`):
+    ``strategy`` (:data:`KERNEL_STRATEGIES`) forces one route for a test:
     ``"scatter"`` goes straight to the blocked scatters, ``"sort"`` to the
     scatter entry with the sort+prefix-diff reduction forced, and
-    ``"matmul"``/``"auto"``/None keep the full profitability logic — the
-    hint can steer toward the MXU but never override its backend guard (a
-    CPU backend still declines, so a planner hint cannot reproduce the
-    forced-matmul regression).
+    ``"matmul"``/``"auto"``/None keep the full profitability logic, the ONE
+    rule that routes every served query (:func:`kernel_route` is its
+    host-side twin) — ``"matmul"`` never overrides the backend guard (a CPU
+    backend still declines).
     """
     ops = tuple(ops)
     measures = tuple(measures)
@@ -478,21 +473,6 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
         return _partial_tables_scatter(
             codes, measures, ops, int(n_groups), mask,
             null_sentinels=null_sentinels, force_sort=True,
-        )
-    if strategy == "matmul!" and matmul_route_allowed(
-        int(codes.shape[0]), int(n_groups)
-    ):
-        # calibration-backed promotion: measurement overrides only the
-        # op/dtype profitability heuristic — backend + value guards were
-        # just enforced (a failed guard falls through to the adaptive
-        # dispatch below, exactly as if the hint were advisory)
-        from bqueryd_tpu.ops import pallas_groupby
-
-        return _partial_tables_mm(
-            codes, measures, ops, int(n_groups), mask,
-            use_pallas=pallas_groupby.pallas_enabled()
-            and int(n_groups) <= pallas_groupby.pallas_groups_limit(),
-            null_sentinels=null_sentinels,
         )
     if _matmul_profitable(measures, ops, int(codes.shape[0]), int(n_groups)):
         # env flags are read HERE, outside jit, so toggling them takes effect
@@ -741,19 +721,17 @@ def partial_tables_bucketized(codes, measures, ops, n_groups, n_buckets,
 def kernel_route(strategy, measures, ops, n, n_groups):
     """Predict the physical route :func:`partial_tables` takes for this
     dispatch WITHOUT running it — the ``effective_strategy`` reported in
-    calc replies / kernel trace spans and the label calibration samples are
-    recorded under.  Mirrors the dispatch above; ``measures`` only needs
-    ``.dtype`` per entry (device arrays, numpy arrays, and dtype stubs all
-    work).  Granularity note: the rare in-kernel demotions (a hicard Pallas
-    plan that fails its VMEM recheck at trace time) are not modelled —
-    those differ per XLA trace, not per dispatch."""
+    calc replies / kernel trace spans.  Mirrors the dispatch above;
+    ``measures`` only needs ``.dtype`` per entry (device arrays, numpy
+    arrays, and dtype stubs all work).  Granularity note: the rare
+    in-kernel demotions (a hicard Pallas plan that fails its VMEM recheck
+    at trace time) are not modelled — those differ per XLA trace, not per
+    dispatch."""
     n, n_groups = int(n), int(n_groups)
     if strategy == "scatter":
         return "scatter"
     if strategy == "sort":
         return "sort"
-    if strategy == "matmul!" and matmul_route_allowed(n, n_groups):
-        return "matmul"
     if _matmul_profitable(measures, tuple(ops), n, n_groups):
         return "matmul"
     if _hicard_matmul_profitable(measures, tuple(ops), n, n_groups):
@@ -1083,7 +1061,7 @@ _partial_tables_mm = _obsprofile.instrument(
 def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
                             null_sentinels=None, force_sort=False):
     """Scatter path: blocked-int32 segment sums (exact, no s64 scatter).
-    ``force_sort`` (the planner's "sort" strategy) makes every sum take the
+    ``force_sort`` (the forced "sort" strategy) makes every sum take the
     sort+prefix-diff reduction regardless of the blocks x groups budget —
     identical partial semantics, group-count-independent cost."""
     valid = codes >= 0

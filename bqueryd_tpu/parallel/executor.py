@@ -655,10 +655,6 @@ class MeshQueryExecutor:
             ]
         if not tables:
             return ResultPayload.empty()
-        # calibration buckets key on the dispatch group's total rows — the
-        # same quantity the controller's selector estimated from stats
-        total_rows = sum(int(t.nrows) for t in tables)
-
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -902,17 +898,13 @@ class MeshQueryExecutor:
             # reuse across cardinality drift, ops.program_bucket); padded
             # groups have zero rows and are sliced off right below, on host
             n_prog = ops.program_bucket(n_groups)
-            # the physical route this dispatch takes post-guards: reported
-            # as effective_strategy and the label calibration samples land
-            # under (hints silently normalized here until this existed —
-            # neither traces nor bench could tell what actually ran)
+            # the physical route this dispatch takes: reported as
+            # effective_strategy
             per_agg_d = tuple(measures_d[i] for i in measure_index)
-            # normalize the hint BEFORE predicting/labelling the route: a
-            # hint the guards would normalize inside _mesh_partials (e.g.
+            # normalize a forced route BEFORE predicting/labelling it: one
+            # the guards would normalize inside _mesh_partials (e.g.
             # "scatter" on a backend whose auto dispatch internally sorts)
-            # must not be reported — or recorded into calibration cells —
-            # as a route the program never ran (the highcard cell-keying
-            # bug: "scatter"-labelled walls that were really the sort path)
+            # must not be reported as a route the program never ran
             strategy = _effective_mesh_strategy(
                 strategy, tuple(query.ops), n_prog, per_agg_d,
                 int(codes_d.shape[1]),
@@ -927,17 +919,12 @@ class MeshQueryExecutor:
                     strategy, per_agg_d, tuple(query.ops),
                     int(codes_d.shape[1]), n_prog,
                 )
-            from bqueryd_tpu.obs import profile as obs_profile
-
-            profiler = obs_profile.profiler()
             # a transiently-classed runtime error (_TRANSIENT_STATUSES: a
             # preempted or briefly unavailable device) gets one retry that
             # keeps the on-device merge path; a second failure propagates
             # to the worker, which degrades to the per-shard engine path.
             # Either way the firing is counted (devicehealth.note_degrade)
             for attempt in range(2):
-                misses_before = profiler.jit_cache_misses
-                kernel_clock = time.perf_counter()
                 try:
                     merged = _mesh_partials(
                         mesh, self.axis_name, query.ops, n_prog,
@@ -948,7 +935,6 @@ class MeshQueryExecutor:
                         merge_mode=merge_mode,
                         timer=self.timer,
                     )
-                    kernel_wall = time.perf_counter() - kernel_clock
                     break
                 except jax.errors.JaxRuntimeError as exc:
                     # deterministic failures (INVALID_ARGUMENT, device OOM)
@@ -959,24 +945,6 @@ class MeshQueryExecutor:
                         raise
                     devicehealth.note_degrade("inplace_retry")
                     time.sleep(0.5)
-            # measured-cost calibration sample (the planner feedback loop):
-            # walls tainted by a jit compile are skipped — a 20 s compile
-            # inside a 4 ms kernel wall would poison the route's EWMA
-            from bqueryd_tpu.plan import calibrate
-
-            if (
-                calibrate.enabled()
-                and profiler.jit_cache_misses == misses_before
-            ):
-                prog = profiler.last_program("executor.mesh_program")
-                calibrate.record_sample(
-                    rows=total_rows, groups=n_groups,
-                    dtypes=[m.dtype for m in per_agg_d],
-                    backend=jax.default_backend(),
-                    strategy=route, wall_s=kernel_wall,
-                    flops=(prog or {}).get("flops"),
-                    bytes_accessed=(prog or {}).get("bytes_accessed"),
-                )
             if n_prog != n_groups:
                 import jax as _jax
 
@@ -2593,15 +2561,12 @@ def _transient_status(exc):
 
 
 def _effective_mesh_strategy(strategy, agg_ops, n_groups, measures_d, width):
-    """Canonicalize a planner hint for the mesh-program cache key: a hint
-    that cannot change the traced route must key (and trace) exactly like
-    ``auto``, or an identical program would be compiled twice — a "matmul"
-    hint is advisory by definition (the dispatcher decides identically under
-    auto), a "scatter" hint is a no-op whenever auto would scatter anyway
-    (always on CPU backends, and past the matmul group ceiling), and the
-    calibration-backed "matmul!" normalizes to auto both when auto already
-    takes the MXU route (identical program) and when the kernel guards
-    would demote it (backend/value guards stand under promotion)."""
+    """Canonicalize a forced route for the mesh-program cache key: one that
+    cannot change the traced route must key (and trace) exactly like
+    ``auto``, or an identical program would be compiled twice — "matmul" is
+    advisory by definition (the dispatcher decides identically under auto)
+    and "scatter" is a no-op whenever auto would scatter anyway (always on
+    CPU backends, and past the matmul group ceiling)."""
     if strategy in (None, "auto", "matmul"):
         return None
     from bqueryd_tpu.ops import groupby as gb
@@ -2611,10 +2576,6 @@ def _effective_mesh_strategy(strategy, agg_ops, n_groups, measures_d, width):
     ) or gb._hicard_matmul_profitable(
         measures_d, agg_ops, width, int(n_groups)
     )
-    if strategy == "matmul!":
-        if mm or not gb.matmul_route_allowed(width, int(n_groups)):
-            return None
-        return strategy
     if strategy == "scatter" and not mm:
         return None
     if strategy == "sort" and not mm:

@@ -1,19 +1,13 @@
 """Query planning & admission: the controller's serving-layer brain.
 
-Four pieces, all control-plane safe (no JAX, no pandas):
+Five pieces, all control-plane safe (no JAX, no pandas):
 
 * :mod:`bqueryd_tpu.plan.logical`   — typed logical plans compiled from the
   ``groupby`` RPC, with rewrite rules (predicate pushdown, mean
   decomposition) and per-dispatch plan fragments;
 * :mod:`bqueryd_tpu.plan.stats`     — per-shard statistics (rows, column
-  min/max, key cardinality) gathered by workers, advertised in their
-  registration messages, and the stats-only shard pruning predicate;
-* :mod:`bqueryd_tpu.plan.strategy`  — cost-based kernel-route selection
-  (scatter vs sort+prefix-diff vs MXU limb-matmul) from those stats;
-* :mod:`bqueryd_tpu.plan.calibrate` — measured-cost calibration of that
-  selection: per-(rows, groups, dtype, backend, strategy) kernel walls
-  recorded by workers, gossiped in WRMs, refined online
-  (``BQUERYD_TPU_CALIB=0`` restores the pure heuristic);
+  min/max) gathered by workers, advertised in their registration messages,
+  and the stats-only shard pruning predicate;
 * :mod:`bqueryd_tpu.plan.admission` — bounded priority admission queue with
   per-client quotas, deadlines, and explicit BUSY backpressure;
 * :mod:`bqueryd_tpu.plan.bundle`    — shared-scan multi-query fusion: the
@@ -25,9 +19,11 @@ Four pieces, all control-plane safe (no JAX, no pandas):
   sketches, time-window rollups — compiled from query specs (and from
   plain groupbys, which round-trip bit-identically onto the engine path).
 
-``BQUERYD_TPU_PLANNER=0`` disables plan-time pruning and strategy hints
-(queries revert to the static fan-out); admission limits are controlled by
-their own env knobs (see :mod:`.admission`).
+``BQUERYD_TPU_PLANNER=0`` disables plan-time pruning (queries revert to the
+static fan-out); admission limits are controlled by their own env knobs (see
+:mod:`.admission`).  Which kernel answers a groupby is not planned here:
+``ops.groupby.kernel_route`` decides it on the worker from the actual rows,
+groups, ops and backend.
 """
 
 import os
@@ -52,22 +48,11 @@ from bqueryd_tpu.plan.stats import (  # noqa: F401
     gather_table_stats,
     stats_can_match,
 )
-from bqueryd_tpu.plan.strategy import (  # noqa: F401
-    STRATEGIES,
-    STRATEGY_AUTO,
-    STRATEGY_MATMUL_BINDING,
-    candidate_strategies,
-    choose_strategy,
-    estimate_groups,
-    select_calibrated,
-    select_for_group,
-)
 from bqueryd_tpu.plan import bundle  # noqa: F401
-from bqueryd_tpu.plan import calibrate  # noqa: F401
 from bqueryd_tpu.plan import dag  # noqa: F401
 
 
 def planner_enabled():
-    """Plan-time pruning + strategy hints; on unless BQUERYD_TPU_PLANNER=0.
+    """Plan-time shard pruning; on unless BQUERYD_TPU_PLANNER=0.
     Read per query so a live controller can be re-tuned."""
     return os.environ.get("BQUERYD_TPU_PLANNER", "1") != "0"

@@ -81,25 +81,19 @@ def compat_key(plan, keep, kwargs):
     )
 
 
-def bundle_fragment(plan, filenames, members, strategy=None, sole=False):
+def bundle_fragment(plan, filenames, members, sole=False):
     """The per-dispatch slice of a BUNDLE: what one CalcMessage executes
     for a whole compatible group.  Shared fields (shard group, group-key
-    columns, strategy hint) ride once; each member record carries only what
-    differs — its aggs, filter conjunction, deadline, and the ``member_id``
-    the reply demultiplexes on.
+    columns) ride once; each member record carries only what differs — its
+    aggs, filter conjunction, deadline, and the ``member_id`` the reply
+    demultiplexes on.
 
-    ``members`` is ``[(member_id, plan, deadline), ...]``.  The "matmul!"
-    binding promotion ships as advisory "matmul" + ``strategy_binding``
-    exactly like :func:`bqueryd_tpu.plan.logical.fragment_for` (same
-    mixed-version contract)."""
-    binding = strategy == "matmul!"
+    ``members`` is ``[(member_id, plan, deadline), ...]``."""
     return {
         "v": BUNDLE_VERSION,
         "filenames": list(filenames),
         "groupby_cols": list(plan.groupby.keys),
         "sole": bool(sole),
-        "strategy": "matmul" if binding else strategy,
-        "strategy_binding": binding,
         "members": [
             {
                 "member_id": member_id,
@@ -162,18 +156,3 @@ def member_shares(executed_ids, walls=None):
             }
     share = round(1.0 / len(executed), 6)
     return {m: share for m in executed}
-
-
-def fragment_strategy(fragment):
-    """The kernel-strategy hint a bundle fragment carries, with the binding
-    promotion reconstructed under the same ``BQUERYD_TPU_CALIB`` kill-switch
-    contract as the single-query plan fragment."""
-    strategy = fragment.get("strategy")
-    if strategy in (None, "auto"):
-        return None
-    if strategy == "matmul" and fragment.get("strategy_binding"):
-        from bqueryd_tpu.plan import calibrate
-
-        if calibrate.enabled():
-            return "matmul!"
-    return strategy

@@ -18,7 +18,7 @@ control plane can reason about *before* anything is dispatched:
   mergeable (it is also exactly what the kernels compute physically, so the
   rewrite documents and deduplicates rather than changes the wire math);
 * **fragment** — ``fragment_for`` cuts the per-dispatch slice of the plan (a
-  shard group, a kernel-strategy hint, the sole-payload flag) into a small
+  shard group, the sole-payload flag) into a small
   pickle-friendly dict a :class:`~bqueryd_tpu.messages.CalcMessage` carries
   under its ``plan`` binary field; ``fragment_to_query`` rebuilds the
   worker-side :class:`GroupByQuery` from it.
@@ -288,18 +288,11 @@ def plan_groupby(filenames, groupby_cols, agg_list, where_terms=None,
 
 # -- fragments ---------------------------------------------------------------
 
-def fragment_for(plan, filenames, strategy=None, sole=False):
+def fragment_for(plan, filenames, sole=False):
     """The per-dispatch slice of a plan: what ONE CalcMessage executes.
     Travels as the message's ``plan`` binary field (pickled, like params).
-
-    The calibration-backed binding promotion ("matmul!") deliberately never
-    rides the wire as a strategy VALUE: pre-calibration workers would
-    reject the unknown literal at the kernel (``KERNEL_STRATEGIES``
-    validation) and fail the query.  It ships as the advisory "matmul"
-    plus a separate ``strategy_binding`` flag — old workers ignore the
-    unknown key and degrade to the advisory semantics, which is exactly
-    the mixed-version contract MIGRATION.md promises."""
-    binding = strategy == "matmul!"
+    It names the work, never the kernel: the worker's
+    ``ops.groupby.kernel_route`` chooses that from what it observes."""
     return {
         "v": PLAN_VERSION,
         "filenames": list(filenames),
@@ -309,8 +302,6 @@ def fragment_for(plan, filenames, strategy=None, sole=False):
         "aggregate": bool(plan.aggregate_rows),
         "expand_filter_column": plan.expand_filter_column,
         "sole": bool(sole),
-        "strategy": "matmul" if binding else strategy,
-        "strategy_binding": binding,
     }
 
 

@@ -5,11 +5,7 @@ A shard's stats are metadata-only reads — nothing is decompressed:
 
 * ``rows`` from the table's meta.json;
 * per-column ``min``/``max`` from the chunk-writer stats in each column's
-  meta (:meth:`ctable.col_stats`), datetime columns in int64 ns;
-* per-column key ``card``inality from whichever cheap source exists:
-  a dict column's dictionary length, or the on-disk factorize sidecar
-  (``factor.npz``) written by a previous query — the ``uniques`` member is
-  read without touching the (much larger) codes array.
+  meta (:meth:`ctable.col_stats`), datetime columns in int64 ns.
 
 ``stats_can_match`` is the controller-side twin of
 :func:`bqueryd_tpu.ops.predicates.shard_can_match`: it decides from
@@ -25,36 +21,10 @@ Control-plane module: no JAX, no pandas.
 
 import os
 
-import numpy as np
-
 #: numbers the controller can compare against min/max stats without any
 #: column-kind translation (bool excluded on purpose: bool storage has no
 #: stats anyway)
 _NUMBER = (int, float)
-
-
-def _sidecar_cardinality(table, name):
-    """len(uniques) from the column's factorize sidecar, or None.  Loads only
-    the stamp + uniques members of the npz — never the row-length codes."""
-    path = table._col_path(name, "factor.npz")
-    stamp = table.factor_stamp(name)
-    if stamp is None or not os.path.exists(path):
-        return None
-    try:
-        with np.load(path, allow_pickle=False) as z:
-            if not np.array_equal(z["stamp"], stamp):
-                return None
-            return int(z["uniques"].shape[0])
-    except Exception:
-        return None
-
-
-def column_cardinality(table, name):
-    """Best-known distinct-value count for a column, or None (unknown)."""
-    if table.kind(name) == "dict":
-        dictionary = table.dictionary(name)
-        return None if dictionary is None else len(dictionary)
-    return _sidecar_cardinality(table, name)
 
 
 def _chunk_prefix_sig(table, name, count):
@@ -88,8 +58,7 @@ def gather_table_stats(table, prev=None):
     chunks an unchanged prefix, validated per column by the metadata-only
     ``sig`` fingerprint — the streaming-append signature), per-column work
     is incremental: min/max fold the NEW chunks' zone maps into the
-    previous bounds and an unchanged column's cardinality probe (the
-    factorize-sidecar npz open, the one non-O(1) read here) is skipped.
+    previous bounds.
     Any non-growth change — including an in-place replacement with
     different content — fails the fingerprint and falls back to the full
     gather."""
@@ -142,24 +111,6 @@ def gather_table_stats(table, prev=None):
             stats = table.col_stats(name)
             if stats is not None:
                 entry["min"], entry["max"] = stats
-        if kind == "dict":
-            # exact and O(1): the persistent dictionary only ever grows
-            dictionary = table.dictionary(name)
-            if dictionary is not None:
-                entry["card"] = len(dictionary)
-        elif grown and nchunks == pentry["chunks"] and "card" in pentry:
-            # unchanged column: reuse instead of re-opening the sidecar
-            entry["card"] = pentry["card"]
-        elif grown and nchunks > pentry["chunks"]:
-            # appended column: its factorize sidecar is provably stale
-            # (the stamp covers the data bytes), so the probe can only
-            # miss — skip it; cardinality re-advertises after the next
-            # query re-factorizes and stores a fresh sidecar
-            pass
-        else:
-            card = column_cardinality(table, name)
-            if card is not None:
-                entry["card"] = card
         cols[name] = entry
     return {"rows": int(table.nrows), "cols": cols}
 
@@ -169,9 +120,7 @@ class StatsCollector:
 
     Called from both the worker's main loop and its liveness heartbeat
     thread, so gathering must stay cheap: full stats are memoized per shard
-    and re-gathered only when the shard's meta identity or its factorize
-    sidecars change (a query writing a new sidecar refreshes the advertised
-    cardinality on the next heartbeat)."""
+    and re-gathered only when the shard's meta identity changes."""
 
     #: min seconds between full stamp sweeps: inside the window collect()
     #: returns the previous snapshot OBJECT without touching the filesystem,
@@ -202,25 +151,13 @@ class StatsCollector:
         self._snapshot_names = None
         self._snapshot_ts = 0.0
 
-    def _stamp(self, rootdir, table):
-        """Identity of everything the stats derive from: the table meta plus
-        every column's factor sidecar mtime (present or absent)."""
-        from bqueryd_tpu.storage.ctable import rootdir_cache_key
-
-        parts = [rootdir_cache_key(rootdir)]
-        for name in table.names:
-            try:
-                st = os.stat(table._col_path(name, "factor.npz"))
-                parts.append((name, st.st_mtime_ns, st.st_size))
-            except OSError:
-                parts.append((name, None))
-        return tuple(parts)
-
     def collect(self, data_dir, names):
         """{shard name: stats} for every shard that opens cleanly.  Returns
         the SAME dict object until the refresh window elapses or the shard
         list changes — callers may use identity to detect staleness."""
         import time
+
+        from bqueryd_tpu.storage.ctable import rootdir_cache_key
 
         now = time.time()
         if (
@@ -238,14 +175,14 @@ class StatsCollector:
                     if self._open is not None
                     else _default_open(rootdir)
                 )
-                stamp = self._stamp(rootdir, table)
+                stamp = rootdir_cache_key(rootdir)
                 hit = self._memo.get(name)
                 if hit is not None and hit[0] == stamp:
                     out[name] = hit[1]
                     continue
                 # stale memo: re-gather INCREMENTALLY against the previous
                 # snapshot (append-grown shards fold only the new chunks'
-                # zone maps and skip unchanged cardinality probes)
+                # zone maps)
                 stats = gather_table_stats(
                     table, prev=hit[1] if hit is not None else None
                 )

@@ -87,7 +87,8 @@ class RPC:
         self.last_trace_id = None
         #: per-shard-group phase timings / strategy report of the most
         #: recent groupby reply ({"hints": ..., "effective": ...} for the
-        #: latter — what the planner asked for vs what actually compiled)
+        #: latter — shards dispatched ({"auto": n}) and the kernel route
+        #: each shard group's worker took)
         self.last_call_timings = None
         self.last_call_strategies = None
         #: per-shard-group merge modes of the most recent groupby reply

@@ -5,7 +5,7 @@ serving.  It composes the two halves of the subsystem:
 
 * :mod:`bqueryd_tpu.serve.subsume` — the pure plan-subsumption lattice
   (exact / window-fold / key-fold / zone-proof matching plus the
-  calibrated source choice);
+  costed source choice);
 * :mod:`bqueryd_tpu.serve.rollup` — heat tracking and the materialized
   rollup entry lifecycle (build / delta-refresh / evict, append-epoch
   staleness).

@@ -32,8 +32,7 @@ sketches, raw rows, basket expansion), joins, and anything this module
 cannot prove falls back to ``recompute`` — the dispatch path is always
 correct, serving is only ever an optimization.
 
-The chosen source is costed through the PR-6 calibration model
-(:func:`bqueryd_tpu.plan.calibrate.analytic_units`): folding a
+The chosen source is costed through :func:`analytic_units`: folding a
 G-group partial must be cheaper than re-scanning N rows, which it is
 whenever G << N — the *Global Hash Tables Strike Back!* observation this
 layer is built on.
@@ -41,6 +40,8 @@ layer is built on.
 Pure control-plane module: NumPy only, importable by the (JAX-free)
 controller; all functions are deterministic on their inputs.
 """
+
+import math
 
 from bqueryd_tpu.models.query import MERGEABLE_OPS
 
@@ -296,28 +297,41 @@ def apply_transform(payload, transform):
     return hostmerge.collapse_partials(p)
 
 
+def analytic_units(strategy, rows, groups):
+    """Backend-free relative cost of a route at (rows, groups) — the same
+    quantities HLO ``cost_analysis`` counts, in arbitrary units: the one-hot
+    contraction is rows x groups MACs, the blocked scatter is a per-limb
+    rows pass plus its ``blocks x groups`` table, the sort is
+    ``rows log rows`` comparisons per limb."""
+    rows = max(int(rows), 1)
+    groups = max(int(groups), 1)
+    if strategy == "matmul":
+        return float(rows) * groups
+    if strategy == "sort":
+        return float(rows) * max(math.log2(max(rows, 2)), 1.0) * 8.0
+    # scatter: 4 16-bit limb passes over rows + the blocked bucket table,
+    # whose blocks x groups cells are written AND reduced (memory-bound) —
+    # the term that makes extreme cardinality favour the sort, matching the
+    # engine's own _MAX_BLOCK_SEGMENTS economics
+    blocks = -(-rows // 65536)
+    return float(rows) * 8.0 + float(blocks) * groups * 8.0
+
+
 def serving_cost(groups, out_groups):
     """Relative cost of answering from a G-group partial (host fold)."""
-    from bqueryd_tpu.plan import calibrate
-
-    return calibrate.analytic_units("scatter", groups, max(out_groups, 1))
+    return analytic_units("scatter", groups, max(out_groups, 1))
 
 
 def recompute_cost(total_rows, out_groups):
     """Relative cost of the dispatch path re-scanning ``total_rows``."""
-    from bqueryd_tpu.plan import calibrate
-
-    return calibrate.analytic_units(
-        "scatter", max(total_rows, 1), max(out_groups, 1)
-    )
+    return analytic_units("scatter", max(total_rows, 1), max(out_groups, 1))
 
 
 def choose_source(matches, total_rows):
     """Pick the cheapest-correct candidate: ``matches`` is a list of
     ``(entry_key, transform, candidate_group_rows)``; returns the winning
     tuple or None when recompute is estimated cheaper than every candidate
-    (tiny tables) — the calibration-model cost decision the lattice defers
-    to."""
+    (tiny tables) — the cost decision the lattice defers to."""
     best = None
     floor = recompute_cost(total_rows, 1)
     for entry_key, transform, groups in matches:

@@ -529,18 +529,6 @@ class WorkerBase:
         except Exception:
             self.logger.exception("SIGUSR1 debug dump failed")
 
-    def _calibration_to_advertise(self):
-        """The WRM calibration summary, or None (non-calc role, disabled,
-        or cold) — a calibration failure must never break liveness."""
-        if getattr(self, "workertype", None) != "calc":
-            return None
-        try:
-            from bqueryd_tpu.plan import calibrate
-
-            return calibrate.summary_for_wire()
-        except Exception:
-            return None
-
     def _pipeline_busy_to_advertise(self):
         """The StageClock busy snapshot riding calc WRMs: the controller's
         capacity model (obs.capacity) reads per-stage busy DELTAS from it
@@ -590,17 +578,11 @@ class WorkerBase:
                 # registry + device health), absorbed controller-side for
                 # rpc.debug_bundle()
                 "debug": debug,
-                # metadata-only per-shard stats (rows, min/max, cardinality)
-                # feeding the controller's plan-time pruning and kernel-
-                # strategy selection; None for non-calc roles and for beats
-                # where the unchanged stats were advertised recently
+                # metadata-only per-shard stats (rows, min/max) feeding the
+                # controller's plan-time pruning; None for non-calc roles
+                # and for beats where the unchanged stats were advertised
+                # recently
                 "shard_stats": self._stats_to_advertise(),
-                # measured-cost calibration summary (plan.calibrate): the
-                # worker's per-(rows, groups, dtype, backend, strategy)
-                # kernel-wall cells, absorbed controller-side into the
-                # model select_calibrated consults; None when calibration
-                # is disabled or nothing has been measured yet
-                "calibration": self._calibration_to_advertise(),
                 # latency histogram snapshot (fixed buckets, JSON-safe):
                 # controllers aggregate these fleet-wide by bucket-vector
                 # addition (get_info "worker_histograms" + peer gossip)
@@ -1242,7 +1224,7 @@ class WorkerNode(WorkerBase):
         """Metadata-only stats for every advertised shard (memoized; see
         plan.stats.StatsCollector).  Disable with BQUERYD_TPU_SHARD_STATS=0
         — the planner then treats this worker's shards as stats-less (no
-        pruning, auto strategy)."""
+        pruning)."""
         if os.environ.get("BQUERYD_TPU_SHARD_STATS", "1") == "0":
             return None
         # getattr defences: embedders (and tests) build workers piecemeal,
@@ -1558,18 +1540,14 @@ class WorkerNode(WorkerBase):
         reply.add_as_binary("rollup_zones", self._rollup_census(table))
         return reply
 
-    def _execute(self, tables, query, timer, strategy=None):
+    def _execute(self, tables, query, timer):
         """Psum-mergeable aggregations (any shard count) -> mesh executor
         (on-device merge + HBM-resident caches); distinct-count / raw-rows
         single shard -> single-device engine; other multi-shard shapes ->
         per-shard engine + host value-keyed merge.  Always returns ONE
-        payload per CalcMessage.
-
-        ``strategy`` is the planner's kernel-route hint from the plan
-        fragment: "host" skips the mesh outright (the engine path forces the
-        NumPy kernels); device routes thread into the mesh program / engine
-        dispatch.  Hints never override survival routing — a wedged backend
-        still host-routes everything."""
+        payload per CalcMessage.  The kernel route is the kernel
+        dispatcher's (``ops.groupby.kernel_route``); what it took is kept
+        in ``_last_effective_strategy`` for the reply."""
         from bqueryd_tpu.models.query import (
             _host_ns_estimate,
             host_kernel_rows,
@@ -1578,9 +1556,7 @@ class WorkerNode(WorkerBase):
         from bqueryd_tpu.parallel import hostmerge
         from bqueryd_tpu.parallel.executor import MeshQueryExecutor
 
-        # what the kernel actually ran post-guards, for the reply envelope /
-        # kernel span (satellite: hints used to normalize silently and
-        # nothing could tell what executed)
+        # what the kernel actually ran, for the reply envelope / kernel span
         self._last_effective_strategy = None
         # detail (BQUERYD_TPU_PROFILE=1 only): the form the mesh executor's
         # float64 sums took, the aggregate_wait span's ``float_sum`` tag
@@ -1618,8 +1594,7 @@ class WorkerNode(WorkerBase):
         # A wedged accelerator backend skips the mesh outright: the engine
         # path below host-routes everything (host_kernel_rows returns its
         # wedged sentinel) instead of hanging on a device dispatch.
-        if strategy != "host" and not devicehealth.backend_wedged(
-        ) and MeshQueryExecutor.supports(
+        if not devicehealth.backend_wedged() and MeshQueryExecutor.supports(
             query
         ) and total_rows > host_kernel_rows(
             max(
@@ -1639,9 +1614,7 @@ class WorkerNode(WorkerBase):
             import jax
 
             try:
-                result = self.mesh_executor.execute(
-                    tables, query, strategy=strategy
-                )
+                result = self.mesh_executor.execute(tables, query)
                 self._last_effective_strategy = (
                     self.mesh_executor.last_effective_strategy
                 )
@@ -1672,9 +1645,7 @@ class WorkerNode(WorkerBase):
                 )
         if len(tables) == 1:
             self.engine.timer = timer
-            result = self.engine.execute_local(
-                tables[0], query, strategy=strategy
-            )
+            result = self.engine.execute_local(tables[0], query)
             self._last_effective_strategy = (
                 self.engine.last_effective_strategy
             )
@@ -1690,7 +1661,7 @@ class WorkerNode(WorkerBase):
         from bqueryd_tpu.parallel import pipeline
 
         payloads = pipeline.map_ordered(
-            lambda t: self.engine.execute_local(t, query, strategy=strategy),
+            lambda t: self.engine.execute_local(t, query),
             tables,
         )
         # shards share one query shape, so the engine's last route speaks
@@ -1850,36 +1821,19 @@ class WorkerNode(WorkerBase):
                 dag = dagmod.OperatorDAG.from_wire(msg.get_from_binary("dag"))
                 dag.sole_payload = bool(msg.get("sole_shard"))
                 query = dag.plain_groupby_query()
-                strategy = None
             else:
                 # a planning controller ships the compiled plan fragment
                 # alongside the reference-shaped params: the fragment is
-                # authoritative (it carries the rewritten query + the
-                # kernel-strategy hint); bare params keep working for
-                # mixed-version clusters and direct tests
+                # authoritative (it carries the rewritten query); bare
+                # params keep working for mixed-version clusters and direct
+                # tests
                 fragment = (
                     msg.get_from_binary("plan") if msg.get("plan") else None
                 )
-                strategy = None
                 if fragment:
-                    from bqueryd_tpu.plan import calibrate, fragment_to_query
+                    from bqueryd_tpu.plan import fragment_to_query
 
                     query = fragment_to_query(fragment)
-                    strategy = fragment.get("strategy")
-                    if strategy in (None, "auto"):
-                        strategy = None
-                    elif strategy == "matmul" and fragment.get(
-                        "strategy_binding"
-                    ):
-                        # calibration-backed promotion rides the wire as
-                        # advisory "matmul" + this flag (old workers ignore it
-                        # — see plan.logical.fragment_for); reconstruct the
-                        # binding form unless BQUERYD_TPU_CALIB=0, the kill
-                        # switch that restores pre-calibration behaviour
-                        # exactly on this worker even when a calibrating
-                        # controller emitted the promotion
-                        if calibrate.enabled():
-                            strategy = "matmul!"
                 else:
                     query = GroupByQuery(
                         groupby_cols,
@@ -1955,9 +1909,7 @@ class WorkerNode(WorkerBase):
             if query is not None:
                 # plain shape: the unchanged engine/mesh path —
                 # bit-identical to the pre-DAG hardwired sequence
-                payload = self._execute(
-                    tables, query, timer, strategy=strategy
-                )
+                payload = self._execute(tables, query, timer)
             else:
                 payload = self._execute_dag(tables, dag, timer)
             effective = getattr(self, "_last_effective_strategy", None)
@@ -2070,13 +2022,10 @@ class WorkerNode(WorkerBase):
         remaining = msg.deadline_remaining()
         if remaining is not None:
             reply["deadline_remaining"] = round(remaining, 4)
-        if strategy is not None:
-            reply["strategy"] = strategy
         if effective is not None:
-            # post-guard reality, distinct from the hint: declared in
+            # the route the kernel rule took: declared in
             # messages.RESULT_ENVELOPE_SCHEMA/ENVELOPE_SCHEMA, folded by the
-            # controller into the client result envelope and bench's
-            # chosen_strategy
+            # controller into the client result envelope
             reply["effective_strategy"] = effective
         if merge_mode is not None:
             # how this reply's partials merged: "device" (ICI-mesh
@@ -2178,7 +2127,6 @@ class WorkerNode(WorkerBase):
         with tracing.detail("parse", timer):
             fragment = msg.get_from_binary("bundle")
             members = bundlemod.bundle_to_queries(fragment)
-            strategy = bundlemod.fragment_strategy(fragment)
             filename = msg.get("filename") or fragment.get("filenames")
             filenames = filename if isinstance(filename, list) else [filename]
         tables = []
@@ -2228,7 +2176,7 @@ class WorkerNode(WorkerBase):
 
                 try:
                     mesh_payloads = self.mesh_executor_for_bundle(
-                        tables, queries, timer, strategy
+                        tables, queries, timer
                     )
                 except chaos.TransientError:
                     # a transient device fault fails the whole bundle over
@@ -2261,7 +2209,7 @@ class WorkerNode(WorkerBase):
                     try:
                         exec_clock = time.perf_counter()
                         results[member_id] = self._execute(
-                            tables, query, timer, strategy=strategy
+                            tables, query, timer
                         )
                         member_walls[member_id] = (
                             time.perf_counter() - exec_clock
@@ -2348,15 +2296,13 @@ class WorkerNode(WorkerBase):
         )
         return reply
 
-    def mesh_executor_for_bundle(self, tables, queries, timer, strategy):
+    def mesh_executor_for_bundle(self, tables, queries, timer):
         """Run the shared-scan mesh path for a bundle (seam kept separate
         so tests can spy on it): returns per-member ResultPayloads."""
         self._last_effective_strategy = None
         self._last_merge_mode = None
         self.mesh_executor.timer = timer
-        payloads = self.mesh_executor.execute_bundle(
-            tables, queries, strategy=strategy
-        )
+        payloads = self.mesh_executor.execute_bundle(tables, queries)
         self._last_effective_strategy = (
             self.mesh_executor.last_effective_strategy
         )

@@ -70,9 +70,7 @@ SLICE_SETTLE_S = 25.0    # > the worker's slowest heartbeat (20 s main loop)
 #: in a reply's ``effective`` — "host", "cached", "delta" — did not
 DEVICE_ROUTES = frozenset({"matmul", "scatter", "sort"})
 #: query -> the route its reply must name.  The MXU limb matmul is the TPU
-#: default for these four ("scatter" there means the backend was misread —
-#: unless the planner's own hint named it: plan.calibrate explores an
-#: unmeasured route on every 20th decision of a measured bucket);
+#: default for these four ("scatter" there means the backend was misread);
 #: 70 225 groups and float64 sums take another device route
 EXPECTED_ROUTES = {
     "single": {"matmul"},
@@ -510,27 +508,21 @@ def check_worker_state(snap, expect_platform):
 
 
 def check_reply(query, rpc):
-    """The reply itself names a device route and the device merge.  A route
-    other than the query's default passes only where the planner's hint
-    asked for exactly that device route."""
+    """The reply itself names a device route — the one the kernel rule
+    takes for this query — and the device merge."""
     strategies = rpc.last_call_strategies or {}
     effective = set(strategies.get("effective", {}).values())
-    steered = set(strategies.get("hints", {})) & DEVICE_ROUTES
     check(
-        effective and effective <= EXPECTED_ROUTES[query] | steered,
+        effective and effective <= EXPECTED_ROUTES[query],
         f"{query}: answered by route(s) {sorted(effective) or 'none'}, "
-        f"expected {sorted(EXPECTED_ROUTES[query])} "
-        f"(planner hints: {strategies.get('hints')})",
+        f"expected {sorted(EXPECTED_ROUTES[query])}",
     )
     merges = set((rpc.last_call_merge_modes or {}).values())
     check(
         merges == {"device"},
         f"{query}: merge mode(s) {sorted(merges) or 'none'}, not 'device'",
     )
-    route = "/".join(sorted(effective))
-    if not effective <= EXPECTED_ROUTES[query]:
-        route += "(planner-hinted)"
-    return route
+    return "/".join(sorted(effective))
 
 
 def check_devices_used(snap):
@@ -578,10 +570,13 @@ def run_queries(rpc, cluster, names, frames, queries=tuple(EXPECTED_ROUTES),
             answer = got
         warm = walls[1:]
         rows = len(frames[0]) if query == "single" else rows_total
-        # one name when every repeat took the same route, else all of them
-        # (a planner-steered repeat compiles another program: its wall is
-        # a cold one)
-        route = routes[0] if len(set(routes)) == 1 else ",".join(routes)
+        # the kernel rule reads only the query and the data: a repeat that
+        # took another route compiled another program, so its wall is cold
+        check(
+            len(set(routes)) == 1,
+            f"{query}: repeats of one query took routes {routes}",
+        )
+        route = routes[0]
         results[query] = {
             "cold_s": walls[0], "warm_s": warm, "answer": answer,
             "route": route, "rows": rows,

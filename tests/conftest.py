@@ -50,19 +50,6 @@ def _disarmed_chaos():
         sys.modules["bqueryd_tpu.chaos"]._reset_for_tests()
 
 
-@pytest.fixture(autouse=True)
-def _fresh_calibration_store():
-    """Reset the process-global measured-cost calibration store between
-    tests: samples recorded by one test's executor runs must not tilt a
-    later test's planner decisions (the cold-start contract under test is
-    'no samples -> heuristic, bit for bit')."""
-    import sys
-
-    if "bqueryd_tpu.plan.calibrate" in sys.modules:
-        sys.modules["bqueryd_tpu.plan.calibrate"]._reset_for_tests()
-    yield
-
-
 @pytest.fixture
 def mem_store_url():
     """A fresh, flushed mem:// coordination store per test."""

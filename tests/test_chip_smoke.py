@@ -85,10 +85,8 @@ def test_cluster_phase_on_cpu_with_a_jax_free_client(tmp_path):
         "platform": "cpu", "device_kind": "cpu", "count": 8,
     }
     assert set(summary["queries"]) == set(chip_smoke.EXPECTED_ROUTES)
-    for name in ("single", "sharded", "multikey"):
-        # the planner cannot have measured these buckets yet (it needs three
-        # clean samples), so their cold decisions are the default route
-        assert summary["queries"][name]["route"].split(",")[0] == "matmul"
+    for name in ("single", "sharded", "multikey", "filtered"):
+        assert summary["queries"][name]["route"] == "matmul"
     restart = [l for l in lines if l.startswith("restart ")][0]
     assert f"compile_cache={tmp_path / 'compile_cache'}" in restart
     # the worker released everything it started
@@ -213,10 +211,10 @@ def test_last_line_is_exactly_the_chip_checks_object(monkeypatch, capsys):
     assert lines[-2].endswith('"claim": null}')
 
 
-def _reply(effective, merge="device", hints=("auto",)):
+def _reply(effective, merge="device"):
     return types.SimpleNamespace(
         last_call_strategies={
-            "hints": dict.fromkeys(hints, 1),
+            "hints": {"auto": 1},
             "effective": {"taxi_0.bcolzs+9more": effective},
         },
         last_call_merge_modes={"taxi_0.bcolzs+9more": merge},
@@ -231,12 +229,9 @@ def test_reply_checks():
         with pytest.raises(chip_smoke.SmokeFailure, match=route):
             chip_smoke.check_reply("highcard", _reply(route))
     # "scatter" where the MXU route is the default means the backend was
-    # misread — unless the planner's own hint asked for it
+    # misread
     with pytest.raises(chip_smoke.SmokeFailure, match="scatter"):
         chip_smoke.check_reply("sharded", _reply("scatter"))
-    assert chip_smoke.check_reply(
-        "sharded", _reply("scatter", hints=("scatter",))
-    ) == "scatter(planner-hinted)"
     with pytest.raises(chip_smoke.SmokeFailure, match="merge mode"):
         chip_smoke.check_reply("sharded", _reply("matmul", merge="host"))
 

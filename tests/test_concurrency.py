@@ -125,7 +125,7 @@ def test_bundle_fragment_round_trip():
     p1 = _plan(keep, ["k"], [["v", "sum", "v"]], [["w", ">", 2.0]])
     p2 = _plan(keep, ["k"], [["v", "mean", "m"]])
     fragment = bundlemod.bundle_fragment(
-        p1, keep, [("m1", p1, None), ("m2", p2, 123.0)], strategy="scatter",
+        p1, keep, [("m1", p1, None), ("m2", p2, 123.0)],
     )
     members = bundlemod.bundle_to_queries(fragment)
     assert [m[0] for m in members] == ["m1", "m2"]
@@ -135,15 +135,9 @@ def test_bundle_fragment_round_trip():
     assert q1.agg_list == [["v", "sum", "v"]]
     # mean decomposition round-trips through the physical form
     assert q2.ops == ("mean",)
-    assert bundlemod.fragment_strategy(fragment) == "scatter"
-    # the binding promotion ships as advisory matmul + flag (mixed-version
-    # contract) and reconstructs only under an enabled calibration
-    binding = bundlemod.bundle_fragment(
-        p1, keep, [("m1", p1, None)], strategy="matmul!",
-    )
-    assert binding["strategy"] == "matmul"
-    assert binding["strategy_binding"] is True
-    assert bundlemod.fragment_strategy(binding) == "matmul!"
+    # a bundle names its members' work, never the kernel
+    assert set(fragment) == {
+        "v", "filenames", "groupby_cols", "sole", "members"}
     with pytest.raises(ValueError):
         bundlemod.bundle_to_queries({"v": 99, "members": []})
 

@@ -231,7 +231,6 @@ def test_gather_stats_incremental_on_append(tmp_path, monkeypatch):
     t1 = ctable(root)
     prev = gather_table_stats(t1)
     assert prev["cols"]["v"]["chunks"] == 4
-    assert prev["cols"]["s"]["card"] == 3
     ctable(root, mode="a").append_dataframe(
         pd.DataFrame(
             {
@@ -242,22 +241,19 @@ def test_gather_stats_incremental_on_append(tmp_path, monkeypatch):
         )
     )
     t2 = ctable(root)
-    # the incremental path must not re-probe unchanged sidecars
-    import bqueryd_tpu.plan.stats as stats_mod
-
+    # the incremental path folds the new chunk's zone map into the previous
+    # bounds: it must not re-read the whole column's stats
     calls = []
-    real = stats_mod._sidecar_cardinality
+    real = t2.col_stats
     monkeypatch.setattr(
-        stats_mod, "_sidecar_cardinality",
-        lambda table, name: calls.append(name) or real(table, name),
+        t2, "col_stats", lambda name: calls.append(name) or real(name),
     )
     fresh = gather_table_stats(t2, prev=prev)
-    assert calls == [], "grown-only columns must skip the sidecar probe"
+    assert "v" not in calls, "a grown column must fold, not re-gather"
     assert fresh["rows"] == 401
     assert fresh["cols"]["v"]["max"] == 5000     # folded from the new chunk
     assert fresh["cols"]["v"]["min"] == prev["cols"]["v"]["min"]
     assert fresh["cols"]["v"]["chunks"] == 5
-    assert fresh["cols"]["s"]["card"] == 4       # dictionary stays exact
     # parity with the full gather
     full = gather_table_stats(t2)
     assert fresh["cols"]["v"]["min"] == full["cols"]["v"]["min"]
@@ -282,7 +278,7 @@ def test_gather_stats_rejects_in_place_replacement(tmp_path):
     full = gather_table_stats(ctable(root))
     assert fresh["cols"]["v"]["min"] == full["cols"]["v"]["min"] < 0
     assert fresh["cols"]["v"]["max"] == full["cols"]["v"]["max"]
-    assert fresh["cols"]["s"].get("card") == full["cols"]["s"].get("card")
+    assert fresh == full
 
 
 def test_stats_collector_invalidate_drops_window(tmp_path):

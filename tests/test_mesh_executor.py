@@ -797,8 +797,8 @@ def test_float64_mean_rides_the_matmul_route_with_a_dense_sum(
         sharded, groupby_as_accelerator, monkeypatch, n_devices, profile):
     """PR 31: a float64 mean over few groups goes by the MXU route (its
     counts are rows of the dot) and sums densely, per device of the mesh,
-    whatever matmul hint the planner sends; the form is reported only under
-    the profile switch."""
+    under auto and under the advisory "matmul" alike; the form is reported
+    only under the profile switch."""
     df, tables = sharded
     if profile:
         monkeypatch.setenv("BQUERYD_TPU_PROFILE", profile)
@@ -817,7 +817,7 @@ def test_float64_mean_rides_the_matmul_route_with_a_dense_sum(
     from bqueryd_tpu.parallel import executor as ex_mod
 
     traces = set()
-    for hint in (None, "matmul", "matmul!"):
+    for hint in (None, "auto", "matmul"):
         payload = executor.execute(tables, query, strategy=hint)
         traces.add(ex_mod._mesh_program.cache_info().misses)
         assert executor.last_effective_strategy == "matmul"
@@ -828,8 +828,21 @@ def test_float64_mean_rides_the_matmul_route_with_a_dense_sum(
             )
         )
         assert_frames_match(got, expected, ["passenger_count"], rtol=1e-12)
-    assert len(traces) == 1, "three hints, ONE traced program"
+    assert len(traces) == 1, "three spellings of auto, ONE traced program"
     executor.execute(tables, query, strategy="scatter")
     assert ex_mod._mesh_program.cache_info().misses not in traces
     assert executor.last_effective_strategy == "scatter"
     assert executor.last_float_sum == ("dense" if profile else None)
+
+
+def test_mesh_executor_reports_route(sharded):
+    """``last_effective_strategy`` is the route the kernel rule took: the
+    MXU route under auto (conftest's FORCE_MATMUL=1), the forced route
+    where a test forces one."""
+    _df, tables = sharded
+    executor = MeshQueryExecutor(mesh=make_mesh())
+    query = GroupByQuery(["passenger_count"], [["payment_type", "sum", "s"]])
+    executor.execute(tables, query)
+    assert executor.last_effective_strategy == "matmul"
+    executor.execute(tables, query, strategy="scatter")
+    assert executor.last_effective_strategy == "scatter"

@@ -1522,13 +1522,12 @@ def _mesh_cache_key(strategy, n_groups=10, width=4096):
     )
 
 
-@pytest.mark.parametrize("hint", [None, "auto", "matmul", "matmul!"])
+@pytest.mark.parametrize("hint", [None, "auto", "matmul"])
 def test_a_float64_mean_goes_by_matmul_under_every_matmul_hint(
         monkeypatch, hint):
     """Where ``matmul_route_allowed`` holds, ``rows`` and the mean's count
-    are two rows of the stacked dot: auto, the planner's advisory hint and
-    the calibration-backed one are ONE route, ONE traced program (one mesh
-    cache key) and one calibration label."""
+    are two rows of the stacked dot: auto and the advisory "matmul" are ONE
+    route and ONE traced program (one mesh cache key)."""
     monkeypatch.setenv("BQUERYD_TPU_FORCE_MATMUL", "1")
     m = _groupby_module()
     floats = (np.zeros(8, np.float64),)
@@ -1546,7 +1545,7 @@ def test_scatter_and_sort_stay_binding_for_a_float64_mean(monkeypatch, hint):
     assert _mesh_cache_key(hint) == hint
 
 
-@pytest.mark.parametrize("hint", [None, "matmul", "matmul!"])
+@pytest.mark.parametrize("hint", [None, "matmul"])
 def test_a_float64_mean_still_scatters_on_the_cpu_backend(monkeypatch, hint):
     """The backend guard stands: without the force flag nothing changes
     route here, and the sum is this backend's own segment sum (no tag)."""
@@ -1590,3 +1589,132 @@ def test_float_sum_route_names_the_float64_sums_only(
         strategy = None
     assert gb.kernel_route(strategy, measures, (op,), 4096, 10) == route
     assert gb.float_sum_route(strategy, measures, (op,), 4096, 10) == expect
+
+
+# -- the ONE route rule --------------------------------------------------------
+
+def test_kernel_route_predictions(monkeypatch):
+    ints = [np.zeros(8, np.int64)]
+    assert gb.kernel_route("scatter", ints, ("sum",), 10_000, 9) == "scatter"
+    assert gb.kernel_route("sort", ints, ("sum",), 10_000, 9) == "sort"
+    assert gb.kernel_route(None, ints, ("sum",), 10_000, 9) == "matmul"
+    assert gb.kernel_route(None, ints, ("min",), 10_000, 9) == "scatter"
+    # past the blocks x groups budget the adaptive scatter sorts
+    assert gb.kernel_route(
+        None, ints, ("sum",), 10_000_000, 1_000_000
+    ) == "sort"
+    monkeypatch.delenv("BQUERYD_TPU_FORCE_MATMUL", raising=False)
+    assert gb.kernel_route(
+        "matmul", ints, ("sum",), 10_000, 9
+    ) == "scatter"  # backend guard: the advisory route cannot force it
+
+
+#: a case of the route table: the backend ``ops.groupby`` reads ("cpu" is
+#: this backend without the force flag, "cpu+force" with it, "tpu" the
+#: accelerator reading of ``groupby_as_accelerator``), rows, groups, the
+#: measures' (dtype, op) pairs, the route ``auto`` takes, and whether the
+#: shape is small enough to run
+_SUM = ((np.int64, "sum"),)
+_BLOCK = 65536  # ops.groupby._SUM_BLOCK
+_ROUTE_TABLE = [
+    pytest.param("cpu+force", 5000, 1, _SUM, "matmul", True, id="groups=1"),
+    pytest.param("cpu+force", 5000, 8192, _SUM, "matmul", True,
+                 id="groups=8192:the-ceiling"),
+    pytest.param("cpu+force", 5000, 8193, _SUM, "scatter", True,
+                 id="groups=8193:past-the-ceiling"),
+    pytest.param("cpu+force", (1 << 36) // 8192, 8192, _SUM, "matmul", False,
+                 id="cells=2^36:the-cap"),
+    pytest.param("cpu+force", (1 << 36) // 8192 + 1, 8192, _SUM, "scatter",
+                 False, id="cells=2^36+8192:past-the-cap"),
+    pytest.param("cpu+force", 5000, 10,
+                 ((np.float64, "min"), (np.int64, "max")), "scatter", True,
+                 id="min-max-only"),
+    pytest.param("cpu+force", 5000, 10,
+                 ((np.float64, "min"), (np.int64, "count")), "matmul", True,
+                 id="min-beside-a-count"),
+    pytest.param("cpu+force", 5000, 10, (), "matmul", True, id="rows-only"),
+    pytest.param("cpu+force", 5000, 10, ((np.float64, "mean"),), "matmul",
+                 True, id="float64-mean"),
+    pytest.param("cpu+force", 5000, 8193, ((np.float64, "mean"),), "scatter",
+                 True, id="float64-mean:past-the-ceiling"),
+    pytest.param("cpu+force", 5000, 37, _SUM, "matmul", True,
+                 id="int64-sum:cpu-with-FORCE_MATMUL"),
+    pytest.param("cpu", 5000, 37, _SUM, "scatter", True,
+                 id="int64-sum:cpu-without-FORCE_MATMUL"),
+    pytest.param("tpu", 5000, 37, _SUM, "matmul", True,
+                 id="int64-sum:accelerator"),
+    pytest.param("tpu", 5000, 8193, _SUM, "scatter", True,
+                 id="groups=8193:accelerator"),
+    pytest.param("cpu", 1024 * _BLOCK, (1 << 25) // 1024, _SUM, "scatter",
+                 False, id="blocks*groups=2^25:the-budget"),
+    pytest.param("cpu", 1024 * _BLOCK, (1 << 25) // 1024 + 1, _SUM, "sort",
+                 False, id="blocks*groups=2^25+1024:past-the-budget"),
+    pytest.param("tpu", 1024 * _BLOCK + 1, (1 << 25) // 1024, _SUM, "sort",
+                 False, id="one-row-more-is-one-block-more:accelerator"),
+]
+
+
+@pytest.mark.parametrize("backend, n, n_groups, aggs, route, runs",
+                         _ROUTE_TABLE)
+def test_route_table(request, monkeypatch, backend, n, n_groups, aggs, route,
+                     runs):
+    """The ONE rule that routes a served query, a case each side of every
+    boundary: ``kernel_route`` under ``auto`` names the route, the
+    dispatcher enters that route's kernel, and the answer is the named
+    route's own, bit for bit."""
+    import unittest.mock as mock
+
+    import jax
+
+    if backend == "tpu":
+        monkeypatch.delenv("BQUERYD_TPU_FORCE_MATMUL", raising=False)
+        m = request.getfixturevalue("groupby_as_accelerator")
+    else:
+        m = _groupby_module()
+        if backend == "cpu":
+            monkeypatch.delenv("BQUERYD_TPU_FORCE_MATMUL", raising=False)
+        else:
+            monkeypatch.setenv("BQUERYD_TPU_FORCE_MATMUL", "1")
+    assert m._SUM_BLOCK == _BLOCK and m._MAX_BLOCK_SEGMENTS == 1 << 25
+    ops_ = tuple(op for _dt, op in aggs)
+    stubs = tuple(np.zeros(1, dt) for dt, _op in aggs)
+    for spelling in (None, "auto"):
+        assert m.kernel_route(spelling, stubs, ops_, n, n_groups) == route
+    if not runs:
+        return
+    rng = np.random.default_rng(32)
+    codes = rng.integers(0, n_groups, n).astype(np.int32)
+    codes[::97] = -1  # null keys drop on every route
+    measures = tuple(
+        (rng.random(n) * 50).astype(dt) if np.issubdtype(dt, np.floating)
+        else rng.integers(-(10**12), 10**12, n).astype(dt)
+        for dt, _op in aggs
+    )
+    mask = rng.random(n) > 0.25
+    with mock.patch.object(
+        m, "_partial_tables_mm", wraps=m._partial_tables_mm
+    ) as mm, mock.patch.object(
+        m, "_partial_tables_scatter", wraps=m._partial_tables_scatter
+    ) as scatter:
+        auto = jax.device_get(
+            m.partial_tables(codes, measures, ops_, n_groups, mask))
+    assert (mm.called, scatter.called) == (
+        route == "matmul", route == "scatter")
+    if route == "matmul":
+        own = m._partial_tables_mm(
+            codes, measures, ops_, n_groups, mask, use_pallas=False,
+            null_sentinels=(None,) * len(measures))
+    else:
+        own = m.partial_tables(
+            codes, measures, ops_, n_groups, mask, strategy=route)
+    own = jax.device_get(own)
+    assert jax.tree_util.tree_structure(auto) == \
+        jax.tree_util.tree_structure(own)
+    for got, want in zip(jax.tree_util.tree_leaves(auto),
+                         jax.tree_util.tree_leaves(own)):
+        np.testing.assert_array_equal(got, want)
+    present = codes >= 0
+    np.testing.assert_array_equal(
+        auto["rows"],
+        np.bincount(codes[present & mask], minlength=n_groups),
+    )

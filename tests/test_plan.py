@@ -23,7 +23,6 @@ from bqueryd_tpu.plan import (
     plan_groupby,
     stats_can_match,
 )
-from bqueryd_tpu.plan.strategy import choose_strategy, select_for_group
 
 
 # -- logical plans -----------------------------------------------------------
@@ -86,13 +85,13 @@ def test_fragment_roundtrip_to_query():
         ["a.bcolzs", "b.bcolzs"], ["k"],
         [["v", "mean", "m"]], [["x", "<=", 9]],
     )
-    frag = fragment_for(plan, ["a.bcolzs"], strategy="scatter", sole=True)
+    frag = fragment_for(plan, ["a.bcolzs"], sole=True)
     query = fragment_to_query(frag)
     assert query.groupby_cols == ["k"]
     assert query.agg_list == [["v", "mean", "m"]]
     assert query.where_terms == [("x", "<=", 9)]
     assert query.sole_payload is True
-    assert frag["strategy"] == "scatter"
+    assert "strategy" not in frag  # a fragment names the work, not the kernel
     # fragments survive the message binary-field transport
     msg = CalcMessage({"payload": "groupby"})
     msg.add_as_binary("plan", frag)
@@ -113,7 +112,7 @@ def test_identical_plans_share_a_signature():
 STATS = {
     "rows": 1000,
     "cols": {
-        "x": {"kind": "numeric", "min": 10, "max": 20, "card": 11},
+        "x": {"kind": "numeric", "min": 10, "max": 20},
         "d": {"kind": "dict"},
     },
 }
@@ -148,115 +147,74 @@ def test_stats_can_match_conjunction():
 
 def test_garbage_stats_never_prune_and_never_raise():
     """A version-skewed worker can advertise any shape; every consumer must
-    degrade (conservative match / auto strategy), never raise mid-launch."""
+    degrade (conservative match), never raise mid-launch."""
     assert stats_can_match(5, [("x", ">", 1)]) is True
     assert stats_can_match({"cols": 3}, [("x", ">", 1)]) is True
     bad_bounds = {"cols": {"x": {"kind": "numeric", "min": "a", "max": "b"}}}
     assert stats_can_match(bad_bounds, [("x", ">", 1)]) is True
-    garbage = {
-        "x.b": {"rows": "many", "cols": {"k": {"kind": "numeric",
-                                               "card": "lots"}}},
-    }
-    assert select_for_group(garbage, ["x.b"], ["k"])[0] == "auto"
-    assert select_for_group({"x.b": 7}, ["x.b"], ["k"])[0] == "auto"
 
 
-# -- strategy selection ------------------------------------------------------
-
-def shard_stats(rows, cards, lo=0, hi=100):
+def shard_stats(rows, cols, lo=0, hi=100):
     return {
         "rows": rows,
-        "cols": {
-            c: {"kind": "numeric", "min": lo, "max": hi, "card": k}
-            for c, k in cards.items()
-        },
+        "cols": {c: {"kind": "numeric", "min": lo, "max": hi} for c in cols},
     }
 
 
-def test_choose_strategy_low_cardinality_is_matmul():
-    assert choose_strategy(10_000_000, 9) == "matmul"
+# -- forced routes -----------------------------------------------------------
+
+def _route_shape(shape):
+    """(codes, measures, ops, n_groups, mask) of one query shape."""
+    rng = np.random.RandomState(7)
+    n = 5000
+    vals = rng.randint(-(10**12), 10**12, n).astype(np.int64)
+    fvals = rng.random(n).astype(np.float64)
+    if shape == "two_keys":
+        # the composite code of two key columns, radix-packed as the engine
+        # packs them
+        n_groups = 37 * 11
+        codes = (rng.randint(0, 37, n) * 11 + rng.randint(0, 11, n))
+    else:
+        n_groups = 37
+        codes = rng.randint(0, 37, n)
+    codes = codes.astype(np.int32)
+    mask = rng.random(n) > 0.3 if shape == "filtered" else None
+    if shape == "float64_mean":
+        return codes, (fvals,), ("mean",), n_groups, mask
+    return codes, (vals, fvals), ("sum", "mean"), n_groups, mask
 
 
-def test_choose_strategy_high_cardinality_is_scatter():
-    assert choose_strategy(10_000_000, 70_000) == "scatter"
+@pytest.mark.parametrize("strategy", ["scatter", "sort", "matmul"])
+@pytest.mark.parametrize(
+    "shape", ["single_key", "two_keys", "float64_mean", "filtered"])
+def test_strategy_hints_are_bit_exact(monkeypatch, shape, strategy):
+    """``auto`` computes what every forced route computes: rows, counts and
+    integer sums bit for bit, float64 sums to reassociation."""
+    import jax
 
-
-def test_choose_strategy_extreme_cardinality_is_sort():
-    assert choose_strategy(10_000_000, 1_000_000) == "sort"
-
-
-def test_choose_strategy_unknown_is_auto():
-    assert choose_strategy(10_000_000, None) == "auto"
-    assert choose_strategy(None, 9) == "auto"
-
-
-def test_select_for_group_overlapping_ranges_use_max_card():
-    # iid shards: same key domain -> global card ~ max per-shard card
-    stats = {
-        f"s{i}.bcolzs": shard_stats(1_000_000, {"a": 265, "b": 265})
-        for i in range(10)
-    }
-    strat, est, rows = select_for_group(
-        stats, list(stats), ["a", "b"]
-    )
-    assert rows == 10_000_000
-    assert est == 265 * 265
-    assert strat == "scatter"
-
-
-def test_select_for_group_disjoint_ranges_sum_cards():
-    # range-partitioned shards: per-shard domains are disjoint -> cards sum
-    stats = {
-        f"s{i}.bcolzs": shard_stats(
-            100_000, {"a": 5000}, lo=i * 10_000, hi=i * 10_000 + 9_999
-        )
-        for i in range(4)
-    }
-    strat, est, _rows = select_for_group(stats, list(stats), ["a"])
-    assert est == 20_000
-    assert strat == "scatter"
-
-
-def test_select_for_group_missing_stats_is_auto():
-    stats = {"a.bcolzs": shard_stats(100, {"k": 5})}
-    strat, est, rows = select_for_group(
-        stats, ["a.bcolzs", "b.bcolzs"], ["k"]
-    )
-    assert (strat, est, rows) == ("auto", None, None)
-
-
-def test_strategy_hints_are_bit_exact():
-    """Every forced route computes the identical partial tables."""
     from bqueryd_tpu import ops
 
-    rng = np.random.RandomState(7)
-    codes = rng.randint(0, 37, 5000).astype(np.int32)
-    vals = rng.randint(-(10**12), 10**12, 5000).astype(np.int64)
-    fvals = rng.random(5000).astype(np.float64)
-    mask = rng.random(5000) > 0.3
+    monkeypatch.setenv("BQUERYD_TPU_FORCE_MATMUL", "1")
+    codes, measures, agg_ops, n_groups, mask = _route_shape(shape)
 
-    def run(strategy):
-        import jax
-
-        out = jax.device_get(
+    def run(forced):
+        return jax.device_get(
             ops.partial_tables(
-                codes, (vals, fvals), ("sum", "mean"), 37, mask,
-                strategy=strategy,
+                codes, measures, agg_ops, n_groups, mask, strategy=forced,
             )
         )
-        return out
 
-    base = run(None)
-    for strategy in ("scatter", "sort", "matmul", "auto"):
-        got = run(strategy)
-        assert np.array_equal(base["rows"], got["rows"])
-        assert np.array_equal(base["aggs"][0]["sum"], got["aggs"][0]["sum"])
-        np.testing.assert_allclose(
-            base["aggs"][1]["sum"], got["aggs"][1]["sum"], rtol=1e-12
-        )
-
+    base, got = run(None), run(strategy)
+    assert jax.tree_util.tree_structure(base) == \
+        jax.tree_util.tree_structure(got)
+    for want, have in zip(jax.tree_util.tree_leaves(base),
+                          jax.tree_util.tree_leaves(got)):
+        if np.issubdtype(np.asarray(want).dtype, np.floating):
+            np.testing.assert_allclose(have, want, rtol=1e-12)
+        else:
+            np.testing.assert_array_equal(have, want)
     with pytest.raises(ValueError):
-        run("warp-drive")
+        run("matmul!")  # no longer a route (PR 32); unknown names raise
 
 
 # -- admission controller ----------------------------------------------------
@@ -406,8 +364,8 @@ def decode_reply(payload):
 
 def test_plan_time_pruning_skips_excluded_shards(controller):
     stats = {
-        "a.bcolzs": shard_stats(100, {"k": 3}, lo=0, hi=50),
-        "b.bcolzs": shard_stats(100, {"k": 3}, lo=1000, hi=2000),
+        "a.bcolzs": shard_stats(100, ["k"], lo=0, hi=50),
+        "b.bcolzs": shard_stats(100, ["k"], lo=1000, hi=2000),
     }
     register(
         controller, "w1", ["a.bcolzs", "b.bcolzs"], stats=stats
@@ -438,7 +396,7 @@ def test_plan_time_pruning_skips_excluded_shards(controller):
 
 
 def test_all_shards_pruned_replies_immediately(controller):
-    stats = {"a.bcolzs": shard_stats(100, {"k": 3}, lo=0, hi=50)}
+    stats = {"a.bcolzs": shard_stats(100, ["k"], lo=0, hi=50)}
     register(controller, "w1", ["a.bcolzs"], stats=stats)
     controller.rpc_groupby(
         groupby_msg(["a.bcolzs"], where=[["k", ">", 99]], token="aa")
@@ -453,27 +411,52 @@ def test_all_shards_pruned_replies_immediately(controller):
 
 def test_planner_disabled_restores_static_fanout(controller, monkeypatch):
     monkeypatch.setenv("BQUERYD_TPU_PLANNER", "0")
-    stats = {"a.bcolzs": shard_stats(100, {"k": 3}, lo=0, hi=50)}
+    stats = {"a.bcolzs": shard_stats(100, ["k"], lo=0, hi=50)}
     register(controller, "w1", ["a.bcolzs"], stats=stats)
     controller.rpc_groupby(
         groupby_msg(["a.bcolzs"], where=[["k", ">", 99]])
     )
     (msg,) = queued(controller)  # no pruning: dispatched anyway
-    frag = msg.get_from_binary("plan")
-    assert frag["strategy"] is None
+    assert msg.get_from_binary("plan")["agg_list"] == [["v", "sum", "v"]]
 
 
-def test_strategy_hint_rides_the_fragment(controller):
-    stats = {
-        "a.bcolzs": shard_stats(10_000_000, {"k": 9}),
-    }
+_ROUTE_KEYS = {"strategy", "strategy_binding"}
+
+
+@pytest.mark.parametrize("path", ["classic", "bundle", "dag"])
+def test_dispatch_carries_no_route(controller, monkeypatch, path):
+    """Whatever the advertised stats say — here the shape the planner used
+    to hint ``matmul`` for — a dispatch names the work and never the kernel:
+    no route key on the message nor in its fragment, no hint counter, and
+    the segment counts its shards under the one route a dispatch asks for."""
+    stats = {"a.bcolzs": shard_stats(10_000_000, ["k"])}
     register(controller, "w1", ["a.bcolzs"], stats=stats)
-    controller.rpc_groupby(groupby_msg(["a.bcolzs"]))
-    (msg,) = queued(controller)
-    frag = msg.get_from_binary("plan")
-    assert frag["strategy"] == "matmul"
-    assert controller.counters["plan_strategy_hints"] == 1
-    assert frag["agg_list"] == [["v", "sum", "v"]]
+    if path == "bundle":
+        monkeypatch.setenv("BQUERYD_TPU_BATCH_WINDOW_MS", "60000")
+        controller.rpc_groupby(groupby_msg(["a.bcolzs"], token="aa"))
+        controller.rpc_groupby(
+            groupby_msg(["a.bcolzs"], where=[["k", ">", 1]], token="bb")
+        )
+        controller._flush_window(force=True)
+        assert controller.counters["plan_bundles"] == 1
+    elif path == "dag":
+        msg = RPCMessage({"payload": "query", "token": "00"})
+        msg.set_args_kwargs(
+            [{"table": "a.bcolzs", "groupby": ["k"],
+              "aggs": [["v", "topk", "top", {"k": 2}]]}], {}
+        )
+        controller.rpc_query(msg)
+    else:
+        controller.rpc_groupby(groupby_msg(["a.bcolzs"]))
+    (shard,) = queued(controller)
+    assert bool(shard.get("dag")) == (path == "dag")
+    fragment = shard.get_from_binary("bundle" if path == "bundle" else "plan")
+    assert not _ROUTE_KEYS & set(shard)
+    assert not _ROUTE_KEYS & set(fragment)
+    assert fragment["groupby_cols"] == ["k"]
+    assert not [c for c in controller.counters if "hint" in c or "calib" in c]
+    for segment in controller.rpc_segments.values():
+        assert segment["strategies"] == {"auto": 1}
 
 
 def test_shared_dispatch_fuses_identical_queries(controller):
@@ -879,3 +862,36 @@ def test_bundle_reply_without_members_aborts_not_misdelivers(
         assert envelope["ok"] is False
         assert "BQUERYD_TPU_BATCH_WINDOW_MS=0" in envelope["error"]
     assert not controller.rpc_segments
+
+
+def test_advertised_stats_hold_rows_and_bounds_only(tmp_path):
+    """What a worker advertises per shard is what pruning reads: ``rows``
+    and per-column kind, chunk identity and min/max — no cardinality, and
+    no probe of a factorize sidecar (writing one changes nothing)."""
+    import pandas as pd
+
+    from bqueryd_tpu.models.query import GroupByQuery, QueryEngine
+    from bqueryd_tpu.plan.stats import StatsCollector, gather_table_stats
+    from bqueryd_tpu.storage.ctable import ctable
+
+    df = pd.DataFrame({
+        "k": np.arange(600, dtype=np.int64) % 7,
+        "v": np.arange(600, dtype=np.int64) - 5,
+        "s": np.array(["a", "b", "c"])[np.arange(600) % 3],
+    })
+    root = str(tmp_path / "t.bcolzs")
+    ctable.fromdataframe(df, root)
+    table = ctable(root, mode="r")
+    stats = gather_table_stats(table)
+    assert stats["rows"] == 600 and set(stats["cols"]) == {"k", "v", "s"}
+    for name, entry in stats["cols"].items():
+        assert set(entry) <= {"kind", "chunks", "sig", "min", "max"}, name
+    assert (stats["cols"]["v"]["min"], stats["cols"]["v"]["max"]) == (-5, 594)
+    collector = StatsCollector(min_refresh_s=0.0)
+    first = collector.collect(str(tmp_path), ["t.bcolzs"])
+    assert first == {"t.bcolzs": stats}
+    # a query factorizes the key and may store its sidecar: the advertised
+    # stats are the same object, so the WRM does not re-advertise them
+    QueryEngine().execute_local(
+        table, GroupByQuery(["k", "s"], [["v", "sum", "v"]]))
+    assert collector.collect(str(tmp_path), ["t.bcolzs"]) is first

@@ -437,3 +437,15 @@ def test_merge_tolerates_payload_without_value_kinds(tmp_path):
     assert "uint64" in p_new["value_kinds"]
     with _pytest.raises(ValueError, match="disagree"):
         hostmerge.merge_payloads([p_old, p_new])
+
+
+def test_engine_reports_route(table):
+    _df, ct = table
+    engine = QueryEngine()
+    query = GroupByQuery(["passenger_count"], [["payment_type", "sum", "s"]])
+    engine.execute_local(ct, query)
+    assert engine.last_effective_strategy == "matmul"
+    engine.execute_local(ct, query, strategy="host")
+    assert engine.last_effective_strategy == "host"
+    engine.execute_local(ct, query, strategy="scatter")
+    assert engine.last_effective_strategy == "scatter"

@@ -183,6 +183,23 @@ def test_groupby_sharded_matches_full(cluster):
     pd.testing.assert_frame_equal(full, parts, check_dtype=False, check_column_type=False)
 
 
+def test_effective_strategy_reaches_the_client_envelope(cluster):
+    """A dispatch carries no route and the reply reports the one the
+    kernel rule took: ``hints`` counts the shards sent ({"auto": n}),
+    ``effective`` names the route per shard group (FORCE_MATMUL=1 here).
+    A fresh filter constant keeps the result cache out of it."""
+    rpc = cluster["rpc"]
+    shard_names = [f"taxi-{i}.bcolzs" for i in range(NR_SHARDS)]
+    rpc.groupby(
+        shard_names, ["payment_type"],
+        [["passenger_count", "sum", "passenger_count"]],
+        [["trip_distance", ">", 0.123456]],
+    )
+    strategies = rpc.last_call_strategies
+    assert strategies["hints"] == {"auto": NR_SHARDS}
+    assert set(strategies["effective"].values()) == {"matmul"}
+
+
 def test_groupby_with_filter(cluster, taxi_df):
     got = cluster["rpc"].groupby(
         ["taxi.bcolz"],

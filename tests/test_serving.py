@@ -3,7 +3,7 @@
 Three layers of coverage:
 
 * lattice — eligibility refusals, exact / key-fold / window-fold / zone-proof
-  matching, transform application parity vs pandas, the calibrated source
+  matching, transform application parity vs pandas, the costed source
   choice (tiny tables refuse on cost);
 * manager — heat threshold decay, build/absorb lifecycle, append-epoch
   staleness (including an append racing a build), delta refresh, retention

@@ -283,6 +283,83 @@ def test_result_cache_disabled_by_env(tmp_path, mem_store_url, monkeypatch):
         worker.socket.close()
 
 
+@pytest.mark.parametrize(
+    "older_keys",
+    [
+        pytest.param({"strategy": "scatter"}, id="strategy=scatter"),
+        pytest.param({"strategy": "sort"}, id="strategy=sort"),
+        pytest.param({"strategy": "matmul", "strategy_binding": True},
+                     id="strategy=matmul+binding"),
+        pytest.param({"strategy": "host"}, id="strategy=host"),
+        pytest.param(None, id="wrm-calibration"),
+    ],
+)
+def test_older_peers_keys_are_ignored(
+    tmp_path, mem_store_url, monkeypatch, older_keys
+):
+    """Mixed versions (MIGRATION.md, PR 32).  A fragment from an older
+    controller may still carry a route: the worker ignores it, runs what it
+    runs for every query, reports that route and echoes no hint.  A WRM from
+    an older worker may still carry its measured-cost cells: the controller
+    registers the worker, keeps no model of them and dispatches as ever."""
+    from bqueryd_tpu.plan import fragment_for, plan_groupby
+
+    monkeypatch.setenv("BQUERYD_TPU_RESULT_CACHE_BYTES", "0")
+    rng = np.random.RandomState(3)
+    df = pd.DataFrame({
+        "g": rng.randint(0, 5, 3000).astype(np.int64),
+        "v": rng.randint(-9, 9, 3000).astype(np.int64),
+    })
+    ctable.fromdataframe(df, str(tmp_path / "t.bcolzs"))
+    plan = plan_groupby(["t.bcolzs"], ["g"], [["v", "sum", "v"]], [])
+    fragment = fragment_for(plan, ["t.bcolzs"], sole=True)
+
+    if older_keys is None:
+        from bqueryd_tpu.controller import ControllerNode
+        from bqueryd_tpu.messages import RPCMessage, WorkerRegisterMessage
+
+        node = ControllerNode(
+            coordination_url=mem_store_url, loglevel=logging.WARNING,
+            runfile_dir=str(tmp_path),
+        )
+        try:
+            node.handle_worker(b"w-old", WorkerRegisterMessage({
+                "worker_id": "w-old", "workertype": "calc",
+                "data_files": ["t.bcolzs"],
+                "calibration": {"v": 1, "cells": {
+                    "r24|g4|int|tpu|sort": {"n": 9, "wall_s": 1e-9}}},
+            }))
+            assert "t.bcolzs" in node.files_map
+            assert not hasattr(node, "calibration")
+            msg = RPCMessage({"payload": "groupby", "token": "00"})
+            msg.set_args_kwargs(
+                [["t.bcolzs"], ["g"], [["v", "sum", "v"]], []], {})
+            node.rpc_groupby(msg)
+            (shard,) = [
+                m for q in node.worker_out_messages.values() for m in q]
+            assert shard.get_from_binary("plan") == fragment
+        finally:
+            node.socket.close()
+        return
+
+    def calc(frag):
+        msg = _calc_msg(["t.bcolzs"])
+        msg.add_as_binary("plan", frag)
+        return worker.handle_work(msg)
+
+    worker = _worker_for(tmp_path, mem_store_url)
+    try:
+        plain = calc(fragment)
+        older = calc({**fragment, **older_keys})
+        assert older["data"] == plain["data"]
+        assert older["effective_strategy"] == plain["effective_strategy"]
+        assert plain["effective_strategy"] == "matmul"  # FORCE_MATMUL=1
+        assert "strategy" not in older
+        assert "calibration" not in worker.prepare_wrm()
+    finally:
+        worker.socket.close()
+
+
 def test_wire_dtype_narrows_by_stats(shard_tables):
     _, tables = shard_tables
     assert _wire_dtype(tables, "v") == np.dtype(np.int16)
