@@ -16,11 +16,17 @@ Design:
   accumulation is exact; per-block tables are then recombined in uint64
   (mod-2^64 arithmetic == two's complement) — bit-exact for the full int64
   range.  Counts ride along as a row of ones in the same matmul.  min/max
-  and cardinalities above ``matmul_groups_limit()`` use the scatter path:
-  exact 16-bit-limb int32 scatters over 64Ki row blocks (mod-2^32 wrap
-  recovered by a uint32 bitcast), switching to a sort + prefix-diff
-  reduction at extreme cardinality where the blocked table would outgrow
-  ``_MAX_BLOCK_SEGMENTS`` — never the emulated-s64 scatter.  A float64 sum
+  and cardinalities above ``matmul_groups_limit()`` use the scatter path,
+  whose exact integer counts and sums take the form the backend does
+  cheapest — never the emulated-s64 scatter.  On an accelerator, where a
+  scatter retires one update every ~9 ns at any group count, that is ONE
+  sort of the rows by group code with the contributions carried as sort
+  operands, then wrapping 64-bit prefix sums differenced at the group
+  boundaries (:class:`_SortedGroups`).  On a CPU backend it is 16-bit-limb
+  int32 scatters over 64Ki row blocks (mod-2^32 wrap recovered by a uint32
+  bitcast), switching to a sort + prefix-diff reduction at extreme
+  cardinality where the blocked table would outgrow
+  ``_MAX_BLOCK_SEGMENTS``.  A float64 sum
   (and an integer mean, which accumulates in float64 like pandas) rides
   neither: on either route it is :func:`_float64_segment_sum` — on an
   accelerator a dense masked reduction in float64 up to
@@ -164,10 +170,14 @@ def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
     bit-exact for the full range; for float64 accumulation the prefix-diff
     matches direct summation to ~1 ulp of the running prefix.
 
-    Callers left: the int64 sums of :func:`_int64_segment_sum` past the
-    ``blocks x groups`` budget or under a binding ``"sort"`` hint, and the
-    float64 sums of :func:`_float64_segment_sum` above ``_DENSE_SUM_GROUPS``
-    groups on an accelerator or under that hint."""
+    One sort, one gather of every row and one boundary search PER SUM:
+    where a query's integer reductions can share a sort and carry their
+    values through it they take :class:`_SortedGroups` instead.  Callers
+    left: the int64 sums of :func:`_int64_segment_sum` past the
+    ``blocks x groups`` budget (a CPU backend only: an accelerator never
+    reaches the blocked form under ``auto``), and the float64 sums of
+    :func:`_float64_segment_sum` above ``_DENSE_SUM_GROUPS`` groups on an
+    accelerator or under a binding ``"sort"`` hint."""
     codes_s, order = lax.sort(
         (safe, jnp.arange(safe.shape[0], dtype=jnp.int32)), num_keys=1
     )
@@ -180,6 +190,154 @@ def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
     zero = jnp.zeros(1, acc_dtype)
     bounds = jnp.concatenate([zero, prefix])[ends]
     return jnp.diff(jnp.concatenate([zero, bounds]))
+
+
+class _SortedGroups:
+    """The accelerator's form of the scatter route's exact integer counts
+    and sums: ONE sort of the query's rows by folded group code with every
+    integer contribution carried through it as a 32-bit sort operand, the
+    group boundaries found once, then each count the difference of the
+    boundaries (or of an int32 prefix of carried flags) and each sum an
+    exact prefix sum read at the boundaries and differenced in wrapping 64
+    bits — bit-exact mod 2^64 for the full int64 range, like the blocked
+    limb scatter it stands in for.  No scatter, no ``arange`` operand, no
+    gather of rows: the only gathers read ``n_groups`` elements.
+
+    Rows that do not count (null key, filtered out) are keyed past the last
+    group, so they sort off the end where no boundary reads them and no
+    operand needs a mask of its own.  Contributions are registered first
+    (:meth:`count`, :meth:`total`; each returns a thunk) and the one sort
+    runs when the first thunk is called, so every reduction of a query
+    shares it."""
+
+    def __init__(self, valid, codes, n_groups):
+        self._key = jnp.where(valid, codes, n_groups).astype(jnp.int32)
+        self._n_groups = n_groups
+        self._words = []     # 32-bit operands carried through the sort
+        self._sorted = None  # (ends, sorted words), once the sort has run
+
+    def _carry(self, word):
+        if self._sorted is not None:
+            raise RuntimeError("contribution registered after the sort ran")
+        self._words.append(word)
+        return len(self._words) - 1
+
+    def _run(self):
+        if self._sorted is None:
+            key_s, *words_s = lax.sort(
+                (self._key, *self._words), num_keys=1, is_stable=False
+            )
+            # ends[g]: one past the last sorted row of group g
+            ends = jnp.searchsorted(
+                key_s, jnp.arange(self._n_groups, dtype=jnp.int32),
+                side="right",
+            )
+            self._sorted = ends, words_s
+        return self._sorted
+
+    def _prefix_at_ends(self, word):
+        """int64[n_groups]: the exact sum of a sorted 32-bit ``word`` over
+        the rows before each group's end.  Everything at row scale is 32
+        bits wide: the word's 16-bit limbs are scanned inside
+        ``_SUM_BLOCK``-row blocks, where an unsigned limb's prefix stays
+        below 2^32 and the signed top limb's within +-2^31, and only the
+        per-block totals and the values read at the boundaries meet in 64
+        bits (a 64-bit scan of every row is emulated: 13.4 ms at 11 M rows
+        on a v5e against 2.6 for both limbs' scans, and a compile of one
+        crashed the TPU compiler — PERF.md section 6, PR 33)."""
+        ends, _ = self._run()
+        n = word.shape[0]
+        n_blocks = -(-n // _SUM_BLOCK)
+        blocks = jnp.pad(word, (0, n_blocks * _SUM_BLOCK - n)).reshape(
+            n_blocks, _SUM_BLOCK
+        )
+        # the top limb keeps the word's sign (arithmetic shift if signed)
+        limbs = (((blocks & 0xFFFF).astype(jnp.uint32), 0), (blocks >> 16, 16))
+        last = jnp.maximum(ends - 1, 0)  # a group's last row, if it has one
+        total = jnp.zeros(self._n_groups, jnp.int64)
+        for limb, shift in limbs:
+            scan = jnp.cumsum(limb, axis=1)
+            whole = lax.index_in_dim(  # [n_blocks] totals
+                scan, _SUM_BLOCK - 1, axis=1, keepdims=False
+            ).astype(jnp.int64)
+            # (log-step adds over the few blocks: no 64-bit reduce-window)
+            before = lax.associative_scan(jnp.add, whole) - whole
+            at = before[last // _SUM_BLOCK] + scan.reshape(-1)[last].astype(
+                jnp.int64
+            )
+            total = total + (at << shift)
+        return jnp.where(ends > 0, total, 0)
+
+    def rows(self):
+        """Thunk of the valid rows per group: no prefix sum at all."""
+        def resolve():
+            ends, _ = self._run()
+            return jnp.diff(ends, prepend=0).astype(jnp.int64)
+        return resolve
+
+    def count(self, flags):
+        """Thunk of the per-group count of valid rows whose flag is set."""
+        slot = self._carry(flags.astype(jnp.int32))
+
+        def resolve():
+            ends, words = self._run()
+            # n < 2^31 rows a dispatch: a flat int32 prefix cannot wrap
+            prefix = jnp.cumsum(words[slot])
+            at = jnp.where(ends > 0, prefix[jnp.maximum(ends - 1, 0)], 0)
+            return jnp.diff(at, prepend=0).astype(jnp.int64)
+        return resolve
+
+    def total(self, values):
+        """Thunk of the per-group int64 sum (mod 2^64) of integer values."""
+        if values.dtype == jnp.bool_:
+            values = values.astype(jnp.uint8)
+        if values.dtype.itemsize < 4:
+            slots = (self._carry(values.astype(jnp.int32)),)
+        elif values.dtype.itemsize == 4:
+            slots = (self._carry(values),)
+        else:
+            # a 64-bit value rides as its two 32-bit halves; the high half
+            # keeps the sign (arithmetic shift; logical for unsigned)
+            half = (
+                jnp.int32
+                if jnp.issubdtype(values.dtype, jnp.signedinteger)
+                else jnp.uint32
+            )
+            slots = (
+                self._carry(values.astype(jnp.uint32)),
+                self._carry((values >> 32).astype(half)),
+            )
+
+        def resolve():
+            _, words = self._run()
+            at = self._prefix_at_ends(words[slots[0]])
+            if len(slots) == 2:
+                at = at + (self._prefix_at_ends(words[slots[1]]) << 32)
+            return jnp.diff(at, prepend=0)
+        return resolve
+
+
+def _int_sums_sort(n, n_groups):
+    """Whether the scatter route's integer counts and sums take a sorted
+    form under ``auto``, from what the trace observes (the backend, the
+    rows, the group count): an accelerator always sorts
+    (:class:`_SortedGroups`); a CPU backend, whose scatter is cheap, keeps
+    the blocked limb scatter up to ``_MAX_BLOCK_SEGMENTS`` buckets and
+    past them :func:`_sorted_segment_sum`, as it always has.
+
+    OBSERVED, nothing to tune: standalone on a TPU v5e (PERF.md section 6,
+    PR 33) a blocked scatter costs 10.9 ns a row a limb at any group count
+    (119.7 ms for ``rows`` alone at 11 010 048 rows, 357.5 with an
+    int32-wide sum, 595.8 with an int64-wide one) where the sorted form
+    costs 22.1, 36.9 and 53.2 ms — about 20 ms for the sort and the scans
+    plus 0.235 ms per 1 000 groups for the boundary search and the reads
+    (21.9 ms at 6 656 groups, 81.1 at 262 144, against 220.4 and 374.4).
+    It still wins at 1 048 576 rows x 73 728 groups, 14 rows a group
+    (14.8 against 22.2 ms); below that the two are a few ms apart and no
+    reading says which is ahead."""
+    if jax.default_backend() != "cpu":
+        return True
+    return -(-n // _SUM_BLOCK) * n_groups > _MAX_BLOCK_SEGMENTS
 
 
 def _dense_segment_sum(contrib, safe, n_groups):
@@ -234,27 +392,25 @@ def _float64_segment_sum(contrib, safe, n_groups, force_sort=False):
     return jax.ops.segment_sum(contrib, safe, num_segments=n_groups)
 
 
-def _int64_segment_sum(values, valid, safe, n_groups, force_sort=False):
+def _int64_segment_sum(values, valid, safe, n_groups):
     """Exact per-group int64 sums of integer ``values`` without any int64
-    scatter.
+    scatter: the blocked form, which every CPU backend takes under ``auto``
+    and any backend under a binding ``"scatter"`` hint (an accelerator's
+    ``auto`` sorts instead: :class:`_SortedGroups`).
 
-    TPUs emulate s64 (`jax's x64 mode <https://docs.jax.dev>`_) and the
-    emulated scatter-add behind ``segment_sum`` dominates the whole query
-    (~5x the cost of the s32 scatter at 10 M rows, measured on v5e).  Instead:
-    split values into 16-bit limbs (elementwise s64 ops are cheap — only the
-    scatter is not), scatter each limb in int32 over ``blocks x groups``
-    buckets, recover each bucket exactly (mod-2^32 wrap is invertible because
-    a block's true limb sum is < 2^32), then reduce the per-block tables in
-    uint64 and recombine limbs with shifts.  Bit-exact for the full int64
-    range.  Past ``_MAX_BLOCK_SEGMENTS`` buckets (~extreme group counts) the
-    sort-based path takes over instead of the emulated-s64 scatter that used
-    to cost ~3 s at 10 M rows."""
+    Split values into 16-bit limbs, scatter each limb in int32 over
+    ``blocks x groups`` buckets, recover each bucket exactly (mod-2^32 wrap
+    is invertible because a block's true limb sum is < 2^32), then reduce
+    the per-block tables in uint64 and recombine limbs with shifts.
+    Bit-exact for the full int64 range.  Past ``_MAX_BLOCK_SEGMENTS``
+    buckets (~extreme group counts) the sort-based path takes over instead
+    of the emulated-s64 scatter that used to cost ~3 s at 10 M rows."""
     n = values.shape[0]
     v = jnp.where(valid, values, 0)
     nbits = values.dtype.itemsize * 8
     signed_in = jnp.issubdtype(values.dtype, jnp.signedinteger)
     n_blocks = -(-n // _SUM_BLOCK)
-    if force_sort or n_blocks * n_groups > _MAX_BLOCK_SEGMENTS:
+    if n_blocks * n_groups > _MAX_BLOCK_SEGMENTS:
         return _sorted_segment_sum(v, safe, n_groups)
     # limbs: (int32 row, shift, signed). Non-top limbs are unsigned 16-bit
     # slices; the top limb carries the sign for signed inputs.
@@ -376,10 +532,12 @@ def _hicard_matmul_profitable(measures, ops, n, n_groups):
     ``matmul_groups_limit``.  Opt-in (BQUERYD_TPU_PALLAS=1) until proven on
     hardware; INT sums/counts only — the kernel's in-kernel mod-2^32 limb
     accumulation has no wrap-free encoding for float Dekker limbs, and
-    min/max ride dedicated scatter kernels regardless.  The pre-fix
-    hardware sample for the 70k-group blocked scatter was 0.583 s at 10M
-    rows; the one-hot contraction is ~1.4e12 bf16 MACs there, tens of ms
-    at realistic MXU utilization."""
+    min/max ride dedicated scatter kernels regardless.  What it has to
+    beat at 11 M rows x 73 728 groups on a v5e is the sorted form's 37 ms
+    (the blocked scatter took 357 standalone; PERF.md section 6, PR 33);
+    its one standalone reading there was 0.456 s (CHANGES.md, PR 21),
+    though the one-hot contraction is ~1.4e12 bf16 MACs, tens of ms at
+    realistic MXU utilization."""
     from bqueryd_tpu.ops import pallas_groupby
 
     if not pallas_groupby.pallas_enabled():
@@ -444,7 +602,8 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
 
     ``strategy`` (:data:`KERNEL_STRATEGIES`) forces one route for a test:
     ``"scatter"`` goes straight to the blocked scatters, ``"sort"`` to the
-    scatter entry with the sort+prefix-diff reduction forced, and
+    scatter entry with the sorted reductions forced (the form an
+    accelerator's ``auto`` takes there: :func:`_int_sums_sort`), and
     ``"matmul"``/``"auto"``/None keep the full profitability logic, the ONE
     rule that routes every served query (:func:`kernel_route` is its
     host-side twin) — ``"matmul"`` never overrides the backend guard (a CPU
@@ -467,7 +626,7 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
     if strategy == "scatter":
         return _partial_tables_scatter(
             codes, measures, ops, int(n_groups), mask,
-            null_sentinels=null_sentinels,
+            null_sentinels=null_sentinels, force_sort=False,
         )
     if strategy == "sort":
         return _partial_tables_scatter(
@@ -736,10 +895,7 @@ def kernel_route(strategy, measures, ops, n, n_groups):
         return "matmul"
     if _hicard_matmul_profitable(measures, tuple(ops), n, n_groups):
         return "matmul"
-    blocks = -(-n // _SUM_BLOCK)
-    if blocks * n_groups > _MAX_BLOCK_SEGMENTS:
-        return "sort"
-    return "scatter"
+    return "sort" if _int_sums_sort(n, n_groups) else "scatter"
 
 
 def float_sum_route(strategy, measures, ops, n, n_groups):
@@ -1059,11 +1215,16 @@ _partial_tables_mm = _obsprofile.instrument(
     static_argnames=("n_groups", "ops", "null_sentinels", "force_sort"),
 )
 def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
-                            null_sentinels=None, force_sort=False):
-    """Scatter path: blocked-int32 segment sums (exact, no s64 scatter).
-    ``force_sort`` (the forced "sort" strategy) makes every sum take the
-    sort+prefix-diff reduction regardless of the blocks x groups budget —
-    identical partial semantics, group-count-independent cost."""
+                            null_sentinels=None, force_sort=None):
+    """Scatter path: exact integer counts and sums with no s64 scatter, in
+    the form the backend does cheapest — one carried-payload sort and
+    prefix differences (:class:`_SortedGroups`) on an accelerator, blocked
+    int32 limb scatters (:func:`_int64_segment_sum`) on a CPU backend;
+    identical partials either way.  ``force_sort`` is None for every served
+    query (the form follows the backend, read at trace time), True under
+    the binding "sort" hint (the sorted form on any backend, float64 sums
+    sorted too) and False under the binding "scatter" hint (the blocked
+    scatters on any backend)."""
     valid = codes >= 0
     if mask is not None:
         valid = valid & mask
@@ -1073,13 +1234,31 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
         jax.ops.segment_sum, segment_ids=safe, num_segments=n_groups
     )
 
-    def int_count(flags):  # bool[n] -> int64[n_groups], no s64 scatter
-        return _int64_segment_sum(
-            flags.astype(jnp.int8), flags, safe, n_groups,
-            force_sort=force_sort,
-        )
+    # (the accelerator reading is the first line of _int_sums_sort; its
+    # second, a CPU backend past the budget, is _int64_segment_sum's own)
+    sort_ints = (
+        jax.default_backend() != "cpu" if force_sort is None else force_sort
+    )
+    sort_floats = force_sort is True
+    # the integer reductions below are values in the blocked form and
+    # thunks in the sorted one, where the one sort can only run once every
+    # contribution is known: the tables are resolved at the end
+    if sort_ints:
+        by_sort = _SortedGroups(valid, codes, n_groups)
+        rows = by_sort.rows()
+        int_sum = by_sort.total
+        # a flag's rows need not be valid: an invalid row sorts off the end
+        int_count = by_sort.count
+    else:
+        def int_count(flags):  # bool[n] -> int64[n_groups], no s64 scatter
+            return _int64_segment_sum(
+                flags.astype(jnp.int8), flags, safe, n_groups
+            )
 
-    rows = int_count(valid)
+        def int_sum(values):
+            return _int64_segment_sum(values, valid, safe, n_groups)
+
+        rows = int_count(valid)
 
     sentinels = _normalize_sentinels(null_sentinels, len(measures))
     aggs = []
@@ -1118,18 +1297,14 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
                     # data flow
                     partial = {
                         "sum": _float64_segment_sum(
-                            contrib, safe, n_groups, force_sort=force_sort
+                            contrib, safe, n_groups, force_sort=sort_floats
                         )
                     }
                 else:
                     partial = {"sum": seg_sum(contrib)}
             else:
-                partial = {
-                    "sum": _int64_segment_sum(
-                        values, present, safe, n_groups,
-                        force_sort=force_sort,
-                    )
-                }
+                # (no null without a sentinel, and a sentinel never sums)
+                partial = {"sum": int_sum(values)}
             if op == "mean":
                 partial["count"] = present_count()
             aggs.append(partial)
@@ -1149,7 +1324,10 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
                     "count": present_count(),
                 }
             )
-    return {"rows": rows, "aggs": tuple(aggs)}
+    return jax.tree_util.tree_map(
+        lambda leaf: leaf() if callable(leaf) else leaf,
+        {"rows": rows, "aggs": tuple(aggs)},
+    )
 
 
 _partial_tables_scatter = _obsprofile.instrument(

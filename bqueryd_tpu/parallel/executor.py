@@ -2564,9 +2564,11 @@ def _effective_mesh_strategy(strategy, agg_ops, n_groups, measures_d, width):
     """Canonicalize a forced route for the mesh-program cache key: one that
     cannot change the traced route must key (and trace) exactly like
     ``auto``, or an identical program would be compiled twice — "matmul" is
-    advisory by definition (the dispatcher decides identically under auto)
-    and "scatter" is a no-op whenever auto would scatter anyway (always on
-    CPU backends, and past the matmul group ceiling)."""
+    advisory by definition (the dispatcher decides identically under auto),
+    and where auto takes the scatter entry "scatter" or "sort" is a no-op
+    when it names the form that entry's integers take there anyway (the
+    blocked scatter on a CPU backend up to the blocks x groups budget; the
+    sort past it and on an accelerator)."""
     if strategy in (None, "auto", "matmul"):
         return None
     from bqueryd_tpu.ops import groupby as gb
@@ -2576,13 +2578,10 @@ def _effective_mesh_strategy(strategy, agg_ops, n_groups, measures_d, width):
     ) or gb._hicard_matmul_profitable(
         measures_d, agg_ops, width, int(n_groups)
     )
-    if strategy == "scatter" and not mm:
+    if not mm and (strategy == "sort") == gb._int_sums_sort(
+        width, int(n_groups)
+    ):
         return None
-    if strategy == "sort" and not mm:
-        # auto's scatter entry already sorts past the blocks x groups budget
-        blocks = -(-width // gb._SUM_BLOCK)
-        if blocks * int(n_groups) > gb._MAX_BLOCK_SEGMENTS:
-            return None
     return strategy
 
 

@@ -378,6 +378,54 @@ def test_packed_fetch_spec_stable_across_kernel_routes(tmp_path, monkeypatch):
     )
 
 
+def test_sorted_form_over_four_devices_matches_host_and_the_default_spec(
+        sharded, monkeypatch):
+    """A two-key integer sum whose per-device partials come from the one
+    carried-payload sort (a binding ``sort`` hint on this backend): the
+    merged answer equals the host reference bit for bit, and what the packed
+    fetch carries — leaves, dtypes, shapes — is what the default route's
+    program carries, so the device merge and the fetch see no difference."""
+    from bqueryd_tpu.parallel import executor as executor_mod
+
+    monkeypatch.setenv("BQUERYD_TPU_PACKED_FETCH", "1")
+    df, tables = sharded
+    specs = {}
+    build = executor_mod._mesh_program
+
+    def spy(*args, **kwargs):
+        program, spec = build(*args, **kwargs)
+        specs[kwargs.get("strategy")] = spec
+        return program, spec
+
+    monkeypatch.setattr(executor_mod, "_mesh_program", spy)
+    query = GroupByQuery(
+        ["PULocationID", "DOLocationID"],
+        [["passenger_count", "sum", "riders"], ["payment_type", "sum", "pay"],
+         ["VendorID", "count", "n"]],
+    )
+    executor = MeshQueryExecutor(mesh=make_mesh(4))
+    frames = {}
+    for strategy in (None, "sort"):
+        payload = executor.execute(tables, query, strategy=strategy)
+        assert executor.last_effective_strategy == (strategy or "scatter")
+        frames[strategy] = hostmerge.payload_to_dataframe(
+            hostmerge.merge_payloads(
+                [ResultPayload.from_bytes(payload.to_bytes())]))
+    assert specs[None]["leaves"] and specs["sort"] == specs[None]
+    keys = ["PULocationID", "DOLocationID"]
+    expected = df.groupby(keys, as_index=False).agg(
+        riders=("passenger_count", "sum"), pay=("payment_type", "sum"),
+        n=("VendorID", "count"),
+    )
+    assert len(expected) > 8192   # past the MXU route's ceiling
+    for frame in frames.values():
+        got = frame.sort_values(keys).reset_index(drop=True)
+        for col in ("riders", "pay", "n"):
+            assert got[col].dtype == np.int64
+            np.testing.assert_array_equal(
+                got[col].to_numpy(), expected[col].to_numpy())
+
+
 def test_cold_path_hits_disk_sidecars_and_matches(sharded, mesh):
     """Warm query -> clear every process cache (the bench's cold reset) ->
     re-query: the alignment must come back from the on-disk factorize /

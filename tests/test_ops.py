@@ -130,14 +130,27 @@ def _groupby_module():
     return sys.modules["bqueryd_tpu.ops.groupby"]
 
 
-def test_highcard_bench_shape_stays_on_blocked_path():
-    """Pin the chosen kernel route for BASELINE config 5 (10 M rows x 70,225
-    groups): with the 64 Ki scatter blocks the bucket count stays inside
-    ``_MAX_BLOCK_SEGMENTS``, so the exact int32 blocked scatter — not the
-    emulated-s64 fallback that cost ~3 s in round 3 — handles it."""
+def test_highcard_bench_shape_stays_on_blocked_path(request, monkeypatch):
+    """The kernel route of BASELINE config 5 (10 M rows x 70,225 groups) and
+    of the benchmark's ``highcard`` (11 010 048 rows x 73 728): on a CPU
+    backend the bucket count stays inside ``_MAX_BLOCK_SEGMENTS``, so the
+    exact int32 blocked scatter handles it — not the emulated-s64 fallback
+    that cost ~3 s in round 3; on an accelerator, where that scatter retires
+    an update every 8.8 ns, the one carried-payload sort does (PR 33)."""
+    monkeypatch.delenv("BQUERYD_TPU_FORCE_MATMUL", raising=False)
     m = _groupby_module()
-    n_blocks = -(-10_000_000 // m._SUM_BLOCK)
-    assert n_blocks * 70_225 <= m._MAX_BLOCK_SEGMENTS
+    ints = (np.zeros(1, np.int32),)
+    for n, n_groups in ((10_000_000, 70_225), (11_010_048, 73_728)):
+        assert -(-n // m._SUM_BLOCK) * n_groups <= m._MAX_BLOCK_SEGMENTS
+        assert not m._int_sums_sort(n, n_groups)
+        assert m.kernel_route(None, ints, ("sum",), n, n_groups) == "scatter"
+    m = request.getfixturevalue("groupby_as_accelerator")
+    for n, n_groups in ((10_000_000, 70_225), (11_010_048, 73_728)):
+        assert m._int_sums_sort(n, n_groups)
+        assert m.kernel_route(None, ints, ("sum",), n, n_groups) == "sort"
+        # the binding hints still name their own forms
+        assert m.kernel_route(
+            "scatter", ints, ("sum",), n, n_groups) == "scatter"
 
 
 def test_groupby_highcard_int64_sum_bit_exact():
@@ -208,6 +221,144 @@ def test_int64_segment_sum_routes_to_sorted_past_budget(monkeypatch):
     np.testing.assert_array_equal(
         np.asarray(rows), np.bincount(codes, minlength=n_groups)
     )
+
+
+_I64 = np.iinfo(np.int64)
+
+
+def _sorted_form_case(name):
+    """(codes, measures, ops, n_groups, mask, sentinels) of one case of the
+    sorted form's test."""
+    rng = np.random.default_rng(33)
+    n, n_groups, mask, sentinels = 5_000, 40, None, None
+    if name == "one_row":
+        n = 1
+    elif name == "groups_past_65536":
+        n, n_groups = 90_000, 70_001
+    codes = rng.integers(0, n_groups, n).astype(np.int32)
+    dtypes = {"int8": np.int8, "int16": np.int16, "int32": np.int32,
+              "int64": np.int64, "uint16": np.uint16}
+    ops = ("sum",)
+    if name in dtypes:
+        info = np.iinfo(dtypes[name])
+        values = rng.integers(
+            info.min, info.max, n, dtype=np.int64, endpoint=True
+        ).astype(dtypes[name])
+        values[:2] = info.min, info.max
+        measures = (values,)
+    elif name == "bool":
+        measures = (rng.random(n) < 0.4,)
+    elif name == "full_int64_range_wraps":
+        # extremes in one group: the true sum leaves int64 and wraps
+        values = rng.integers(_I64.min, _I64.max, n, dtype=np.int64)
+        values[:64] = _I64.max
+        codes[:64] = 3
+        values[64:96] = _I64.min
+        codes[64:96] = 4
+        measures = (values,)
+    elif name == "two_sums_and_a_count":
+        measures = (
+            rng.integers(_I64.min // 2, _I64.max // 2, n).astype(np.int64),
+            rng.integers(-(2**31), 2**31 - 1, n).astype(np.int32),
+            rng.integers(0, 9, n).astype(np.int16),
+        )
+        ops = ("sum", "sum", "count")
+    elif name == "datetime_count_with_nat":
+        stamps = rng.integers(0, 10**15, n).astype(np.int64)
+        stamps[::3] = _I64.min  # NaT
+        measures = (stamps, stamps)
+        ops = ("count", "count_na")
+        sentinels = (_I64.min, _I64.min)
+    else:
+        measures = (rng.integers(-(10**12), 10**12, n).astype(np.int64),)
+    if name == "mask":
+        mask = rng.random(n) < 0.6
+    elif name == "negative_codes":
+        codes[::5] = -1
+    elif name == "empty_groups":
+        codes[(codes % 3 == 0) | (codes < 2) | (codes > n_groups - 3)] = 7
+    elif name == "every_row_invalid":
+        codes[:] = -1
+    return codes, measures, ops, n_groups, mask, sentinels
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["int8", "int16", "int32", "int64", "uint16", "bool",
+     "full_int64_range_wraps", "mask", "negative_codes", "empty_groups",
+     "every_row_invalid", "one_row", "groups_past_65536",
+     "two_sums_and_a_count", "datetime_count_with_nat"],
+)
+def test_sorted_form_matches_add_at_bit_for_bit(case):
+    """The one carried-payload sort and its prefix differences (a binding
+    ``sort`` hint reaches it on this backend) against ``np.add.at``: every
+    table int64 and equal bit for bit, wrapping mod 2^64 like the blocked
+    scatter, whose own answer is compared too."""
+    import jax
+
+    codes, measures, ops, n_groups, mask, sentinels = _sorted_form_case(case)
+    out = jax.device_get(gb.partial_tables(
+        codes, measures, ops, n_groups, mask=mask, null_sentinels=sentinels,
+        strategy="sort",
+    ))
+    keep = codes >= 0
+    if mask is not None:
+        keep &= mask
+    rows = np.bincount(codes[keep], minlength=n_groups)
+    assert out["rows"].dtype == np.int64
+    np.testing.assert_array_equal(out["rows"], rows)
+    for values, op, agg in zip(measures, ops, out["aggs"]):
+        if op == "sum":
+            want = np.zeros(n_groups, dtype=np.int64)
+            with np.errstate(over="ignore"):
+                np.add.at(want, codes[keep], values[keep].astype(np.int64))
+            got = agg["sum"]
+        else:
+            null = values == _I64.min if sentinels else np.zeros(len(values), bool)
+            counted = keep & (null if op == "count_na" else ~null)
+            want = np.bincount(codes[counted], minlength=n_groups)
+            got = agg["count"]
+        assert got.dtype == np.int64 and got.shape == (n_groups,)
+        np.testing.assert_array_equal(got, want)
+    blocked = jax.device_get(gb.partial_tables(
+        codes, measures, ops, n_groups, mask=mask, null_sentinels=sentinels,
+        strategy="scatter",
+    ))
+    assert jax.tree_util.tree_structure(out) == \
+        jax.tree_util.tree_structure(blocked)
+    for got, want in zip(jax.tree_util.tree_leaves(out),
+                         jax.tree_util.tree_leaves(blocked)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+
+
+def test_sorted_form_lowers_to_one_sort_and_no_scatter():
+    """What the form is for, read off the lowered module of a two-sum
+    integer query under ``sort``: exactly one sort, whatever the number of
+    sums, no scatter, and no gather but of one element a group."""
+    import re
+
+    import jax
+
+    n, n_groups = 3 * 65536 + 17, 1_000
+    codes = np.zeros(n, np.int32)
+    measures = (np.zeros(n, np.int64), np.zeros(n, np.int32))
+    text = jax.jit(
+        lambda c, m, k: gb.partial_tables(
+            c, m, ("sum", "sum"), n_groups, mask=k, strategy="sort")
+    ).lower(codes, measures, np.ones(n, bool)).as_text()
+    assert text.count('"stablehlo.sort"(') == 1
+    assert "stablehlo.scatter" not in text
+    gathered = re.findall(r"stablehlo\.gather.*->\s*tensor<(\d+)x", text)
+    assert gathered and {int(g) for g in gathered} == {n_groups}
+    assert "dynamic_slice" not in text and "dynamic_gather" not in text
+    # the blocked form of the same query, for contrast: a scatter a limb
+    blocked = jax.jit(
+        lambda c, m, k: gb.partial_tables(
+            c, m, ("sum", "sum"), n_groups, mask=k, strategy="scatter")
+    ).lower(codes, measures, np.ones(n, bool)).as_text()
+    assert "stablehlo.sort" not in blocked
+    assert blocked.count('"stablehlo.scatter"(') == 1 + 4 + 2
 
 
 def test_groupby_count_na():
@@ -1577,16 +1728,14 @@ def test_a_query_of_extrema_alone_is_still_not_matmul_profitable(monkeypatch):
         (np.float32, "sum", "scatter", "dense"),  # accumulates in float64
         (np.int64, "mean", "matmul", "dense"),
         (np.int64, "sum", "matmul", None),
-        (np.float64, "min", "scatter", None),
+        (np.float64, "min", "sort", None),  # auto: its counts by the sort
         (np.float64, "count", "matmul", None),
     ],
 )
 def test_float_sum_route_names_the_float64_sums_only(
         groupby_as_accelerator, dtype, op, route, expect):
-    strategy = None if route == "matmul" else "scatter"
+    strategy = "scatter" if route == "scatter" else None
     measures = (np.zeros(8, dtype),)
-    if op == "min" and route == "scatter":
-        strategy = None
     assert gb.kernel_route(strategy, measures, (op,), 4096, 10) == route
     assert gb.float_sum_route(strategy, measures, (op,), 4096, 10) == expect
 
@@ -1607,6 +1756,27 @@ def test_kernel_route_predictions(monkeypatch):
     assert gb.kernel_route(
         "matmul", ints, ("sum",), 10_000, 9
     ) == "scatter"  # backend guard: the advisory route cannot force it
+
+
+@pytest.mark.parametrize(
+    "n, n_groups, ops_",
+    [(5000, 8193, ("sum",)), (11_010_048, 73_728, ("sum",)),
+     (5000, 10, ("min", "max")), (1_048_576, 262_144, ("sum", "count"))],
+    ids=["groups=8193", "highcard", "min-max-only", "groups=262144"],
+)
+def test_kernel_route_says_sort_wherever_an_accelerator_scatters(
+        groupby_as_accelerator, monkeypatch, n, n_groups, ops_):
+    """Whatever reaches the scatter entry on an accelerator counts and sums
+    its integers by the one sort (``rows`` at the least: the extrema of a
+    min/max-only query keep their own scatters), so ``auto`` reads ``sort``
+    there; the binding hints read as themselves on any backend."""
+    monkeypatch.delenv("BQUERYD_TPU_FORCE_MATMUL", raising=False)
+    m = groupby_as_accelerator
+    stubs = (np.zeros(1, np.int32),) * len(ops_)
+    for spelling in (None, "auto", "matmul"):
+        assert m.kernel_route(spelling, stubs, ops_, n, n_groups) == "sort"
+    assert m.kernel_route("scatter", stubs, ops_, n, n_groups) == "scatter"
+    assert m.kernel_route("sort", stubs, ops_, n, n_groups) == "sort"
 
 
 #: a case of the route table: the backend ``ops.groupby`` reads ("cpu" is
@@ -1643,8 +1813,20 @@ _ROUTE_TABLE = [
                  id="int64-sum:cpu-without-FORCE_MATMUL"),
     pytest.param("tpu", 5000, 37, _SUM, "matmul", True,
                  id="int64-sum:accelerator"),
-    pytest.param("tpu", 5000, 8193, _SUM, "scatter", True,
+    pytest.param("tpu", 5000, 8193, _SUM, "sort", True,
                  id="groups=8193:accelerator"),
+    pytest.param("tpu", 150_000, 73_728, ((np.int32, "sum"),), "sort", True,
+                 id="groups=73728:accelerator"),
+    pytest.param("tpu", 11_010_048, 73_728, ((np.int32, "sum"),), "sort",
+                 False, id="highcard:accelerator"),
+    pytest.param("cpu", 11_010_048, 73_728, ((np.int32, "sum"),), "scatter",
+                 False, id="highcard:cpu"),
+    pytest.param("tpu", 5000, 10,
+                 ((np.float64, "min"), (np.int64, "max")), "sort", True,
+                 id="min-max-only:accelerator"),
+    pytest.param("tpu", 5000, 8193,
+                 ((np.int64, "sum"), (np.float64, "min"), (np.int16, "count")),
+                 "sort", True, id="sum-min-count:accelerator"),
     pytest.param("cpu", 1024 * _BLOCK, (1 << 25) // 1024, _SUM, "scatter",
                  False, id="blocks*groups=2^25:the-budget"),
     pytest.param("cpu", 1024 * _BLOCK, (1 << 25) // 1024 + 1, _SUM, "sort",
@@ -1660,8 +1842,9 @@ def test_route_table(request, monkeypatch, backend, n, n_groups, aggs, route,
                      runs):
     """The ONE rule that routes a served query, a case each side of every
     boundary: ``kernel_route`` under ``auto`` names the route, the
-    dispatcher enters that route's kernel, and the answer is the named
-    route's own, bit for bit."""
+    dispatcher enters that route's kernel, the traced program has the named
+    form (a sort and no integer scatter, or the reverse), and the answer is
+    the named route's own, bit for bit."""
     import unittest.mock as mock
 
     import jax
@@ -1698,8 +1881,19 @@ def test_route_table(request, monkeypatch, backend, n, n_groups, aggs, route,
     ) as scatter:
         auto = jax.device_get(
             m.partial_tables(codes, measures, ops_, n_groups, mask))
+    # ("sort" is a form of the scatter entry, not an entry of its own)
     assert (mm.called, scatter.called) == (
-        route == "matmul", route == "scatter")
+        route == "matmul", route != "matmul")
+    if route != "matmul":
+        text = jax.jit(
+            lambda c, ms, k: m.partial_tables(c, ms, ops_, n_groups, k)
+        ).lower(codes, measures, mask).as_text()
+        extrema = sum(op in ("min", "max") for op in ops_)
+        assert text.count('"stablehlo.sort"(') == (route == "sort")
+        if route == "sort":  # only the extrema still scatter
+            assert text.count('"stablehlo.scatter"(') == extrema
+        else:
+            assert text.count('"stablehlo.scatter"(') > extrema
     if route == "matmul":
         own = m._partial_tables_mm(
             codes, measures, ops_, n_groups, mask, use_pallas=False,
