@@ -377,7 +377,9 @@ SPAN_SCHEMA = {
                       "device's time as the host waits for it; tagged "
                       "effective_strategy and, where the mesh executor "
                       "summed in float64 on an accelerator, float_sum "
-                      "(dense | sorted)",
+                      "(dense | sorted); for a solo or DAG unit also "
+                      "merge_mode (device | host, the reply's own) and "
+                      "devices (the mesh's size)",
     "send": "detail annotation: the reply's send; its seconds ride the "
             "next calc reply's phase_timings['post_prev']",
     "post": "detail annotation: Done + throttled gc.collect() + RSS check "

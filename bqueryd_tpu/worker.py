@@ -1929,8 +1929,16 @@ class WorkerNode(WorkerBase):
                     if span.get("name") in ("kernel", "aggregate_wait"):
                         tags = span.setdefault("tags", {})
                         tags["effective_strategy"] = effective
-                        if float_sum and span["name"] == "aggregate_wait":
-                            tags["float_sum"] = float_sum
+                        if span["name"] == "aggregate_wait":
+                            # a detail span (switch only): which merge this
+                            # launch ran, over how many devices — a degraded
+                            # fetch does not pass for the normal path
+                            if float_sum:
+                                tags["float_sum"] = float_sum
+                            tags["merge_mode"] = merge_mode
+                            tags["devices"] = int(
+                                self.mesh_executor.mesh.devices.size
+                            )
             if float_sum:
                 self.metrics.counter(
                     "bqueryd_tpu_float_sum_total",

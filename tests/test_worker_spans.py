@@ -306,6 +306,46 @@ def test_the_float_sum_tag_and_counter_exist_under_the_switch_only(
     assert "float_sum" not in kernel.get("tags", {})
 
 
+@pytest.mark.parametrize("kind", ["solo", "dag"])
+@pytest.mark.parametrize("profile", ["1", None], ids=["traced", "untraced"])
+def test_the_merge_tags_exist_under_the_switch_only(node, monkeypatch, profile, kind):
+    """PR 34: which merge a launch ran and over how many devices is detail
+    — two tags on the ``aggregate_wait`` span, the ``merge_mode`` the reply
+    already sends and the mesh's size; without the switch there is no such
+    span, and the reply gains no key either way."""
+    if profile:
+        monkeypatch.setenv("BQUERYD_TPU_PROFILE", profile)
+    else:
+        monkeypatch.delenv("BQUERYD_TPU_PROFILE", raising=False)
+    node["run"](kind)   # steady state: a first pass marks its compile
+    reply = node["run"](kind)
+    assert set(reply) == REPLY_KEYS[kind]
+    tags = [s.get("tags", {}) for s in reply["spans"]
+            if s["name"] == "aggregate_wait"]
+    if profile:
+        mesh = node["worker"].mesh_executor.mesh
+        assert reply["merge_mode"] == "device"
+        assert [(t["merge_mode"], t["devices"]) for t in tags] == [
+            ("device", mesh.devices.size)]
+        assert "float_sum" not in tags[0]   # an int64 sum: nothing to name
+    else:
+        assert tags == []
+    kernel = next(s for s in reply["spans"] if s["name"] == "kernel")
+    assert not {"merge_mode", "devices"} & set(kernel.get("tags", {}))
+
+
+def test_the_merge_tag_names_the_host_merge_under_its_kill_switch(node, monkeypatch):
+    """A launch whose partial tables were merged on the host does not pass
+    for the device merge in a timeline."""
+    monkeypatch.setenv("BQUERYD_TPU_PROFILE", "1")
+    monkeypatch.setenv("BQUERYD_TPU_DEVICE_MERGE", "0")
+    reply = node["run"]("solo")
+    assert reply["merge_mode"] == "host"
+    tags = [s["tags"] for s in reply["spans"] if s["name"] == "aggregate_wait"]
+    assert [t["merge_mode"] for t in tags] == ["host"]
+    assert tags[0]["devices"] == node["worker"].mesh_executor.mesh.devices.size
+
+
 # -- (c) the loop thread's annotations --------------------------------------------
 
 class Annotations:
