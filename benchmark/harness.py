@@ -280,17 +280,59 @@ def memory_peak(rpc, records):
 
 # -- the check ---------------------------------------------------------------------
 
-def check(cell, seed, names, records, control=False):
-    """Compare the sampled answers of the window with the plain reference."""
+def evenly(items, count):
+    """``count`` of ``items``, evenly spaced, the first and the last among them."""
+    if count >= len(items) or count < 2:
+        return list(items[:max(count, 0)])
+    last, steps = len(items) - 1, count - 1
+    return [items[(i * last + steps // 2) // steps] for i in range(count)]
+
+
+def chosen(mix, recorded):
+    """The recorded answers the reference compares: all of them up to the
+    mix's ``check_at_most``, beyond it that many, so that what a run does
+    after its window does not grow with what the window completed.  Each
+    shape the window recorded gets an equal share (a remainder goes to the
+    shapes first in file order; what a shape with fewer answers leaves
+    passes to the others), evenly spaced over its answers in send order.
+    The choice follows from the records alone: no draw, the same twice."""
+    by_shape = {shape: [] for shape in traffic.shapes_of(mix)}
+    for record in recorded:
+        by_shape[record["shape"]].append(record)
+    waiting = [shape for shape, answers in by_shape.items() if answers]
+    share_of, left = {}, int(mix["check_at_most"])
+    while waiting:
+        share, extra = divmod(left, len(waiting))
+        offer = {shape: share + (i < extra) for i, shape in enumerate(waiting)}
+        short = [shape for shape in waiting if len(by_shape[shape]) <= offer[shape]]
+        if not short:
+            share_of.update(offer)
+            break
+        for shape in short:
+            share_of[shape] = len(by_shape[shape])
+            left -= share_of[shape]
+            waiting.remove(shape)
+    picked = {id(r) for shape, n in share_of.items() for r in evenly(by_shape[shape], n)}
+    return [r for r in recorded if id(r) in picked]
+
+
+def check(cell, ref, records, control=False):
+    """Compare answers the window recorded with the plain reference ``ref``.
+    A marked query that has no answer counts in ``unanswered`` whatever is
+    chosen; of the answered ones ``chosen`` says which are compared."""
     config = cell["config"]
-    frames = dict(zip(names, data.frames(config, seed)))
-    ref = reference.Reference(frames)
-    sampled = [r for r in records if r["check"]]
-    numbers, control_numbers = [], []
-    for record in sampled:
+    marked = [r for r in records if r["check"]]
+    recorded = [r for r in marked if r.get("answer") is not None]
+    compared = chosen(cell["mix"], recorded)
+    numbers = [
+        reference.compare(r["args"], None, None, config["columns"])
+        for r in marked if r.get("answer") is None
+    ]
+    control_numbers = []
+    for record in compared:
         expected = ref.answer(record["args"])
         numbers.append(reference.compare(
-            record["args"], record.get("answer"), expected, config["columns"]
+            record["args"], record["answer"], expected, config["columns"]
         ))
         if control:
             control_numbers.append(reference.compare(
@@ -299,12 +341,36 @@ def check(cell, seed, names, records, control=False):
             ))
     limits = config["guarantees"]["check_limits"]
     correct, rows = reference.verdict(reference.worst(numbers), limits)
-    correct = correct and len(sampled) > 0
-    rows.append(["answers_compared", len(sampled), None])
+    correct = correct and len(compared) > 0
+    rows.append(["answers_recorded", len(recorded), None])
+    rows.append(["answers_compared", len(compared), None])
     out = {"correct": correct, "rows": rows}
     if control:
         out["control"] = reference.verdict(reference.worst(control_numbers), limits)
     return out
+
+
+class Laps:
+    """Where a run's own seconds went: the clock at the points the run
+    passes, each logged as it is passed, so that a run that is cut shows in
+    its log how far it got."""
+
+    def __init__(self, label, started):
+        self.label, self.started, self.last, self.parts = label, started, started, {}
+
+    def __call__(self, part, note=""):
+        now = time.perf_counter()
+        self.parts[part] = now - self.last
+        self.last = now
+        cl.log(f"{self.label}: {part} took {self.parts[part]:.1f}s, "
+               f"done at {now - self.started:.1f}s{note}")
+        return now
+
+    def total(self):
+        """The parts and their total, for ``observed["run_s"]``."""
+        cl.log(f"{self.label}: run {self.last - self.started:.1f}s = "
+               + " + ".join(f"{part} {s:.1f}" for part, s in self.parts.items()))
+        return dict(self.parts, total=self.last - self.started)
 
 
 # -- one run -----------------------------------------------------------------------
@@ -322,6 +388,7 @@ def run_cell(workload, seed, seconds, trace, started=None, control=False,
     warmup_max_s = rehearsal.get("warmup_max_s")
     cell = load_cell(workload, home)
     config, mix = cell["config"], cell["mix"]
+    lap = Laps(workload, started)
     state = os.path.join(home, "benchmark")
     from bqueryd_tpu.storage import native
 
@@ -365,8 +432,7 @@ def run_cell(workload, seed, seconds, trace, started=None, control=False,
                 f"the worker computes on {device['count']} x {device['platform']}, "
                 f"the cell needs {cell['chips']} x {expect_platform}"
             )
-        cl.log(f"{workload}: worker ready at {time.perf_counter() - started:.1f}s "
-               f"on {device['count']} x {device['device_kind']}")
+        lap("worker_ready", f" on {device['count']} x {device['device_kind']}")
 
         warm_rng = np.random.default_rng([int(seed), 11])
         constants = {
@@ -386,6 +452,7 @@ def run_cell(workload, seed, seconds, trace, started=None, control=False,
             t0 = time.perf_counter()
             cold_records = one_pass(rpc, cell, names, rows_of, constants)
             cold_s = (time.perf_counter() - t0) / len(cold_records)
+        lap("cold_pass")
 
         history, warm_since = {}, None
         now = time.perf_counter()
@@ -430,8 +497,9 @@ def run_cell(workload, seed, seconds, trace, started=None, control=False,
         if trace:
             tracer = Tracer(cluster, os.path.join(workdir, "trace"))
             evidence["counters_before"] = tracer.call("counters")
-        setup_s = time.perf_counter() - started
+        setup_s = lap("warm_up") - started
         start, records = run_window(cluster, plans, seconds, tracer)
+        lap("window")
 
         peak = memory_peak(rpc, records)
         if trace:
@@ -452,8 +520,12 @@ def run_cell(workload, seed, seconds, trace, started=None, control=False,
         cluster.stop()
         shutil.rmtree(workdir, ignore_errors=True)
 
+    lap("stop")
     # the chip is free and the peak is read: now the reference
-    checked = check(cell, seed, names, records, control)
+    ref = reference.Reference(dict(zip(names, data.frames(config, seed))))
+    lap("frames")
+    checked = check(cell, ref, records, control)
+    lap("reference")
     result = {
         "correct": checked["correct"],
         "attempted": len(records),
@@ -482,7 +554,7 @@ def run_cell(workload, seed, seconds, trace, started=None, control=False,
             }
     else:
         result["metrics"] = end_to_end(cell, records, start, cold_s, setup_s)
-    result["observed"] = observed(records, warm_routes, passes)
+    result["observed"] = dict(observed(records, warm_routes, passes), run_s=lap.total())
     if control:
         result["control"] = {"fails": not checked["control"][0], "rows": checked["control"][1]}
     result["check"] = {name: [number, limit] for name, number, limit in checked["rows"]}

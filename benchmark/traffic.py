@@ -7,22 +7,31 @@ repeat a tile as it stands; every other request carries a fresh constant in
 the configuration's slot.  Every seed gets the same work in another order:
 shapes come in shuffled cycles that hold each shape exactly ``weight`` times
 (times four where some repeat, so the repeat share is exact per cycle), and
-the constant is drawn stratified over its range, never twice.
+the constant is drawn stratified over its range, never twice.  Every
+``check_every``-th planned query is marked for the check (its answer is
+kept); ``check_at_most`` is how many of the kept answers the reference
+compares after the window (``harness.chosen``).
 """
 
 import json
 
 import numpy as np
 
-MIX_KEYS = {"name", "why", "streams", "check_every", "warmup_max_s", "settle_allow"}
+MIX_KEYS = {"name", "why", "streams", "check_every", "check_at_most", "warmup_max_s",
+            "settle_allow"}
 STREAM_KEYS = {"count", "repeat_share", "shapes"}
 
 
 def read_mix(path):
     """A mix file; a key the generator does not read is an error, so that
-    no mix asks for an open loop or a think time and gets neither."""
+    no mix asks for an open loop or a think time and gets neither, and so
+    is a key it reads and the mix leaves out, so that none arrives with an
+    uncapped check."""
     with open(path) as f:
         mix = json.load(f)
+    missing = MIX_KEYS - set(mix)
+    if missing:
+        raise ValueError(f"{path}: the mix states no {sorted(missing)}")
     unread = set(mix) - MIX_KEYS
     for stream in mix["streams"]:
         unread |= set(stream) - STREAM_KEYS

@@ -32,7 +32,7 @@ TINY = {"taxi-1chip": "taxi-tiny.json", "taxi-4chip": "taxi-tiny4.json"}
 #: tiles, three requests in four as the tile stands
 DASHBOARD = {
     "name": "dashboard-3x2", "why": "a repeating mix at a tiny size",
-    "check_every": 4, "warmup_max_s": 30, "settle_allow": 0,
+    "check_every": 4, "check_at_most": 48, "warmup_max_s": 30, "settle_allow": 0,
     "streams": [{"count": 3, "repeat_share": 0.75,
                  "shapes": [{"shape": "sharded", "weight": 1}, {"shape": "highcard", "weight": 1}]}],
 }
@@ -193,16 +193,22 @@ def test_no_two_window_queries_of_an_adhoc_mix_are_equal(mix_name):
 
 def test_a_mix_is_held_to_the_keys_the_generator_reads(tmp_path):
     """A mix that asks for an open loop or a think time is refused, not
-    silently given the closed loop; the shipped mixes are as ISSUE.md has them."""
-    for name, wrong in (("loop", {"loop": "open"}), ("think_s", {"streams": [dict(DASHBOARD["streams"][0], think_s=1)]})):
+    silently given the closed loop, and so is one that states no cap on the
+    check; the shipped mixes are as ISSUE.md has them."""
+    uncapped = {k: v for k, v in DASHBOARD.items() if k != "check_at_most"}
+    for name, wrong in (("loop", dict(DASHBOARD, loop="open")), ("check_at_most", uncapped),
+                        ("think_s", dict(DASHBOARD, streams=[dict(DASHBOARD["streams"][0], think_s=1)]))):
         path = tmp_path / f"{name}.json"
-        path.write_text(json.dumps(dict(DASHBOARD, **wrong)))
+        path.write_text(json.dumps(wrong))
         with pytest.raises(ValueError, match=name):
             traffic.read_mix(str(path))
+    (tmp_path / "whole.json").write_text(json.dumps(DASHBOARD))
+    assert traffic.read_mix(str(tmp_path / "whole.json")) == DASHBOARD
     shapes = {name: [e["shape"] for s in traffic.read_mix(os.path.join(DATA, "traffic", name + ".json"))["streams"]
                      for e in s["shapes"]] for name in ("adhoc-lowcard", "adhoc-heavy", "dashboard-8")}
     assert shapes == {"adhoc-lowcard": ["single", "filtered", "multikey"], "adhoc-heavy": ["highcard", "f64mean"],
                       "dashboard-8": ["sharded", "multikey", "filtered", "highcard"]}
+    assert {traffic.read_mix(os.path.join(DATA, "traffic", name + ".json"))["check_at_most"] for name in shapes} == {48}
 
 
 def test_a_repeating_mix_repeats_three_in_four():
@@ -269,6 +275,91 @@ def test_the_control_in_float32_fails_the_comparison(tiny_reference, shape):
     control = ref.answer(args, accumulate="float32")
     numbers = reference.worst([reference.compare(args, control, ref.answer(args), config["columns"])])
     assert not reference.verdict(numbers, config["guarantees"]["check_limits"])[0], numbers
+
+
+# -- the cap on the check: what a run does after its window ------------------------------------
+
+class CountingReference:
+    """Stands where ``reference.Reference`` does and keeps what it was asked."""
+
+    def __init__(self):
+        self.asked, self.asked_as_control = [], []
+
+    def answer(self, args, accumulate=None):
+        (self.asked if accumulate is None else self.asked_as_control).append(args[3][0][2])
+        return hand_made_answer(args[3][0][2], off=0 if accumulate is None else 1)
+
+
+def hand_made_answer(value, off=0):
+    return pd.DataFrame({"passenger_count": [1, 2], "s": np.array([value, value + 1 + off], dtype=np.int64)})
+
+
+def hand_made_records(recorded, unanswered=0):
+    """A window's records in send order, the shapes taking turns while each
+    lasts: every third marked for the check and holding its answer, the
+    last ``unanswered`` of the marked ones holding none."""
+    left, records = dict(recorded), []
+    while any(left.values()):
+        for shape in [s for s, n in left.items() if n]:
+            left[shape] -= 1
+            for marked in (True, False, False):
+                value = len(records)
+                args = (["f"], ["passenger_count"], [["fare_amount", "sum", "s"]], [["trip_distance", ">", value]])
+                records.append({"shape": shape, "args": args, "check": marked, "ok": True, "t_send": float(value)})
+                if marked:
+                    records[-1]["answer"] = hand_made_answer(value)
+    for record in [r for r in records if r["check"]][len(records) // 3 - unanswered:]:
+        record.update(ok=False, answer=None)
+    return records
+
+
+THREE_SHAPES = dict(DASHBOARD, check_at_most=50, streams=[{"count": 1, "repeat_share": 0.0, "shapes": [
+    {"shape": s, "weight": 1} for s in ("single", "filtered", "multikey")]}])
+CAPPED = {
+    # case: (mix, recorded answers by shape, marked queries with no answer, compared by shape)
+    "300_recorded_of_two_shapes_compare_24_of_each": ("adhoc-heavy", {"highcard": 150, "f64mean": 150}, 0, [24, 24]),
+    "30_recorded_compare_30": ("adhoc-heavy", {"highcard": 15, "f64mean": 15}, 0, [15, 15]),
+    "5_of_one_shape_and_200_of_the_other_compare_5_and_43": ("adhoc-heavy", {"highcard": 5, "f64mean": 200}, 0, [5, 43]),
+    "a_remainder_goes_to_the_shapes_first_in_file_order": (THREE_SHAPES, {"multikey": 90, "single": 80, "filtered": 70}, 0, [17, 17, 16]),
+    "a_marked_query_with_no_answer_is_unanswered_though_never_chosen": ("adhoc-heavy", {"highcard": 150, "f64mean": 150}, 2, [24, 24]),
+    "the_same_records_give_the_same_choice_twice": ("adhoc-heavy", {"highcard": 101, "f64mean": 77}, 0, [24, 24]),
+    "the_control_sees_the_chosen_set": ("adhoc-heavy", {"highcard": 40, "f64mean": 9}, 0, [39, 9]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CAPPED))
+def test_the_check_compares_at_most_what_the_mix_states_of_the_recorded_answers(case):
+    mix, recorded, unanswered, compared = CAPPED[case]
+    if isinstance(mix, str):
+        mix = traffic.read_mix(os.path.join(DATA, "traffic", mix + ".json"))
+    cell = {"config": harness.load_json(os.path.join(HERE, "taxi-tiny.json")), "mix": mix}
+    records = hand_made_records(recorded, unanswered)
+    ref, again = CountingReference(), CountingReference()
+    out = harness.check(cell, ref, records, control=True)
+    rows = {name: number for name, number, _limit in out["rows"]}
+    assert rows["answers_recorded"] == sum(recorded.values()) - unanswered
+    assert rows["answers_compared"] == sum(compared) == len(ref.asked) <= mix["check_at_most"]
+    assert rows["answers_compared"] == min(rows["answers_recorded"], mix["check_at_most"])
+    assert rows["unanswered"] == unanswered and rows["int_mismatch"] == 0
+    assert out["correct"] is (unanswered == 0)
+    by_value = {r["args"][3][0][2]: r for r in records}
+    assert all(by_value[v]["check"] and by_value[v]["answer"] is not None for v in ref.asked)
+    assert ref.asked == sorted(ref.asked)   # in send order
+    for shape, count in zip(traffic.shapes_of(mix), compared):
+        mine = [r["args"][3][0][2] for r in records if r["shape"] == shape and r.get("answer") is not None]
+        asked = [v for v in ref.asked if by_value[v]["shape"] == shape]
+        assert len(asked) == count and asked[0] == mine[0] and asked[-1] == mine[-1]
+        # evenly spaced: no two gaps differ by more than one answer of the shape
+        gaps = {mine.index(b) - mine.index(a) for a, b in zip(asked, asked[1:])}
+        assert not gaps or max(gaps) - min(gaps) <= 1
+    # the control is the reference in float32's place over the same chosen answers, and fails
+    assert ref.asked_as_control == ref.asked and out["control"][0] is False
+    # nothing is drawn: the same records give the same choice, with the control or without
+    assert harness.check(cell, again, records)["rows"] == out["rows"] and again.asked == ref.asked
+    assert again.asked_as_control == []
+    # a wrong answer among the chosen is found as before
+    by_value[ref.asked[-1]]["answer"] = hand_made_answer(ref.asked[-1], off=1)
+    assert harness.check(cell, CountingReference(), records)["correct"] is False
 
 
 # -- the yardstick's arithmetic ------------------------------------------------------------
@@ -364,7 +455,7 @@ def test_rehearse_one_cell_of_each_mix(tmp_path, deployment_defaults, workload, 
     assert CONTRACT_KEYS <= set(result) and list(result)[-1] == "check"
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 3
     assert result["device"]["platform"] == "cpu"
-    assert result["check"]["answers_compared"][0] >= 1
+    assert result["check"]["answers_recorded"][0] >= result["check"]["answers_compared"][0] >= 1
     assert result["control"]["fails"] is True
     cell = harness.load_cell(workload, str(tmp_path))
     if trace:
@@ -446,7 +537,7 @@ def test_a_configuration_a_mix_and_a_metric_are_added_as_data(tmp_path, deployme
         TINY.pop("taxi-half")
     (tmp_path / "benchmark" / "traffic" / "adhoc-sharded.json").write_text(json.dumps({
         "name": "adhoc-sharded", "why": "two streams that repeat: what a dashboard mix needs of the generator",
-        "check_every": 2, "warmup_max_s": 30, "settle_allow": 0,
+        "check_every": 2, "check_at_most": 48, "warmup_max_s": 30, "settle_allow": 0,
         "streams": [{"count": 2, "repeat_share": 0.5, "shapes": [{"shape": "sharded", "weight": 1}]}],
     }))
     (tmp_path / "benchmark" / "layer_metrics" / "executor_layout_ms.json").write_text(json.dumps({
