@@ -353,12 +353,15 @@ SPAN_SCHEMA = {
     "parse": "detail span in calc: envelope args, plan fragment / DAG "
              "round trip, up to the first table open",
     "cache_probe": "detail span in calc: result-cache key + get and the "
-                   "delta key before the execution; the delta cache's own "
+                   "delta key before the execution, both made from the "
+                   "identities the open read; the delta cache's own "
                    "look-up (_serve_delta); cache.put + delta_cache.store "
                    "after serialize",
     "table_keys": "detail span in calc, between the executor's prune and "
-                  "align: the identity of every table of the unit (a stat "
-                  "and a realpath each) for the executor's cache keys",
+                  "align: the identities of the unit's tables for the "
+                  "executor's cache keys, taken from what the worker's "
+                  "open read (a stat and a realpath each, once a unit); "
+                  "asked of the filesystem here only for bare tables",
     "mem_sample": "detail span in calc: each device-memory sample around "
                   "the execution (with note_devices)",
     "layout_fold": "detail span in h2d_transfer: the row mask folded into "

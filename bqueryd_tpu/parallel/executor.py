@@ -224,6 +224,36 @@ def _codes_dtype(n_groups):
 from bqueryd_tpu.storage.ctable import table_cache_key as _table_key  # noqa: E402,E501
 
 
+def _matching_shards(tables, identities, where_terms):
+    """Host-side shard pruning: the tables that can hold a matching row,
+    and with them the identities their caller handed down (or None)."""
+    from bqueryd_tpu import ops
+
+    keep = [ops.shard_can_match(t, where_terms) for t in tables]
+    tables = [t for t, k in zip(tables, keep) if k]
+    if identities is not None:
+        identities = [i for i, k in zip(identities, keep) if k]
+    return tables, identities
+
+
+def view_identities(identities, tables, views, on_recomputed=None):
+    """What handed-down ``identities`` become once chunk pruning put
+    ``views`` in the place of ``tables``: a table that pruning left whole
+    keeps what its open read; a :class:`ChunkView` is filed under its own
+    token (the chunk selection and its parent's meta identity, which
+    building the view read off the filesystem again: ``on_recomputed`` is
+    told how many did)."""
+    if identities is None:
+        return None
+    n_views = sum(view is not table for view, table in zip(views, tables))
+    if n_views and on_recomputed is not None:
+        on_recomputed(n_views)
+    return tuple(
+        ident if view is table else _table_key(view)
+        for ident, table, view in zip(identities, tables, views)
+    )
+
+
 class MeshQueryExecutor:
     """Executes a :class:`GroupByQuery` over a list of shard tables on a
     device mesh, merging per-shard partials inside the compiled program
@@ -234,10 +264,15 @@ class MeshQueryExecutor:
     """
 
     def __init__(self, mesh=None, axis_name="shards", timer=None,
-                 host_limit_bytes=None):
+                 host_limit_bytes=None, on_identity_recomputed=None):
         self._mesh = mesh
         self.axis_name = axis_name
         self.timer = timer
+        #: called with the number of tables whose identity an execute*()
+        #: asked the filesystem for itself, because its caller handed none
+        #: down (the worker counts them:
+        #: ``bqueryd_tpu_table_identity_total{source="recomputed"}``)
+        self._on_identity_recomputed = on_identity_recomputed
         self._align_engine = None
         #: the physical kernel route the last execute() dispatched
         #: (post-guards) — the worker surfaces it as ``effective_strategy``
@@ -597,12 +632,27 @@ class MeshQueryExecutor:
         with tracing.detail("layout_fold", self.timer, site="device"):
             return program(unmasked, tuple(columns), constants)
 
+    def _tables_key(self, tables, identities):
+        """The first element of every cache key of a unit: the identity of
+        each table, in table order.  A worker hands down what its open
+        read (``identities``, one per table: the value ``_table_key``
+        would return, read once per unit); for bare tables — tests, a
+        caller with no open of its own — each is asked of the filesystem
+        here, as before."""
+        with tracing.detail("table_keys", self.timer):
+            if identities is not None:
+                return tuple(identities)
+            if self._on_identity_recomputed is not None:
+                self._on_identity_recomputed(len(tables))
+            return tuple(_table_key(t) for t in tables)
+
     # -- execution ----------------------------------------------------------
     def execute(self, tables, query: GroupByQuery,
-                strategy=None) -> ResultPayload:
+                strategy=None, identities=None) -> ResultPayload:
         """``strategy`` is the planner's kernel-route hint, threaded into the
         mesh program's ``partial_tables`` call (and its trace cache key);
-        None/"auto" keeps the dispatcher's own adaptive choice."""
+        None/"auto" keeps the dispatcher's own adaptive choice.
+        ``identities``: see :meth:`_tables_key`."""
         from bqueryd_tpu import chaos, ops
 
         # chaos site worker.device: a transient DeviceBusyError raised here
@@ -647,12 +697,10 @@ class MeshQueryExecutor:
         engine = self._engine()
 
         with self._phase("prune"):
-            tables = [
-                t
-                for t in tables
-                if not query.where_terms
-                or ops.shard_can_match(t, query.where_terms)
-            ]
+            if query.where_terms:
+                tables, identities = _matching_shards(
+                    tables, identities, query.where_terms
+                )
         if not tables:
             return ResultPayload.empty()
         import jax
@@ -660,8 +708,7 @@ class MeshQueryExecutor:
 
         from bqueryd_tpu.parallel import pipeline
 
-        with tracing.detail("table_keys", self.timer):
-            tables_key = tuple(_table_key(t) for t in tables)
+        tables_key = self._tables_key(tables, identities)
         cols_key = tuple(query.groupby_cols)
         mesh = self.mesh
         n_dev = mesh.devices.size
@@ -1030,7 +1077,8 @@ class MeshQueryExecutor:
         )
 
     # -- shared-scan bundles -------------------------------------------------
-    def execute_bundle(self, tables, queries, strategy=None):
+    def execute_bundle(self, tables, queries, strategy=None,
+                       identities=None):
         """Shared-scan execution of a compatible query bundle: every query
         scans the same ``tables`` with the same group-key columns; measures
         and filters may differ per member.  One decode/align/factorize pass,
@@ -1092,8 +1140,7 @@ class MeshQueryExecutor:
 
         from bqueryd_tpu.parallel import devicemerge, pipeline
 
-        with tracing.detail("table_keys", self.timer):
-            tables_key = tuple(_table_key(t) for t in tables)
+        tables_key = self._tables_key(tables, identities)
         cols_key = tuple(gcols)
         mesh = self.mesh
         n_dev = mesh.devices.size
@@ -1313,7 +1360,7 @@ class MeshQueryExecutor:
             return out
 
     # -- operator-DAG fast path ----------------------------------------------
-    def execute_dag(self, tables, dag):
+    def execute_dag(self, tables, dag, identities=None):
         """Batched mesh execution of an EXTENDED operator DAG (joins /
         top-k / quantile sketches / window rollups): one decode/align/H2D
         pass over the whole shard group — join-probe gathers, window-bucket
@@ -1377,10 +1424,9 @@ class MeshQueryExecutor:
 
         with self._phase("prune"):
             if dag.scan.pushdown:
-                tables = [
-                    t for t in tables
-                    if ops.shard_can_match(t, dag.scan.pushdown)
-                ]
+                tables, identities = _matching_shards(
+                    tables, identities, dag.scan.pushdown
+                )
                 pruned = []
                 for t in tables:
                     view, decoded, skipped = ops.chunk_pruned_table(
@@ -1389,6 +1435,9 @@ class MeshQueryExecutor:
                     pruned.append(view)
                     if decoded or skipped:
                         self.last_prune_counts.append((decoded, skipped))
+                identities = view_identities(
+                    identities, tables, pruned, self._on_identity_recomputed
+                )
                 tables = pruned
         if not tables:
             return ResultPayload.empty()
@@ -1458,8 +1507,7 @@ class MeshQueryExecutor:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        with tracing.detail("table_keys", self.timer):
-            tables_key = tuple(_table_key(t) for t in tables)
+        tables_key = self._tables_key(tables, identities)
         derive_sig = dag.derive_signature()
         mesh = self.mesh
         n_dev = mesh.devices.size
@@ -1472,8 +1520,9 @@ class MeshQueryExecutor:
         # repeat query (same derivations, any measures) skips them all
         dexec = opexec.DagExecutor(engine)
 
-        def derive(table):
-            dkey = (_table_key(table), "dagderive", derive_sig)
+        def derive(shard):
+            table, table_key = shard
+            dkey = (table_key, "dagderive", derive_sig)
             hit = self._align_cache.get(dkey)
             if hit is not None:
                 return hit
@@ -1508,7 +1557,9 @@ class MeshQueryExecutor:
 
         def get_derived():
             if "v" not in derived_memo:
-                derived_memo["v"] = self._map_shards(derive, tables)
+                derived_memo["v"] = self._map_shards(
+                    derive, zip(tables, tables_key)
+                )
             return derived_memo["v"]
 
         missing_cols = [

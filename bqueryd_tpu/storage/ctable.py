@@ -996,7 +996,14 @@ def table_cache_key(table):
     reshard/activation (which rewrites meta.json) invalidates naturally.
     Tables without a stat-able meta.json get a one-time random token pinned
     to the instance (NOT id(): CPython reuses addresses after GC, which
-    would let a new table hit a dead table's cached blocks)."""
+    would let a new table hit a dead table's cached blocks).
+
+    This asks the filesystem (a stat and a realpath) at every call.  A
+    worker's unit of work does not call it: ``WorkerNode._open_identified``
+    reads :func:`rootdir_cache_key` once per shard at open and hands the
+    same value, ``key + (nrows,)``, down to the result cache, the delta
+    store and the mesh executor.  What calls it is a consumer given bare
+    tables: the engine path, tests, and a :class:`ChunkView` (its token)."""
     key = rootdir_cache_key(getattr(table, "rootdir", None))
     if key is not None:
         return key + (int(table.nrows),)

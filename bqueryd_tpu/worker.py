@@ -946,6 +946,20 @@ class WorkerNode(WorkerBase):
         self._table_cache = {}
         self._stats_collector = None
         self._warmup_thread = None
+        # who read a table's identity off the filesystem (a stat of
+        # meta.json and a realpath): the open, once per shard per unit, or
+        # a consumer that was handed none and asked again
+        self._identity_passes = {
+            source: self.metrics.counter(
+                "bqueryd_tpu_table_identity_total",
+                "filesystem identity passes per table, by who made them: "
+                "open = the unit's open (handed down to every cache key "
+                "of the unit); recomputed = a consumer given no identity "
+                "(0 in a served unit but for chunk-pruned views)",
+                labels={"source": source},
+            )
+            for source in ("open", "recomputed")
+        }
         # device-health gauges: read-only snapshots (never launch a probe
         # from a metrics scrape) — operators see the wedge latch and its
         # probe debt wherever they already scrape worker metrics
@@ -1260,7 +1274,10 @@ class WorkerNode(WorkerBase):
 
             # memory_limit_mb: what _check_mem holds this process to
             self._mesh_executor = MeshQueryExecutor(
-                host_limit_bytes=self.memory_limit_mb * 10**6
+                host_limit_bytes=self.memory_limit_mb * 10**6,
+                on_identity_recomputed=self._identity_passes[
+                    "recomputed"
+                ].inc,
             )
         return self._mesh_executor
 
@@ -1269,8 +1286,10 @@ class WorkerNode(WorkerBase):
         """Serialized-result cache keyed by (table identities, query
         signature).  Table identity includes the shard's meta.json mtime, so
         activation of new data invalidates naturally — a repeated query on
-        unchanged shards costs one dict lookup, no kernel dispatch.  Bounded
-        by BQUERYD_TPU_RESULT_CACHE_BYTES (0 disables)."""
+        unchanged shards costs one dict lookup, no kernel dispatch.  The
+        identities are the ones the unit's open read (``_open_unit``), not
+        a second look at the filesystem.  Bounded by
+        BQUERYD_TPU_RESULT_CACHE_BYTES (0 disables)."""
         if self._result_cache is None:
             from bqueryd_tpu.utils.cache import BytesCappedCache
 
@@ -1317,23 +1336,33 @@ class WorkerNode(WorkerBase):
             and all(op in ops.MERGEABLE_OPS for op in query.ops)
         )
 
-    def _delta_key(self, tables, query):
+    @staticmethod
+    def _delta_key(identities, query):
+        """The delta store files a shard group by where it lives, not by
+        what its meta.json reads (an append must FIND the entry): the
+        realpath of each rootdir, which is the first element of the
+        identity the open read."""
         return (
-            tuple(os.path.realpath(t.rootdir) for t in tables),
+            # (a table with no stat-able meta.json has a token for an
+            # identity: filed under it, never found again)
+            tuple(
+                ident[0] if isinstance(ident, tuple) else ident
+                for ident in identities
+            ),
             query.signature(),
         )
 
-    def _serve_delta(self, cache, tables, query, timer):
+    def _serve_delta(self, cache, key, tables, query, timer):
         """Serve a grown shard group from the delta cache: aggregate ONLY
         the appended chunks of each grown table through the ordinary
         engine path and merge the tail partials into the cached payload.
-        Returns the refreshed serialized payload, or None (no entry /
-        not an append-only growth — the caller recomputes)."""
+        ``key`` is the unit's ``_delta_key``.  Returns the refreshed
+        serialized payload, or None (no entry / not an append-only growth
+        — the caller recomputes)."""
         from bqueryd_tpu.models.query import ResultPayload
         from bqueryd_tpu.parallel import hostmerge
 
         with tracing.detail("cache_probe", timer):
-            key = self._delta_key(tables, query)
             entry = cache.get(key)
         if entry is None:
             return None
@@ -1476,7 +1505,7 @@ class WorkerNode(WorkerBase):
         rootdir = os.path.join(self.data_dir, filename)
         if not os.path.exists(rootdir):
             raise ValueError(f"Path {rootdir} does not exist")
-        table = self._open_table(rootdir)
+        table, identity = self._open_identified(rootdir)
         dag = None
         if msg.get("dag"):
             dag = dagmod.OperatorDAG.from_wire(msg.get_from_binary("dag"))
@@ -1521,7 +1550,9 @@ class WorkerNode(WorkerBase):
                 self.engine.timer = timer
                 payload = self.engine.execute_local(table, query)
             else:
-                payload = self._execute_dag([table], dag, timer)
+                payload = self._execute_dag(
+                    [table], dag, timer, (identity,)
+                )
             with tracing.trace_span("serialize"), timer.phase("serialize"):
                 data = payload.to_bytes()
         self.flight.record(
@@ -1540,21 +1571,27 @@ class WorkerNode(WorkerBase):
         reply.add_as_binary("rollup_zones", self._rollup_census(table))
         return reply
 
-    def _execute(self, tables, query, timer):
+    def _execute(self, tables, query, timer, identities=None):
         """Psum-mergeable aggregations (any shard count) -> mesh executor
         (on-device merge + HBM-resident caches); distinct-count / raw-rows
         single shard -> single-device engine; other multi-shard shapes ->
         per-shard engine + host value-keyed merge.  Always returns ONE
         payload per CalcMessage.  The kernel route is the kernel
         dispatcher's (``ops.groupby.kernel_route``); what it took is kept
-        in ``_last_effective_strategy`` for the reply."""
+        in ``_last_effective_strategy`` for the reply.  ``identities``:
+        what the unit's open read of each table (``_open_identified``),
+        handed on to the mesh executor; None (bare tables) leaves the
+        executor to ask the filesystem itself."""
         from bqueryd_tpu.models.query import (
             _host_ns_estimate,
             host_kernel_rows,
         )
         from bqueryd_tpu import ops as ops_mod
         from bqueryd_tpu.parallel import hostmerge
-        from bqueryd_tpu.parallel.executor import MeshQueryExecutor
+        from bqueryd_tpu.parallel.executor import (
+            MeshQueryExecutor,
+            view_identities,
+        )
 
         # what the kernel actually ran, for the reply envelope / kernel span
         self._last_effective_strategy = None
@@ -1583,7 +1620,12 @@ class WorkerNode(WorkerBase):
                 decoded = sum(p[1] for p in pruned)
                 skipped = sum(p[2] for p in pruned)
                 if decoded or skipped:
-                    tables = [p[0] for p in pruned]
+                    views = [p[0] for p in pruned]
+                    identities = view_identities(
+                        identities, tables, views,
+                        self._identity_passes["recomputed"].inc,
+                    )
+                    tables = views
                     self.chunks_decoded_total.inc(decoded)
                     self.chunks_skipped_total.inc(skipped)
                     self._last_chunk_prune = (decoded, skipped)
@@ -1614,7 +1656,9 @@ class WorkerNode(WorkerBase):
             import jax
 
             try:
-                result = self.mesh_executor.execute(tables, query)
+                result = self.mesh_executor.execute(
+                    tables, query, identities=identities
+                )
                 self._last_effective_strategy = (
                     self.mesh_executor.last_effective_strategy
                 )
@@ -1674,7 +1718,7 @@ class WorkerNode(WorkerBase):
 
         return ResultPayload(merged)
 
-    def _execute_dag(self, tables, dag, timer):
+    def _execute_dag(self, tables, dag, timer, identities=None):
         """Extended operator-DAG execution (joins / top-k / quantile
         sketches / window rollups).  Device-mergeable shapes (classic +
         top-k + sketch part kinds) take the MESH FAST PATH: one
@@ -1688,7 +1732,8 @@ class WorkerNode(WorkerBase):
         a failed device program — falls back to the PR-13 per-shard
         operator pipelines on the stage pool with the host value-keyed
         merge.  Plain DAGs never reach here (handle_work routes them
-        through ``_execute`` bit-identically)."""
+        through ``_execute`` bit-identically).  ``identities`` as in
+        ``_execute``."""
         from bqueryd_tpu.models.query import host_kernel_rows
         from bqueryd_tpu.parallel.opexec import DagExecutor
         from bqueryd_tpu.plan import dag as dagmod
@@ -1707,7 +1752,9 @@ class WorkerNode(WorkerBase):
 
             self.mesh_executor.timer = timer
             try:
-                payload = self.mesh_executor.execute_dag(tables, dag)
+                payload = self.mesh_executor.execute_dag(
+                    tables, dag, identities=identities
+                )
                 self._last_effective_strategy = (
                     self.mesh_executor.last_effective_strategy
                 )
@@ -1752,25 +1799,58 @@ class WorkerNode(WorkerBase):
             self.chunks_skipped_total.inc(skipped)
             self._last_chunk_prune = (decoded, skipped)
 
-    def _open_table(self, rootdir):
-        """Table instances cached by meta identity: re-opening per query
-        costs a meta.json parse per shard; activation (fresh inode/mtime)
-        misses naturally.  Instances are read-only and light — column bytes
-        live in the storage module's global cache, not per instance."""
+    def _open_identified(self, rootdir):
+        """``(table, identity)``.  Table instances are cached by meta
+        identity: re-opening per query costs a meta.json parse per shard;
+        activation (fresh inode/mtime) misses naturally.  Instances are
+        read-only and light — column bytes live in the storage module's
+        global cache, not per instance.
+
+        The ``rootdir_cache_key`` that validates the cached instance is the
+        unit's ONE look at the filesystem for this shard, and the identity
+        is made from it: ``key + (nrows,)``, letter for letter what
+        ``table_cache_key(table)`` would return, so every cache keyed by it
+        keeps its entries.  The caller hands it down with the table (result
+        cache, delta store, mesh executor).  It is NOT kept on the instance
+        or anywhere that outlives the unit: the stat per unit is what makes
+        an activation, a movebcolz or an append miss."""
         from bqueryd_tpu.storage import ctable
-        from bqueryd_tpu.storage.ctable import rootdir_cache_key
+        from bqueryd_tpu.storage.ctable import (
+            rootdir_cache_key,
+            table_cache_key,
+        )
 
         key = rootdir_cache_key(rootdir)
-        if key is not None:
-            hit = self._table_cache.get(key)
-            if hit is not None:
-                return hit
-        table = ctable(rootdir, mode="r", auto_cache=True)
-        if key is not None:
+        self._identity_passes["open"].inc()
+        if key is None:
+            # no stat-able meta.json: nothing to cache the instance by
+            table = ctable(rootdir, mode="r", auto_cache=True)
+            return table, table_cache_key(table)
+        table = self._table_cache.get(key)
+        if table is None:
+            table = ctable(rootdir, mode="r", auto_cache=True)
             if len(self._table_cache) > 512:
                 self._table_cache.clear()
             self._table_cache[key] = table
-        return table
+        return table, key + (int(table.nrows),)
+
+    def _open_table(self, rootdir):
+        """The table alone, for a caller that files nothing under its
+        identity (the stats collector)."""
+        return self._open_identified(rootdir)[0]
+
+    def _open_unit(self, filenames):
+        """``(tables, identities)`` of a unit's shard files, in file
+        order: each opened, and its identity read, once."""
+        tables, identities = [], []
+        for name in filenames:
+            rootdir = os.path.join(self.data_dir, name)
+            if not os.path.exists(rootdir):
+                raise ValueError(f"Path {rootdir} does not exist")
+            table, identity = self._open_identified(rootdir)
+            tables.append(table)
+            identities.append(identity)
+        return tables, tuple(identities)
 
     def handle_work(self, msg):
         if msg.isa("execute_code"):
@@ -1851,22 +1931,15 @@ class WorkerNode(WorkerBase):
                 dag = dagmod.dag_from_query(query)
                 query = dag.plain_groupby_query()
             filenames = filename if isinstance(filename, list) else [filename]
-        tables = []
         with tracing.trace_span("open"), timer.phase("open"):
-            for name in filenames:
-                rootdir = os.path.join(self.data_dir, name)
-                if not os.path.exists(rootdir):
-                    raise ValueError(f"Path {rootdir} does not exist")
-                tables.append(self._open_table(rootdir))
+            tables, identities = self._open_unit(filenames)
         with tracing.detail("cache_probe", timer):
             cache = self.result_cache
             cache_key = None
             data = None
             if cache is not None:
-                from bqueryd_tpu.parallel.executor import _table_key
-
                 cache_key = (
-                    tuple(_table_key(t) for t in tables),
+                    identities,
                     # extended DAGs have no GroupByQuery form; their identity
                     # is the DAG signature (join table / window / sketch
                     # params included).  Plain shapes keep the historical
@@ -1891,10 +1964,12 @@ class WorkerNode(WorkerBase):
             if query is not None and self._delta_eligible(query):
                 delta_cache = self.delta_cache()
                 if delta_cache is not None:
-                    delta_key = self._delta_key(tables, query)
+                    delta_key = self._delta_key(identities, query)
         if data is None and delta_cache is not None:
             self._last_merge_mode = None
-            data = self._serve_delta(delta_cache, tables, query, timer)
+            data = self._serve_delta(
+                delta_cache, delta_key, tables, query, timer
+            )
             if data is not None:
                 effective = "delta"
                 if cache is not None and len(data) <= cache.max_bytes // 8:
@@ -1909,9 +1984,9 @@ class WorkerNode(WorkerBase):
             if query is not None:
                 # plain shape: the unchanged engine/mesh path —
                 # bit-identical to the pre-DAG hardwired sequence
-                payload = self._execute(tables, query, timer)
+                payload = self._execute(tables, query, timer, identities)
             else:
-                payload = self._execute_dag(tables, dag, timer)
+                payload = self._execute_dag(tables, dag, timer, identities)
             effective = getattr(self, "_last_effective_strategy", None)
             merge_mode = getattr(self, "_last_merge_mode", None)
             # detail: the form the mesh executor's float64 sums took (dense
@@ -2118,7 +2193,6 @@ class WorkerNode(WorkerBase):
 
         from bqueryd_tpu import chaos, obs
         from bqueryd_tpu.obs import profile as obs_profile
-        from bqueryd_tpu.parallel.executor import _table_key
         from bqueryd_tpu.plan import bundle as bundlemod
 
         recorder = None
@@ -2137,17 +2211,11 @@ class WorkerNode(WorkerBase):
             members = bundlemod.bundle_to_queries(fragment)
             filename = msg.get("filename") or fragment.get("filenames")
             filenames = filename if isinstance(filename, list) else [filename]
-        tables = []
         with tracing.trace_span("open"), timer.phase("open"):
-            for name in filenames:
-                rootdir = os.path.join(self.data_dir, name)
-                if not os.path.exists(rootdir):
-                    raise ValueError(f"Path {rootdir} does not exist")
-                tables.append(self._open_table(rootdir))
+            tables, tables_sig = self._open_unit(filenames)
 
         with tracing.detail("cache_probe", timer):
             cache = self.result_cache
-            tables_sig = tuple(_table_key(t) for t in tables)
             payloads = {}      # member_id -> serialized ResultPayload bytes
             errors = {}        # member_id -> failure text (member-only abort)
             active = []        # (member_id, query) still needing execution
@@ -2184,7 +2252,7 @@ class WorkerNode(WorkerBase):
 
                 try:
                     mesh_payloads = self.mesh_executor_for_bundle(
-                        tables, queries, timer
+                        tables, queries, timer, tables_sig
                     )
                 except chaos.TransientError:
                     # a transient device fault fails the whole bundle over
@@ -2217,7 +2285,7 @@ class WorkerNode(WorkerBase):
                     try:
                         exec_clock = time.perf_counter()
                         results[member_id] = self._execute(
-                            tables, query, timer
+                            tables, query, timer, tables_sig
                         )
                         member_walls[member_id] = (
                             time.perf_counter() - exec_clock
@@ -2304,13 +2372,16 @@ class WorkerNode(WorkerBase):
         )
         return reply
 
-    def mesh_executor_for_bundle(self, tables, queries, timer):
+    def mesh_executor_for_bundle(self, tables, queries, timer,
+                                 identities=None):
         """Run the shared-scan mesh path for a bundle (seam kept separate
         so tests can spy on it): returns per-member ResultPayloads."""
         self._last_effective_strategy = None
         self._last_merge_mode = None
         self.mesh_executor.timer = timer
-        payloads = self.mesh_executor.execute_bundle(tables, queries)
+        payloads = self.mesh_executor.execute_bundle(
+            tables, queries, identities=identities
+        )
         self._last_effective_strategy = (
             self.mesh_executor.last_effective_strategy
         )

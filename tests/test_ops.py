@@ -1148,6 +1148,10 @@ def test_worker_degrades_mesh_overflow_to_engine(tmp_path, caplog):
     worker._mesh_executor = None
     worker._result_cache = None
     worker.memory_limit_mb = 2048   # what bounds the executor's align segment
+    from bqueryd_tpu.obs.metrics import Counter
+
+    # bare tables: the executor reads their identities itself, and says so
+    worker._identity_passes = {"recomputed": Counter("recomputed", "")}
     import logging as _logging
 
     worker.logger = _logging.getLogger("test-overflow")
@@ -1212,7 +1216,7 @@ def test_worker_degrades_mesh_runtime_error_to_engine(tmp_path, caplog):
     class _FailingMesh:
         timer = None
 
-        def execute(self, tables, query, strategy=None):
+        def execute(self, tables, query, strategy=None, identities=None):
             raise jax.errors.JaxRuntimeError(
                 "INTERNAL: Mosaic failed to compile TPU kernel"
             )
