@@ -380,9 +380,13 @@ SPAN_SCHEMA = {
                       "device's time as the host waits for it; tagged "
                       "effective_strategy and, where the mesh executor "
                       "summed in float64 on an accelerator, float_sum "
-                      "(dense | sorted); for a solo or DAG unit also "
+                      "(dense | segmented); for a solo or DAG unit also "
                       "merge_mode (device | host, the reply's own) and "
                       "devices (the mesh's size)",
+    "float_sum_wait": "detail span in aggregate_wait: the same wait, only "
+                      "for a launch whose float64 sums took a form other "
+                      "than dense (tagged form: segmented) - the device's "
+                      "time is then mostly the float sum's",
     "send": "detail annotation: the reply's send; its seconds ride the "
             "next calc reply's phase_timings['post_prev']",
     "post": "detail annotation: Done + throttled gc.collect() + RSS check "

@@ -88,6 +88,7 @@ SPAN_CATEGORIES = {
     "layout_columns": "layout_columns",
     "aggregate_launch": "aggregate_launch",
     "aggregate_wait": "aggregate_wait",
+    "float_sum_wait": "aggregate_wait",
     # annotation-only detail names (after the reply / the worker's loop):
     # on no timeline, declared for the lint
     "send": "worker_other",

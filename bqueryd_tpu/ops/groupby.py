@@ -30,8 +30,11 @@ Design:
   (and an integer mean, which accumulates in float64 like pandas) rides
   neither: on either route it is :func:`_float64_segment_sum` — on an
   accelerator a dense masked reduction in float64 up to
-  ``_DENSE_SUM_GROUPS`` groups (no sort, no gather, no scatter) and the
-  sort + prefix-diff above — while its ``rows`` and the mean's count take
+  ``_DENSE_SUM_GROUPS`` groups (no sort, no gather, no scatter) and above
+  it a segmented scan over the rows sorted by group code, the value
+  carried through the sort (:func:`_segmented_sums`; the scatter route's
+  own sort where it has one), in both of which a group's sum meets only
+  its own values — while its ``rows`` and the mean's count take
   the route's own form, so such a query goes by the MXU route wherever
   that is allowed: the counts are two rows of the one-hot dot.
   A pure-NumPy twin (:func:`host_partial_tables`) serves latency-aware
@@ -142,13 +145,14 @@ _MAX_BLOCK_SEGMENTS = 1 << 25
 
 #: up to this many groups a float64 per-group sum on an accelerator is the
 #: dense masked reduction (:func:`_dense_segment_sum`), whose cost grows with
-#: the group count; above it the sort + prefix-diff, whose cost does not.
-#: OBSERVED, not set by anyone: standalone timings of the two at 11 010 048
-#: rows on a TPU v5e (PERF.md section 6, PR 31) read 2.3 ms at 10 groups,
-#: 18 at 256, 136 at 2 048, 271 at 4 096 and 406 at 6 144 for the dense sum
-#: (0.066 ms a group) against 346-410 for the sorted one at any count: they
-#: cross at about 5 000 groups, and the constant is the last power of two
-#: where the dense form still wins by more than two to one
+#: the group count; above it the sorted rows' segmented scan
+#: (:func:`_segmented_sums`), whose cost does not.
+#: OBSERVED, not set by anyone: standalone timings at 11 010 048 rows on a
+#: TPU v5e (PERF.md section 6, PR 31) read 2.3 ms at 10 groups, 18 at 256,
+#: 136 at 2 048, 271 at 4 096 and 406 at 6 144 for the dense sum (0.066 ms a
+#: group) against 346-410 at any count for the sort + gather + float64
+#: prefix difference that stood above it until PR 38 (which also missed the
+#: 1e-7 a group is promised: PERF.md section 6, PR 38)
 _DENSE_SUM_GROUPS = 2048
 
 
@@ -167,17 +171,18 @@ def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
     wide adds (only the SCATTER is expensive in emulated 64-bit arithmetic),
     and no ``blocks x groups`` table, so cost is independent of ``n_groups``.
     For int64 the wrapping (mod 2^64) prefix sums difference back exactly —
-    bit-exact for the full range; for float64 accumulation the prefix-diff
-    matches direct summation to ~1 ulp of the running prefix.
+    bit-exact for the full range; in a float ``acc_dtype`` the prefix-diff
+    matches direct summation only to ~1 ulp of the running prefix.
 
     One sort, one gather of every row and one boundary search PER SUM:
     where a query's integer reductions can share a sort and carry their
-    values through it they take :class:`_SortedGroups` instead.  Callers
-    left: the int64 sums of :func:`_int64_segment_sum` past the
+    values through it they take :class:`_SortedGroups` instead.  The one
+    caller left: the int64 sums of :func:`_int64_segment_sum` past the
     ``blocks x groups`` budget (a CPU backend only: an accelerator never
-    reaches the blocked form under ``auto``), and the float64 sums of
-    :func:`_float64_segment_sum` above ``_DENSE_SUM_GROUPS`` groups on an
-    accelerator or under a binding ``"sort"`` hint."""
+    reaches the blocked form under ``auto``).  No float sum takes it since
+    PR 38: the difference of a running prefix of the whole table carries
+    one rounding of that prefix, which a group of a few cents beside a
+    table of millions cannot absorb (:func:`_segmented_sums` instead)."""
     codes_s, order = lax.sort(
         (safe, jnp.arange(safe.shape[0], dtype=jnp.int32)), num_keys=1
     )
@@ -215,6 +220,7 @@ class _SortedGroups:
         self._n_groups = n_groups
         self._words = []     # 32-bit operands carried through the sort
         self._sorted = None  # (ends, sorted words), once the sort has run
+        self._key_s = None   # the sorted key, for the segmented float sums
 
     def _carry(self, word):
         if self._sorted is not None:
@@ -233,6 +239,7 @@ class _SortedGroups:
                 side="right",
             )
             self._sorted = ends, words_s
+            self._key_s = key_s
         return self._sorted
 
     def _prefix_at_ends(self, word):
@@ -316,6 +323,98 @@ class _SortedGroups:
             return jnp.diff(at, prepend=0)
         return resolve
 
+    def float_total(self, contrib):
+        """Thunk of the per-group float64 sum of ``contrib`` (zero on the
+        rows that do not count for it), carried through the one sort and
+        summed group by group (:func:`_segmented_sums`): no second sort of
+        the rows, no gather of every row, no prefix of the whole table."""
+        slot = self._carry(contrib)
+
+        def resolve():
+            ends, words = self._run()
+            return _segmented_sums(
+                self._key_s, words[slot], ends, self._n_groups
+            )
+        return resolve
+
+
+#: rows per block of the float64 segmented scan (:func:`_segmented_sums`):
+#: ``log2`` of it is the number of passes over every row, and the blocks'
+#: last rows (rows / block of them) are scanned once more.  OBSERVED:
+#: standalone at 11 010 048 rows x 73 728 groups on a TPU v5e (PERF.md
+#: section 6, PR 38) the sort, the boundary search and the scan together
+#: take 47.4 ms a launch at 128, 52.1 at 1 024 and 56.0 at 65 536, of which
+#: the three-operand sort alone is 35.5
+_SEGMENT_BLOCK = 128
+
+
+def _segmented_scan(keys, values):
+    """The inclusive scan of ``values`` along the last axis that starts
+    anew wherever the (sorted) ``keys`` change: at step ``d`` an element
+    takes in the partial sum ``d`` places before it where both have one
+    key — the keys are sorted, so every element between them has it too —
+    and after ``log2`` of the axis' length steps each holds the sum of its
+    own key's elements up to itself.  A balanced tree of adds, each one
+    elementwise pass; a sum never meets a value of another key."""
+    lead = [(0, 0)] * (keys.ndim - 1)
+    zero = jnp.zeros((), values.dtype)
+    d = 1
+    while d < keys.shape[-1]:
+        same = keys[..., d:] == keys[..., :-d]
+        values = values + jnp.pad(
+            jnp.where(same, values[..., :-d], zero), lead + [(d, 0)]
+        )
+        d *= 2
+    return values
+
+
+def _segmented_sums(key_s, v_s, ends, n_groups, block=None):
+    """float[n_groups]: per-group sums of ``v_s``, rows already sorted by
+    group key ``key_s`` with ``ends[g]`` one past group ``g``'s last row, in
+    which a group's sum only ever meets its own values — no running prefix
+    of the table to difference, whose one rounding (of up to the table's
+    total) a small group's sum cannot absorb.
+
+    :func:`_segmented_scan` inside ``block``-row blocks, so each row holds
+    the sum of its group's rows from the group's first row in the block up
+    to itself, with an error that grows with the log of a group's rows.  A
+    group's total is the value at its last row plus, where the group
+    started in an earlier block, the same scan over the blocks' last rows.
+    No 64-bit ``cumsum`` or ``associative_scan`` at row scale (PERF.md
+    section 7: one crashed the TPU compiler, one never returned), no
+    gather but the ``n_groups`` reads at the boundaries."""
+    block = _SEGMENT_BLOCK if block is None else block
+    n = key_s.shape[0]
+    n_blocks = -(-n // block)
+    pad = n_blocks * block - n
+    # pad rows take a key no group has and add nothing
+    k = jnp.pad(key_s, (0, pad), constant_values=n_groups).reshape(
+        n_blocks, block
+    )
+    v = _segmented_scan(k, jnp.pad(v_s, (0, pad)).reshape(n_blocks, block))
+    # the blocks' last rows: the part of the block's last group that lies
+    # in the block, scanned the same way over the few blocks
+    tail_key = k[:, -1]
+    tail = _segmented_scan(tail_key, v[:, -1])
+    last = jnp.maximum(ends - 1, 0)  # a group's last row, if it has one
+    before = jnp.maximum(last // block - 1, 0)  # the block before its block
+    groups = jnp.arange(n_groups, dtype=key_s.dtype)
+    zero = jnp.zeros((), v.dtype)
+    carried = jnp.where(
+        (last >= block) & (tail_key[before] == groups), tail[before], zero
+    )
+    present = jnp.diff(ends, prepend=0) > 0
+    return jnp.where(present, v.reshape(-1)[last] + carried, zero)
+
+
+def _resolved(partials):
+    """The partial tables with every :class:`_SortedGroups` thunk called:
+    the one sort can only run once every contribution is registered, so
+    the sorted reductions are values only at the end of a route."""
+    return jax.tree_util.tree_map(
+        lambda leaf: leaf() if callable(leaf) else leaf, partials
+    )
+
 
 def _int_sums_sort(n, n_groups):
     """Whether the scatter route's integer counts and sums take a sorted
@@ -364,31 +463,37 @@ def _dense_segment_sum(contrib, safe, n_groups):
 
 def _float_sum_form(n_groups, force_sort=False):
     """Which reduction a float64 per-group sum takes, from what the trace
-    can observe (the backend and the group count, both static): ``"sorted"``
-    under a binding sort hint and above ``_DENSE_SUM_GROUPS`` groups on an
-    accelerator, ``"dense"`` up to it, None on a CPU backend (native
-    float64: the plain scatter-add is the cheap one there)."""
+    can observe (the backend and the group count, both static):
+    ``"segmented"`` under a binding sort hint and above
+    ``_DENSE_SUM_GROUPS`` groups on an accelerator, ``"dense"`` up to it,
+    None on a CPU backend (native float64: the plain scatter-add is the
+    cheap one there).  In all three a group's sum meets only its own
+    values, so a group of one row of cents is as exact beside a table of
+    millions as alone."""
     if force_sort:
-        return "sorted"
+        return "segmented"
     if jax.default_backend() == "cpu":
         return None
-    return "dense" if n_groups <= _DENSE_SUM_GROUPS else "sorted"
+    return "dense" if n_groups <= _DENSE_SUM_GROUPS else "segmented"
 
 
-def _float64_segment_sum(contrib, safe, n_groups, force_sort=False):
+def _float64_segment_sum(contrib, safe, n_groups, sorted_groups,
+                         force_sort=False):
     """The per-group sum of a float64 ``contrib`` for BOTH kernel routes
     (the MXU route's ``f64_scatter`` plan and the scatter route's float
-    branch), so a binding hint changes the counts' route and not the sum."""
+    branch), so a binding hint changes the counts' route and not the sum.
+    ``sorted_groups()`` gives the query's :class:`_SortedGroups` — the
+    route's own where its integer reductions sort, one made for the float
+    sums where they do not — and is called only by the segmented form,
+    which then returns a thunk (the one sort runs once every contribution
+    is registered); the other forms return the table."""
     form = _float_sum_form(n_groups, force_sort)
     if form == "dense":
         return _dense_segment_sum(contrib, safe, n_groups)
-    if form == "sorted":
+    if form == "segmented":
         # no native f64 on TPU: an emulated-f64 scatter is the wide-scatter
-        # cost this module exists to avoid; the sort + prefix-diff uses only
-        # cheap elementwise wide adds
-        return _sorted_segment_sum(
-            contrib, safe, n_groups, acc_dtype=contrib.dtype
-        )
+        # cost this module exists to avoid
+        return sorted_groups().float_total(contrib)
     return jax.ops.segment_sum(contrib, safe, num_segments=n_groups)
 
 
@@ -900,7 +1005,7 @@ def kernel_route(strategy, measures, ops, n, n_groups):
 
 def float_sum_route(strategy, measures, ops, n, n_groups):
     """Which form the float64-accumulated sums of this dispatch take —
-    ``"dense"`` or ``"sorted"`` (:func:`_float_sum_form`) — or None where it
+    ``"dense"`` or ``"segmented"`` (:func:`_float_sum_form`) — or None where it
     has none or the backend scatter-adds float64 natively.  The host-side
     twin of the kernels' trace-time choice, like :func:`kernel_route` (same
     arguments): the ``float_sum`` tag of the ``aggregate_wait`` detail span
@@ -1157,6 +1262,13 @@ def _partial_tables_mm(codes, measures, ops, n_groups, mask=None,
     rows_count = int_row(valid_count_row).astype(jnp.int64)
     safe = jnp.where(valid, codes, 0).astype(jnp.int32)
 
+    @functools.cache
+    def sorted_groups():
+        # the counts are rows of the dot: only a float64 sum above
+        # _DENSE_SUM_GROUPS groups sorts here, all such sums of a query in
+        # one sort
+        return _SortedGroups(valid, codes, n_groups)
+
     aggs = []
     for plan in plans:
         kind, op = plan[0], plan[1]
@@ -1186,7 +1298,11 @@ def _partial_tables_mm(codes, measures, ops, n_groups, mask=None,
             _, _, values, present_row = plan
             present = valid & ~_null_mask(values)
             contrib = jnp.where(present, values, 0).astype(jnp.float64)
-            partial = {"sum": _float64_segment_sum(contrib, safe, n_groups)}
+            partial = {
+                "sum": _float64_segment_sum(
+                    contrib, safe, n_groups, sorted_groups
+                )
+            }
             if op == "mean":
                 partial["count"] = int_row(present_row).astype(jnp.int64)
             aggs.append(partial)
@@ -1202,7 +1318,7 @@ def _partial_tables_mm(codes, measures, ops, n_groups, mask=None,
             aggs.append(
                 {kind: ext, "count": int_row(present_row).astype(jnp.int64)}
             )
-    return {"rows": rows_count, "aggs": tuple(aggs)}
+    return _resolved({"rows": rows_count, "aggs": tuple(aggs)})
 
 
 _partial_tables_mm = _obsprofile.instrument(
@@ -1223,8 +1339,8 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
     identical partials either way.  ``force_sort`` is None for every served
     query (the form follows the backend, read at trace time), True under
     the binding "sort" hint (the sorted form on any backend, float64 sums
-    sorted too) and False under the binding "scatter" hint (the blocked
-    scatters on any backend)."""
+    segmented over the same sort) and False under the binding "scatter"
+    hint (the blocked scatters on any backend)."""
     valid = codes >= 0
     if mask is not None:
         valid = valid & mask
@@ -1260,6 +1376,13 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
 
         rows = int_count(valid)
 
+    @functools.cache
+    def sorted_groups():
+        # a segmented float64 sum rides the integer reductions' one sort;
+        # where they scatter (a binding "scatter" hint on an accelerator)
+        # the float sums share a sort of their own
+        return by_sort if sort_ints else _SortedGroups(valid, codes, n_groups)
+
     sentinels = _normalize_sentinels(null_sentinels, len(measures))
     aggs = []
     for values, op, sentinel in zip(measures, ops, sentinels):
@@ -1287,17 +1410,16 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
                     values.dtype if floating else jnp.float64
                 )
                 contrib = jnp.where(present, values, 0).astype(acc)
-                # the sort+prefix-diff reduction differences near-equal
-                # large prefixes, so it requires the float64 accumulator
-                # (the x64 default here); a float32 accumulator (x64 off)
-                # stays on the scatter even under a binding "sort" hint —
-                # catastrophic cancellation is worse than the hint miss
+                # the forms of _float64_segment_sum are for the float64
+                # accumulator (the x64 default here); a float32 accumulator
+                # (x64 off) stays on the plain scatter-add whatever the hint
                 if contrib.dtype == jnp.float64:
                     # backend and group count read at trace time, outside
                     # data flow
                     partial = {
                         "sum": _float64_segment_sum(
-                            contrib, safe, n_groups, force_sort=sort_floats
+                            contrib, safe, n_groups, sorted_groups,
+                            force_sort=sort_floats,
                         )
                     }
                 else:
@@ -1324,10 +1446,7 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
                     "count": present_count(),
                 }
             )
-    return jax.tree_util.tree_map(
-        lambda leaf: leaf() if callable(leaf) else leaf,
-        {"rows": rows, "aggs": tuple(aggs)},
-    )
+    return _resolved({"rows": rows, "aggs": tuple(aggs)})
 
 
 _partial_tables_scatter = _obsprofile.instrument(

@@ -280,7 +280,8 @@ class MeshQueryExecutor:
         self.last_effective_strategy = None
         #: detail (BQUERYD_TPU_PROFILE=1 only, else None): the form the
         #: float64 sums of the last execute() took, ops.float_sum_route —
-        #: the worker tags the ``aggregate_wait`` span ``float_sum`` with it
+        #: the worker tags the ``aggregate_wait`` span ``float_sum`` with
+        #: it, and a form other than dense gets the span ``float_sum_wait``
         self.last_float_sum = None
         #: how the last execute() merged partials across the mesh
         #: ("device" | "host") — the worker surfaces it as the reply
@@ -981,6 +982,7 @@ class MeshQueryExecutor:
                         measure_index=measure_index,
                         merge_mode=merge_mode,
                         timer=self.timer,
+                        float_form=self.last_float_sum,
                     )
                     break
                 except jax.errors.JaxRuntimeError as exc:
@@ -2257,7 +2259,8 @@ def _mesh_bundle_program(mesh, axis, n_groups, in_dtypes, in_width, pack,
     ), spec
 
 
-def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what):
+def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what,
+                  float_form=None):
     """The ONE packed-fetch scaffold shared by the three mesh fetch paths
     (:func:`_mesh_partials`, :func:`_mesh_bundle_partials`,
     :func:`_mesh_dag_partials`): run the packed program and fetch one byte
@@ -2274,7 +2277,10 @@ def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what):
     solo path owning the packed-broken diagnosis.  ``run(pack_flag)``
     returns ``(program, spec)``; ``call(program)`` invokes it with the
     caller's argument tuple; ``finish(merged, fetched_bytes)`` is the
-    caller's layout normalization + merge-byte accounting."""
+    caller's layout normalization + merge-byte accounting.  ``float_form``
+    (``ops.float_sum_route``, under the profile switch only) names the form
+    the launch's float64 sums took: other than ``dense`` it gets the detail
+    span ``float_sum_wait`` inside ``aggregate_wait``."""
     global _packed_fetch_broken, _packed_transient_count
     import jax
 
@@ -2289,7 +2295,8 @@ def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what):
                 with tracing.detail("aggregate_launch", timer):
                     out = call(program)
                 with tracing.detail("aggregate_wait", timer):
-                    jax.block_until_ready(out)
+                    with _float_sum_wait(float_form, timer):
+                        jax.block_until_ready(out)
                 with _fetch_phase(timer):
                     flat = np.asarray(jax.device_get(out))
         except Exception as exc:
@@ -2700,6 +2707,16 @@ def _record_merge_bytes(merge_mode, fetched, n_dev, n_groups, merged):
     )
 
 
+def _float_sum_wait(form, timer):
+    """The detail span ``float_sum_wait`` round the wait for a launch whose
+    float64 sums took a form other than ``dense`` (the forms whose cost
+    does not shrink with the group count: the device's time is then mostly
+    theirs); no span where the launch summed no float64 or densely."""
+    if form in (None, "dense"):
+        return contextlib.nullcontext()
+    return tracing.detail("float_sum_wait", timer, form=form)
+
+
 @contextlib.contextmanager
 def _fetch_phase(timer):
     """The D2H fetch timed as its own phase ("fetch" -> span "d2h_fetch"):
@@ -2722,7 +2739,7 @@ def _fetch_phase(timer):
 
 def _mesh_partials(mesh, axis, agg_ops, n_groups, codes_d, measures_d,
                    null_sentinels=None, strategy=None, measure_index=None,
-                   merge_mode="psum", timer=None):
+                   merge_mode="psum", timer=None, float_form=None):
     """Run the mesh program and return the merged partials pytree ON HOST
     (numpy leaves) — fetching one packed buffer when packing is enabled.
     ``measures_d`` holds one device block per DISTINCT measure column;
@@ -2781,4 +2798,5 @@ def _mesh_partials(mesh, axis, agg_ops, n_groups, codes_d, measures_d,
     return _fetch_merged(
         run, lambda program: program(codes_d, *measures_d), merge_mode,
         n_dev, finish, timer, latch=True, what="query",
+        float_form=float_form,
     )

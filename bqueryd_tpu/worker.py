@@ -1990,7 +1990,7 @@ class WorkerNode(WorkerBase):
             effective = getattr(self, "_last_effective_strategy", None)
             merge_mode = getattr(self, "_last_merge_mode", None)
             # detail: the form the mesh executor's float64 sums took (dense
-            # / sorted), which it reports under the profile switch only —
+            # / segmented), which it reports under the profile switch only —
             # where the aggregate_wait span that carries it exists
             float_sum = (
                 getattr(self, "_last_float_sum", None)
@@ -2001,6 +2001,8 @@ class WorkerNode(WorkerBase):
                 # compiled post-guards — rpc.trace() waterfalls can now
                 # tell a promoted matmul from a silently-normalized hint
                 for span in recorder.spans:
+                    if span.get("name") == "float_sum_wait" and float_sum:
+                        span.setdefault("tags", {})["form"] = float_sum
                     if span.get("name") in ("kernel", "aggregate_wait"):
                         tags = span.setdefault("tags", {})
                         tags["effective_strategy"] = effective

@@ -1630,16 +1630,18 @@ def test_integer_mean_takes_the_dense_sum(groupby_as_accelerator, strategy):
     [
         pytest.param(10, None, "dense", id="few_groups"),
         pytest.param("C", None, "dense", id="at_the_constant"),
-        pytest.param("C+1", None, "sorted", id="one_past_the_constant"),
-        pytest.param("C+1", "scatter", "sorted", id="one_past_by_scatter"),
-        pytest.param(10, "sort", "sorted", id="binding_sort_hint"),
+        pytest.param("C+1", None, "segmented", id="one_past_the_constant"),
+        pytest.param("C+1", "scatter", "segmented", id="one_past_by_scatter"),
+        pytest.param(10, "sort", "segmented", id="binding_sort_hint"),
     ],
 )
 def test_the_group_count_decides_the_form_of_the_float64_sum(
         groupby_as_accelerator, n_groups, strategy, form):
-    """Dense up to ``_DENSE_SUM_GROUPS`` groups, the sort + prefix-diff
-    above and under its binding hint: what the kernels trace is what
-    ``float_sum_route`` says from the host side, and both are right."""
+    """Dense up to ``_DENSE_SUM_GROUPS`` groups, the sorted rows' segmented
+    scan above and under the binding sort hint (PR 38; never the prefix
+    difference of the whole table, whatever the hint): what the kernels
+    trace is what ``float_sum_route`` says from the host side, and both are
+    right."""
     import unittest.mock as mock
 
     import jax
@@ -1655,16 +1657,19 @@ def test_the_group_count_decides_the_form_of_the_float64_sum(
     with mock.patch.object(
         m, "_dense_segment_sum", wraps=m._dense_segment_sum
     ) as dense, mock.patch.object(
+        m, "_segmented_sums", wraps=m._segmented_sums
+    ) as segmented, mock.patch.object(
         m, "_sorted_segment_sum", wraps=m._sorted_segment_sum
-    ) as by_sort:
+    ) as prefix_diff:
         out = jax.device_get(m.partial_tables(
             codes, (values,), ("sum",), n_groups, strategy=strategy))
-    # (a binding sort hint sorts the row count too, so no call counts)
-    assert (dense.called, by_sort.called) == (form == "dense", form == "sorted")
+    assert (dense.called, segmented.called) == (
+        form == "dense", form == "segmented")
+    assert not prefix_diff.called
     expect = np.zeros(n_groups)
     np.add.at(expect, codes, values)
     np.testing.assert_allclose(
-        np.asarray(out["aggs"][0]["sum"]), expect, rtol=1e-9)
+        np.asarray(out["aggs"][0]["sum"]), expect, rtol=1e-12)
 
 
 # -- the route of a float64 mean ---------------------------------------------

@@ -94,6 +94,8 @@ def node(tmp_path_factory):
             "v": rng.integers(0, 100, n).astype(np.int64),
             "u": rng.integers(0, 100, n).astype(np.int64),
             "w": rng.random(n) * 10,
+            # past _DENSE_SUM_GROUPS groups: a float64 sum goes segmented
+            "k3000": rng.integers(0, 3000, n).astype(np.int64),
             # one measure column per test that needs one no query has read
             **{f"cold_{kind}": rng.integers(0, 100, n).astype(np.int64)
                for kind in ("solo", "bundle", "dag", "loop")},
@@ -304,6 +306,49 @@ def test_the_float_sum_tag_and_counter_exist_under_the_switch_only(
         assert counted() == before
     kernel = next(s for s in reply["spans"] if s["name"] == "kernel")
     assert "float_sum" not in kernel.get("tags", {})
+
+
+@pytest.mark.parametrize("profile", ["1", None], ids=["traced", "untraced"])
+def test_a_segmented_float_sum_gets_a_wait_span_of_its_own(
+        node, groupby_as_accelerator, monkeypatch, profile):
+    """PR 38: above ``_DENSE_SUM_GROUPS`` groups a float64 sum on an
+    accelerator takes the segmented form, and the wait for that launch is
+    also the detail span ``float_sum_wait`` — inside ``aggregate_wait``,
+    tagged ``form``, under the switch only; the ``float_sum`` tag and the
+    counter carry the form's name.  A dense or integer sum has no such
+    span."""
+    if profile:
+        monkeypatch.setenv("BQUERYD_TPU_PROFILE", profile)
+    else:
+        monkeypatch.delenv("BQUERYD_TPU_PROFILE", raising=False)
+    worker = node["worker"]
+
+    def counted(form):
+        return sum(
+            m.value for m in worker.metrics.metrics()
+            if m.name == "bqueryd_tpu_float_sum_total" and m.labels == {"form": form}
+        )
+
+    node["run"]("solo", measure="w", key="k3000")   # compiles here
+    before = counted("segmented")
+    reply = node["run"]("solo", measure="w", key="k3000")
+    assert reply["effective_strategy"] == "matmul"
+    assert set(reply) == REPLY_KEYS["solo"]
+    spans = {name: [s for s in reply["spans"] if s["name"] == name]
+             for name in ("aggregate_wait", "float_sum_wait")}
+    if profile:
+        (outer,), (inner,) = spans["aggregate_wait"], spans["float_sum_wait"]
+        assert inner["tags"] == {"form": "segmented"}
+        assert outer["tags"]["float_sum"] == "segmented"
+        assert interval(outer)[0] - SLACK_S <= interval(inner)[0]
+        assert interval(inner)[1] <= interval(outer)[1] + SLACK_S
+        assert counted("segmented") == before + 1
+        for measure, key in (("w", "k"), ("v", "k3000")):   # dense; an int64 sum
+            other = node["run"]("solo", measure=measure, key=key)
+            assert "float_sum_wait" not in span_names(other)
+    else:
+        assert spans == {"aggregate_wait": [], "float_sum_wait": []}
+        assert counted("segmented") == before
 
 
 @pytest.mark.parametrize("kind", ["solo", "dag"])
