@@ -979,16 +979,21 @@ def open_ctable(rootdir, mode="r", **kw):
     return ctable(rootdir, mode=mode, **kw)
 
 
-def rootdir_cache_key(rootdir):
+def rootdir_cache_key(rootdir, canonical=None):
     """Stat-based identity of a table rootdir, or None when meta.json is
     not stat-able.  st_ino closes the same-mtime rewrite window: meta.json
     is written atomically (tempfile + rename), so every activation yields a
-    fresh inode even when the timestamp granularity would hide the change."""
+    fresh inode even when the timestamp granularity would hide the change.
+    ``canonical``, where the caller already knows ``realpath(rootdir)``
+    (the worker's open), stands in for asking it again; the stat is made
+    either way."""
     try:
         st = os.stat(os.path.join(rootdir, "meta.json"))
     except (OSError, TypeError):
         return None
-    return (os.path.realpath(rootdir), st.st_ino, st.st_mtime_ns)
+    if canonical is None:
+        canonical = os.path.realpath(rootdir)
+    return (canonical, st.st_ino, st.st_mtime_ns)
 
 
 def table_cache_key(table):

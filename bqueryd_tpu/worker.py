@@ -28,6 +28,7 @@ import importlib
 import os
 import signal
 import socket as socket_mod
+import stat
 import sys
 import threading
 import time
@@ -960,6 +961,18 @@ class WorkerNode(WorkerBase):
             )
             for source in ("open", "recomputed")
         }
+        # how the open made each shard's canonical path (``_open_unit``)
+        self._identity_paths = {
+            form: self.metrics.counter(
+                "bqueryd_tpu_identity_path_total",
+                "canonical paths of the shards a unit opened, by form: "
+                "joined = the unit's one realpath of data_dir and the "
+                "shard's name, proven by one lstat; resolved = a realpath "
+                "of the shard (a symlinked shard or a nested name)",
+                labels={"form": form},
+            )
+            for form in ("joined", "resolved")
+        }
         # device-health gauges: read-only snapshots (never launch a probe
         # from a metrics scrape) — operators see the wedge latch and its
         # probe debt wherever they already scrape worker metrics
@@ -1502,10 +1515,7 @@ class WorkerNode(WorkerBase):
         timer = PhaseTimer()
         args, _kwargs = msg.get_args_kwargs()
         filename, groupby_cols, agg_list, where_terms = args[:4]
-        rootdir = os.path.join(self.data_dir, filename)
-        if not os.path.exists(rootdir):
-            raise ValueError(f"Path {rootdir} does not exist")
-        table, identity = self._open_identified(rootdir)
+        (table,), (identity,) = self._open_unit([filename])
         dag = None
         if msg.get("dag"):
             dag = dagmod.OperatorDAG.from_wire(msg.get_from_binary("dag"))
@@ -1799,7 +1809,7 @@ class WorkerNode(WorkerBase):
             self.chunks_skipped_total.inc(skipped)
             self._last_chunk_prune = (decoded, skipped)
 
-    def _open_identified(self, rootdir):
+    def _open_identified(self, rootdir, canonical=None):
         """``(table, identity)``.  Table instances are cached by meta
         identity: re-opening per query costs a meta.json parse per shard;
         activation (fresh inode/mtime) misses naturally.  Instances are
@@ -1813,14 +1823,15 @@ class WorkerNode(WorkerBase):
         keeps its entries.  The caller hands it down with the table (result
         cache, delta store, mesh executor).  It is NOT kept on the instance
         or anywhere that outlives the unit: the stat per unit is what makes
-        an activation, a movebcolz or an append miss."""
+        an activation, a movebcolz or an append miss.  ``canonical`` is
+        ``realpath(rootdir)`` where the caller has it (``_open_unit``)."""
         from bqueryd_tpu.storage import ctable
         from bqueryd_tpu.storage.ctable import (
             rootdir_cache_key,
             table_cache_key,
         )
 
-        key = rootdir_cache_key(rootdir)
+        key = rootdir_cache_key(rootdir, canonical)
         self._identity_passes["open"].inc()
         if key is None:
             # no stat-able meta.json: nothing to cache the instance by
@@ -1841,13 +1852,36 @@ class WorkerNode(WorkerBase):
 
     def _open_unit(self, filenames):
         """``(tables, identities)`` of a unit's shard files, in file
-        order: each opened, and its identity read, once."""
+        order: each opened, and its identity read, once.
+
+        The identity's path is the shard's ``realpath``.  Where ``name`` is
+        one plain entry of ``data_dir`` and that entry is not a symlink (a
+        shard ``movebcolz`` moved in), it is ``join(realpath(data_dir),
+        name)``: one ``lstat`` proves it and says the shard exists, and
+        ``data_dir`` is resolved once a unit, kept no longer, so a
+        re-pointed ``data_dir`` shows at the next unit.  Any other shard (a
+        symlink, a nested name, a dangling link, a missing entry) takes
+        ``exists`` and a ``realpath`` of its own."""
+        real_data_dir = os.path.realpath(self.data_dir)
         tables, identities = [], []
         for name in filenames:
             rootdir = os.path.join(self.data_dir, name)
-            if not os.path.exists(rootdir):
+            try:
+                joined = (
+                    name not in ("", ".", "..")
+                    and os.sep not in name
+                    and not stat.S_ISLNK(os.lstat(rootdir).st_mode)
+                )
+            except (OSError, ValueError):
+                joined = False   # missing, or no path: ``exists`` says so
+            if joined:
+                canonical, form = os.path.join(real_data_dir, name), "joined"
+            elif os.path.exists(rootdir):
+                canonical, form = None, "resolved"
+            else:
                 raise ValueError(f"Path {rootdir} does not exist")
-            table, identity = self._open_identified(rootdir)
+            self._identity_paths[form].inc()
+            table, identity = self._open_identified(rootdir, canonical)
             tables.append(table)
             identities.append(identity)
         return tables, tuple(identities)

@@ -10,12 +10,19 @@ delta store's key and the mesh executor's cache keys.  Pinned here:
 * the value handed down is ``table_cache_key(table)`` letter for letter,
   so no cache key changes in value;
 * nothing is kept between units: a meta.json rewrite misses, an append is
-  delta-served from its tail, an unchanged repeat hits the result cache.
+  delta-served from its tail, an unchanged repeat hits the result cache;
+* (PR 39) the pass makes the shard's canonical path from the unit's one
+  ``realpath`` of ``data_dir`` and one ``lstat`` a plain shard
+  (``bqueryd_tpu_identity_path_total{form="joined"}``), and a symlinked or
+  nested shard's by its own ``realpath`` (``form="resolved"``), to the
+  same value.
 """
 
+import collections
 import importlib
 import logging
 import os
+import threading
 
 import numpy as np
 import pandas as pd
@@ -45,15 +52,33 @@ def _frame(rng, n, offset=0):
 
 
 @pytest.fixture
-def node(tmp_path, monkeypatch):
-    """One calc worker driven directly (no loop thread, so no heartbeat
-    opens a table behind the test's back) over ``SHARDS`` shard files;
-    small tables go by the mesh executor, not by the host kernels."""
+def make_worker(monkeypatch):
+    """Calc workers driven directly (no loop thread, so no heartbeat opens
+    a table behind the test's back); small tables go by the mesh executor,
+    not by the host kernels."""
     from bqueryd_tpu.worker import WorkerNode
 
     monkeypatch.setenv("BQUERYD_TPU_HOST_KERNEL_ROWS", "0")
     monkeypatch.setenv("BQUERYD_TPU_WARMUP", "0")
     monkeypatch.delenv("BQUERYD_TPU_PROFILE", raising=False)
+    made = []
+
+    def make(data_dir):
+        made.append(WorkerNode(
+            coordination_url=f"mem://identity-{os.urandom(4).hex()}",
+            data_dir=str(data_dir), loglevel=logging.WARNING,
+            restart_check=False,
+        ))
+        return made[-1]
+
+    yield make
+    for worker in made:
+        worker.socket.close()
+
+
+@pytest.fixture
+def node(tmp_path, make_worker):
+    """One calc worker over ``SHARDS`` shard files."""
     rng = np.random.default_rng(37)
     names, frames = [], []
     for i in range(SHARDS):
@@ -62,14 +87,8 @@ def node(tmp_path, monkeypatch):
         ctable.fromdataframe(
             frames[-1], str(tmp_path / names[-1]), chunklen=500
         )
-    worker = WorkerNode(
-        coordination_url=f"mem://identity-{os.urandom(4).hex()}",
-        data_dir=str(tmp_path), loglevel=logging.WARNING,
-        restart_check=False,
-    )
-    yield {"worker": worker, "names": names, "frames": frames,
-           "root": tmp_path}
-    worker.socket.close()
+    yield {"worker": make_worker(tmp_path), "names": names,
+           "frames": frames, "root": tmp_path}
 
 
 def _message(names, kind="solo", where=None, above=2.5, topk=2):
@@ -114,9 +133,9 @@ def _counting(monkeypatch):
     calls = []
     real = ctable_mod.rootdir_cache_key
 
-    def counted(rootdir):
+    def counted(rootdir, canonical=None):
         calls.append(rootdir)
-        return real(rootdir)
+        return real(rootdir, canonical)
 
     monkeypatch.setattr(ctable_mod, "rootdir_cache_key", counted)
     return calls
@@ -357,3 +376,152 @@ def test_an_append_between_two_units_is_served_from_its_tail(
     fourth = worker.handle_work(_message(names))
     assert fourth["effective_strategy"] == "delta"
     assert worker.delta_refreshes_total.value == 2
+
+
+# -- (d) the canonical path: one realpath a unit, one lstat a plain shard -----
+
+#: how each layout's shard is opened: ``joined`` = ``realpath(data_dir)``
+#: and the name, proven by an lstat; ``resolved`` = a realpath of its own
+LAYOUTS = {
+    "plain": "joined",
+    "symlinked_shard": "resolved",
+    "symlinked_data_dir": "joined",
+    "nested_name": "resolved",
+}
+
+
+def _write_shard(rootdir, seed=39):
+    frame = _frame(np.random.default_rng(seed), ROWS)
+    ctable.fromdataframe(frame, str(rootdir), chunklen=500)
+    return frame
+
+
+def _lay_out(root, layout):
+    """``(data_dir, name)``: a data dir whose one shard, ``name``, is laid
+    out as ``layout`` says."""
+    data_dir = root / "data"
+    if layout == "symlinked_data_dir":
+        (root / "real").mkdir()
+        os.symlink(root / "real", data_dir)
+    else:
+        data_dir.mkdir()
+    name = "x.bcolzs"
+    if layout == "symlinked_shard":
+        (root / "store").mkdir()
+        _write_shard(root / "store" / "x.v1.bcolzs")
+        os.symlink(root / "store" / "x.v1.bcolzs", data_dir / name)
+    elif layout == "nested_name":
+        name = os.path.join("sub", name)
+        (data_dir / "sub").mkdir()
+        _write_shard(data_dir / name)
+    else:
+        _write_shard(data_dir / name)
+    return data_dir, name
+
+
+def _forms(worker):
+    return {form: c.value for form, c in worker._identity_paths.items()}
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_every_layout_hands_down_table_cache_key(
+    tmp_path, make_worker, layout
+):
+    data_dir, name = _lay_out(tmp_path, layout)
+    worker = make_worker(data_dir)
+    (table,), (identity,) = worker._open_unit([name])
+    assert identity == table_cache_key(table)
+    assert identity[0] == os.path.realpath(os.path.join(data_dir, name))
+    assert identity[-1] == ROWS
+    want = {"joined": 0, "resolved": 0}
+    want[LAYOUTS[layout]] = 1
+    assert _forms(worker) == want
+
+
+@pytest.mark.parametrize("repointed", ["shard", "data_dir"])
+def test_a_repointed_link_misses_at_the_next_unit(
+    tmp_path, make_worker, repointed
+):
+    """A shard link re-pointed (resolved form), or the data dir's (joined
+    form: ``data_dir`` is resolved once a unit and kept no longer), between
+    two units: the second opens the new table and answers from it."""
+    layout = {"shard": "symlinked_shard", "data_dir": "symlinked_data_dir"}
+    data_dir, name = _lay_out(tmp_path, layout[repointed])
+    worker = make_worker(data_dir)
+    first = worker.handle_work(_message([name]))
+    assert first["effective_strategy"] not in ("cached", "delta")
+    _, (before,) = worker._open_unit([name])
+
+    if repointed == "shard":
+        target, link = tmp_path / "store" / "x.v2.bcolzs", data_dir / name
+        frame = _write_shard(target, seed=40)
+    else:
+        target, link = tmp_path / "real2", data_dir
+        target.mkdir()
+        frame = _write_shard(target / name, seed=40)
+    os.symlink(target, f"{link}.new")
+    os.replace(f"{link}.new", link)   # swapped in at once
+
+    _, (after,) = worker._open_unit([name])
+    assert after[0] == os.path.realpath(os.path.join(data_dir, name))
+    assert after[0] != before[0]
+    second = worker.handle_work(_message([name]))
+    assert second["effective_strategy"] not in ("cached", "delta")
+    got = _table(second)
+    truth = frame[frame["w"] > 2.5].groupby("k", as_index=False)["v"].sum()
+    np.testing.assert_array_equal(got["k"].to_numpy(), truth["k"].to_numpy())
+    np.testing.assert_array_equal(got["s"].to_numpy(), truth["v"].to_numpy())
+
+
+@pytest.mark.parametrize("absent", ["missing", "dangling"])
+def test_an_absent_shard_raises_as_before(tmp_path, make_worker, absent):
+    data_dir, name = _lay_out(tmp_path, "plain")
+    if absent == "dangling":
+        os.symlink(tmp_path / "gone.bcolzs", data_dir / "y.bcolzs")
+    worker = make_worker(data_dir)
+    with pytest.raises(ValueError) as err:
+        worker._open_unit([name, "y.bcolzs"])
+    rootdir = os.path.join(str(data_dir), "y.bcolzs")
+    assert str(err.value) == f"Path {rootdir} does not exist"
+    assert _forms(worker) == {"joined": 1, "resolved": 0}
+
+
+@pytest.mark.parametrize("kind", ["solo", "dag"])
+def test_a_served_unit_makes_one_realpath_and_two_calls_a_shard(
+    node, monkeypatch, kind
+):
+    """The filesystem calls of a whole served unit over plain shards: the
+    unit's one ``realpath`` (of ``data_dir``, an lstat a component), then
+    an lstat of each shard and the stat of its meta.json.  (A bundle builds
+    its filter masks on the host, and each column read there keys the
+    storage cache by its own exists + stat + realpath: not the open's.)"""
+    worker, names = node["worker"], node["names"]
+    worker.handle_work(_message(names, kind))   # warm, as in (a)
+    calls = collections.Counter()
+    unit_thread = threading.get_ident()   # not a thread left by another test
+
+    def counting(call, real):
+        def counted(*args, **kwargs):
+            if threading.get_ident() == unit_thread:
+                calls[call] += 1
+            return real(*args, **kwargs)
+        return counted
+
+    for call in ("lstat", "stat"):
+        monkeypatch.setattr(os, call, counting(call, getattr(os, call)))
+    monkeypatch.setattr(
+        os.path, "realpath", counting("realpath", os.path.realpath)
+    )
+    os.path.realpath(str(node["root"]))
+    components = calls["lstat"]
+    calls.clear()
+    joined = _forms(worker)["joined"]
+    reply = worker.handle_work(_message(
+        names, kind, **({"topk": 3} if kind == "dag" else {"above": 3.0})
+    ))
+    seen = dict(calls)
+    assert reply["effective_strategy"] not in ("cached", "delta")
+    assert seen.get("realpath") == 1, seen
+    assert seen.get("stat") == SHARDS, seen   # meta.json's, the guarantee
+    assert seen["lstat"] + seen["stat"] <= 2 * SHARDS + components, seen
+    assert _forms(worker) == {"joined": joined + SHARDS, "resolved": 0}
