@@ -1066,6 +1066,7 @@ def _autopsy_split(record):
         "kernel": "kernel",
         "collective_merge": "merge", "d2h_fetch": "merge",
         "bundle_demux": "merge", "reply_serialization": "merge",
+        "reply_absorb": "merge", "reply_encode": "merge",
         "client_deserialize": "merge",
     }
     for name, seconds in (record.get("segments") or {}).items():

@@ -985,10 +985,13 @@ class ControllerNode:
     def _dispatch_wire(self, worker_id, msg):
         """The low-level dispatch seam shared by the primary and hedge
         paths: the controller.dispatch chaos site (drop / duplicate /
-        delay) plus the raw ROUTER send.  Returns False when the envelope
-        was chaos-dropped (recorded here; callers decide whether that
-        means 'lost on the wire' or 'never sent'); zmq.ZMQError from a
-        gone peer propagates to the caller."""
+        delay) plus the raw ROUTER send.  Returns the wall clock at the
+        send, after the envelope's encoding (where the dispatch span ends
+        and the in-flight window starts: the worker may be computing before
+        this call returns), or None when the envelope was chaos-dropped
+        (recorded here; callers decide whether that means 'lost on the
+        wire' or 'never sent'); zmq.ZMQError from a gone peer propagates to
+        the caller."""
         fault = chaos.fire(
             "controller.dispatch",
             worker=worker_id,
@@ -1001,15 +1004,13 @@ class ControllerNode:
                 "chaos_dispatch_dropped",
                 worker=worker_id, token=msg.get("token"),
             )
-            return False
-        self.socket.send_multipart(
-            [worker_id.encode(), msg.to_json().encode()]
-        )
+            return None
+        frames = [worker_id.encode(), msg.to_json().encode()]
+        sent_at = time.time()
+        self.socket.send_multipart(frames)
         if fault is not None and fault.action == "duplicate":
-            self.socket.send_multipart(
-                [worker_id.encode(), msg.to_json().encode()]
-            )
-        return True
+            self.socket.send_multipart(frames)
+        return sent_at
 
     def _send_to_worker(self, worker_id, msg):
         # chaos site controller.dispatch: drop (the envelope "leaves" but
@@ -1017,7 +1018,7 @@ class ControllerNode:
         # duplicate (the worker sees the work twice — reply dedup must
         # hold), delay (handled inside fire)
         try:
-            self._dispatch_wire(worker_id, msg)
+            sent_at = self._dispatch_wire(worker_id, msg) or time.time()
         except zmq.ZMQError as exc:
             self.logger.warning("send to worker %s failed: %s", worker_id, exc)
             self.remove_worker(worker_id)
@@ -1057,7 +1058,7 @@ class ControllerNode:
                 if msg.get("filename") is not None else None,
                 trace_id=(msg.get_trace() or {}).get("trace_id"),
             )
-        self._record_dispatch_span(msg, worker_id)
+        self._record_dispatch_span(msg, worker_id, sent_at=sent_at)
         if worker_id in self.worker_map:
             self.worker_map[worker_id]["busy"] = True
             # a successful dispatch is proof of liveness: the send would have
@@ -1068,13 +1069,14 @@ class ControllerNode:
             self._requeued_tokens.discard(token)
             self.inflight[token] = {
                 "worker": worker_id,
-                "sent_at": time.time(),
+                "sent_at": sent_at,
                 "msg": msg,
                 "parent": msg.get("parent_token"),
                 "retries": msg.get("_retries", 0),
             }
 
-    def _record_dispatch_span(self, msg, worker_id, hedge=False):
+    def _record_dispatch_span(self, msg, worker_id, hedge=False,
+                              sent_at=None):
         """One "dispatch" span per successful send: queue-entry -> send, its
         span_id the CalcMessage's trace hop (the worker's calc span parents
         to it).  Recorded into EVERY live subscriber segment so shared
@@ -1105,7 +1107,7 @@ class ControllerNode:
             excluded = msg.get("_excluded_workers")
             if excluded:
                 tags["excluded"] = list(excluded)
-        now = time.time()
+        now = sent_at if sent_at is not None else time.time()
         span = obs.make_span(
             wire["trace_id"], "dispatch",
             now if hedge else queued_ts,
@@ -1328,7 +1330,7 @@ class ControllerNode:
             # bookkeeping — the next tick may try again, the plan's
             # counters decide)
             try:
-                if not self._dispatch_wire(target, msg):
+                if self._dispatch_wire(target, msg) is None:
                     continue
             except zmq.ZMQError:
                 # gone peer (ROUTER_MANDATORY): cull it like the primary
@@ -1452,8 +1454,11 @@ class ControllerNode:
     # -- inbound demux -----------------------------------------------------
     def handle_in(self, frames):
         self.msg_count_in += 1
+        # the pickup, on the spans' two clocks: where a query's
+        # request_decode and a reply's reply_absorb span start
+        picked_up = (time.time(), time.perf_counter())
         if len(frames) == 3 and frames[1] == b"":
-            self.handle_rpc(frames[0], frames[2])
+            self.handle_rpc(frames[0], frames[2], picked_up)
             return
         if len(frames) == 3:
             try:
@@ -1462,7 +1467,7 @@ class ControllerNode:
                 self.logger.warning("malformed worker reply dropped")
                 return
             msg["data"] = frames[2]
-            self.handle_worker(frames[0], msg)
+            self.handle_worker(frames[0], msg, picked_up)
             return
         if len(frames) == 2:
             try:
@@ -1480,12 +1485,12 @@ class ControllerNode:
                 # there is no client to answer (no token)
                 getattr(self, f"rpc_{msg['payload']}")(msg)
             else:
-                self.handle_worker(frames[0], msg)
+                self.handle_worker(frames[0], msg, picked_up)
             return
         self.logger.warning("dropping %d-frame message", len(frames))
 
     # -- worker messages ---------------------------------------------------
-    def handle_worker(self, sender, msg):
+    def handle_worker(self, sender, msg, picked_up=None):
         worker_id = (
             msg.get("worker_id")
             or (sender.decode() if isinstance(sender, bytes) else sender)
@@ -1741,7 +1746,7 @@ class ControllerNode:
                     )
                     return
                 self._discard_loser(token, worker_id)
-            self.process_worker_result(msg, entry)
+            self.process_worker_result(msg, entry, picked_up)
             if fault is not None and fault.action == "duplicate":
                 # replay the envelope through the sink: definitionally a
                 # duplicate.  A still-open segment counts it at the
@@ -1757,33 +1762,31 @@ class ControllerNode:
                     self.counters["duplicate_replies"] += 1
                 self.process_worker_result(msg, None)
 
-    def _record_inflight_span(self, msg, entry):
-        """The send→reply window as a dispatch span (tag ``wait``): worker
-        spans carve the actual execution out of it at higher sweep
-        priority, so what this span surfaces in an autopsy is the wire /
-        poll-loop transit the controller cannot otherwise see — without
-        it, a fast query's coverage is eaten by gaps no node owns."""
+    def _record_inflight_span(self, msg, entry, picked_up=None):
+        """The send→reply window, up to the reply's pickup, as an
+        ``inflight`` span: worker spans carve the actual execution out of
+        it at higher sweep priority, so what it surfaces in an autopsy (as
+        dispatch) is the wire / poll-loop transit the controller cannot
+        otherwise see — without it, a fast query's coverage is eaten by
+        gaps no node owns.  Its own name keeps it out of the attempts list
+        (the queue-entry dispatch span already represents the attempt)
+        and off the dispatch spans, which hold no worker time."""
         from bqueryd_tpu import obs
 
         wire = msg.get_trace()
         sent_at = (entry or {}).get("sent_at")
         if not wire or sent_at is None or not obs.enabled():
             return
-        now = time.time()
+        now = picked_up[0] if picked_up is not None else time.time()
         new_spans = [
             obs.make_span(
-                wire["trace_id"], "dispatch", sent_at,
+                wire["trace_id"], "inflight", sent_at,
                 max(now - float(sent_at), 0.0),
                 parent_span_id=wire.get("parent_span_id"),
                 node=self.address,
                 tags={
                     "worker": entry.get("worker"),
                     "retries": entry.get("retries", 0),
-                    # attribution charges the uncovered remainder to the
-                    # dispatch segment but keeps it out of the attempts
-                    # list (the queue-entry span already represents the
-                    # attempt)
-                    "wait": True,
                 },
             )
         ]
@@ -1791,20 +1794,14 @@ class ControllerNode:
         if entry.get("hedged") and hedged_at is not None:
             # the hedge duplicate's racing window (hedge dispatch → this
             # reply): surfaces as the hedge_dispatch segment — how long
-            # the query's tail was spent racing two holders.  wait=True
-            # keeps it out of the attempts list (maybe_hedge's marker
-            # span already lists the hedge attempt)
+            # the query's tail was spent racing two holders
             new_spans.append(
                 obs.make_span(
-                    wire["trace_id"], "dispatch", hedged_at,
+                    wire["trace_id"], "inflight", hedged_at,
                     max(now - float(hedged_at), 0.0),
                     parent_span_id=wire.get("parent_span_id"),
                     node=self.address,
-                    tags={
-                        "worker": entry.get("hedged"),
-                        "hedge": True,
-                        "wait": True,
-                    },
+                    tags={"worker": entry.get("hedged"), "hedge": True},
                 )
             )
         for parent in self._work_parents(msg):
@@ -1813,7 +1810,9 @@ class ControllerNode:
                 segment["obs"]["spans"].extend(new_spans)
 
     # -- results sink ------------------------------------------------------
-    def process_worker_result(self, msg, entry=None):
+    def process_worker_result(self, msg, entry=None, picked_up=None):
+        """``picked_up`` (wall, perf_counter): handle_in's pickup of the
+        reply, where its reply_absorb span starts and its inflight ends."""
         parent = msg.get("parent_token")
         token = msg.get("token")
         if token is not None and token in self._append_waiters:
@@ -1849,7 +1848,7 @@ class ControllerNode:
         ):
             # the transient-fault path records its own (failed-tagged)
             # in-flight span inside _requeue
-            self._record_inflight_span(msg, entry)
+            self._record_inflight_span(msg, entry, picked_up)
         if parent is None and not subscribers:
             # single-segment RPC (execute_code, sleep, readfile): a binary
             # data frame is folded into the JSON reply as base64
@@ -1973,7 +1972,9 @@ class ControllerNode:
                 segment["obs"]["spans"].extend(
                     s for s in spans if isinstance(s, dict)
                 )
-            self._maybe_complete_segment(p)
+            self._maybe_complete_segment(
+                p, self._record_reply_absorb(segment, picked_up)
+            )
         if not delivered:
             self.logger.warning("orphaned result for parent %s dropped", parent)
 
@@ -2105,9 +2106,11 @@ class ControllerNode:
                 "orphaned bundle result %s dropped", token
             )
 
-    def _maybe_complete_segment(self, parent):
+    def _maybe_complete_segment(self, parent, began=None):
         """Reply to the client once every requested shard is covered (by a
-        worker payload, a batched group payload, or a plan-time prune)."""
+        worker payload, a batched group payload, or a plan-time prune).
+        ``began`` (wall, perf_counter): where the reply_absorb span ended,
+        and so where reply_encode starts; else the cover check's end."""
         segment = self.rpc_segments.get(parent)
         if segment is None:
             return
@@ -2125,6 +2128,8 @@ class ControllerNode:
             covered |= files
         if not covered.issuperset(segment["filenames"]):
             return
+        if began is None:
+            began = (time.time(), time.perf_counter())
         self.rpc_segments.pop(parent)
         # payloads in requested-filename order (not reply-arrival order):
         # the aggregate=False rows path concatenates payloads client-side,
@@ -2178,15 +2183,31 @@ class ControllerNode:
         if segment.get("compiled"):
             envelope["compiled"] = segment["compiled"]
         reply = pickle.dumps(envelope, protocol=4)
-        self._finish_segment(parent, segment, reply)
+        self._finish_segment(parent, segment, reply, encode_began=began)
 
-    def _finish_segment(self, parent, segment, reply_bytes=None, error=None):
+    def _finish_segment(self, parent, segment, reply_bytes=None, error=None,
+                        encode_began=None):
         """Final reply for a groupby parent + admission slot release.
         ``reply_bytes=None`` finishes silently (a cancelled query whose
         client is no longer waiting — replying would mis-pair with the
-        identity's next request)."""
+        identity's next request).  ``encode_began``: the reply_encode
+        span's start (envelope build, pickle, send to the client)."""
         if reply_bytes is not None:
             self.reply_rpc_raw(segment["client_token"], reply_bytes)
+        if encode_began is not None and segment.get("obs"):
+            from bqueryd_tpu import obs
+
+            if obs.enabled():
+                obs_state = segment["obs"]
+                obs_state["spans"].append(
+                    obs.make_span(
+                        obs_state["trace_id"], "reply_encode",
+                        encode_began[0],
+                        time.perf_counter() - encode_began[1],
+                        parent_span_id=obs_state["qspan_id"],
+                        node=self.address,
+                    )
+                )
         self._finalize_query_obs(parent, segment, error=error)
         ticket = segment.get("admission_ticket")
         if ticket is not None:
@@ -2194,21 +2215,52 @@ class ControllerNode:
             self._ticket_sigs.pop(ticket, None)
             self._admit_ready()
 
-    @staticmethod
-    def _new_obs_state(ctx):
+    def _new_obs_state(self, ctx, picked_up=None):
         """Per-query trace state: the client's context, the controller
         "groupby" span id every query span parents to, the span list the
         timeline is assembled from, and the submit clock the admission
-        span measures against."""
+        span measures against.  ``picked_up`` (wall, perf_counter): where
+        handle_in picked the request up; the request_decode span runs from
+        there to here (msg_factory, the flight record, the verb's checks),
+        before the groupby root, under the client's span."""
         from bqueryd_tpu import obs
 
-        return {
+        decoded = time.perf_counter()
+        state = {
             "trace_id": ctx.trace_id,
             "root_span_id": ctx.span_id,
             "qspan_id": obs.new_id(),
             "spans": [],
             "submitted_ts": time.time(),
         }
+        if picked_up is not None and obs.enabled():
+            state["spans"].append(
+                obs.make_span(
+                    ctx.trace_id, "request_decode", picked_up[0],
+                    decoded - picked_up[1], parent_span_id=ctx.span_id,
+                    node=self.address,
+                )
+            )
+        return state
+
+    def _record_reply_absorb(self, segment, picked_up):
+        """The reply_absorb span: handle_in's pickup of a worker's reply to
+        this segment's completion check.  Returns that instant on both
+        clocks, where reply_encode starts, so the two never overlap."""
+        from bqueryd_tpu import obs
+
+        if picked_up is None or not segment.get("obs") or not obs.enabled():
+            return None
+        now = (time.time(), time.perf_counter())
+        obs_state = segment["obs"]
+        obs_state["spans"].append(
+            obs.make_span(
+                obs_state["trace_id"], "reply_absorb", picked_up[0],
+                now[1] - picked_up[1],
+                parent_span_id=obs_state["qspan_id"], node=self.address,
+            )
+        )
+        return now
 
     def _observe_admission_wait(self, wait_s):
         """Admission's wait hook: queued time before launch."""
@@ -2301,9 +2353,8 @@ class ControllerNode:
         timeline assembly into the trace ring buffer, slow-query check."""
         from bqueryd_tpu import obs
 
-        wall = time.perf_counter() - segment.get(
-            "created_clock", time.perf_counter()
-        )
+        began = (time.time(), time.perf_counter())
+        wall = began[1] - segment.get("created_clock", began[1])
         self.counters["queries_completed"] += 1
         obs_state = segment.get("obs")
         if error is not None:
@@ -2423,6 +2474,16 @@ class ControllerNode:
         )
         if recorded:
             self.counters["slow_queries"] += 1
+        # this function's own span, after the client's reply: it costs the
+        # loop, not the query, and the next request may wait behind it.
+        # Appended to the stored timeline itself, after the put
+        spans.append(
+            obs.make_span(
+                trace_id, "finalize", began[0],
+                time.perf_counter() - began[1],
+                parent_span_id=obs_state["root_span_id"], node=self.address,
+            )
+        )
 
     def abort_parent(self, parent, error_text, reply=True, error_class=None,
                      attempts=None):
@@ -2493,7 +2554,7 @@ class ControllerNode:
             self.others[addr] = info
 
     # -- RPC dispatch ------------------------------------------------------
-    def handle_rpc(self, client, payload):
+    def handle_rpc(self, client, payload, picked_up=None):
         token = binascii.hexlify(client).decode()
         try:
             msg = msg_factory(payload)
@@ -2502,6 +2563,8 @@ class ControllerNode:
             return
         msg["token"] = token
         verb = msg.get("payload")
+        if picked_up is not None and verb in ("groupby", "query"):
+            msg["_picked_up"] = picked_up   # popped by the verb, never sent on
         from bqueryd_tpu import obs
 
         # flight ring: client envelopes (hot path — kill-switch gated; pings
@@ -3141,6 +3204,7 @@ class ControllerNode:
         from bqueryd_tpu import obs
         from bqueryd_tpu import plan as planmod
 
+        picked_up = msg.pop("_picked_up", None)
         args, kwargs = msg.get_args_kwargs()
         if len(args) != 4:
             raise ValueError(
@@ -3186,7 +3250,7 @@ class ControllerNode:
         ctx = obs.TraceContext.from_wire(msg.get_trace())
         if ctx is None:
             ctx = obs.TraceContext.new_root()
-        obs_state = self._new_obs_state(ctx)
+        obs_state = self._new_obs_state(ctx, picked_up)
         msg["_obs"] = obs_state
         plan_start = time.time()
         plan_clock = time.perf_counter()
@@ -3220,13 +3284,14 @@ class ControllerNode:
         from bqueryd_tpu import obs
         from bqueryd_tpu.plan import dag as dagmod
 
+        picked_up = msg.pop("_picked_up", None)
         args, kwargs = msg.get_args_kwargs()
         if len(args) != 1 or not isinstance(args[0], dict):
             raise ValueError("query needs one spec dict argument")
         ctx = obs.TraceContext.from_wire(msg.get_trace())
         if ctx is None:
             ctx = obs.TraceContext.new_root()
-        obs_state = self._new_obs_state(ctx)
+        obs_state = self._new_obs_state(ctx, picked_up)
         msg["_obs"] = obs_state
         plan_start = time.time()
         plan_clock = time.perf_counter()

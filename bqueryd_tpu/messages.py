@@ -218,6 +218,10 @@ ENVELOPE_SCHEMA = {
                        "of a bundle dispatch; rides the envelope so the "
                        "reply (msg.copy) carries its own demux table",
     "_dispatch_queued_ts": "controller-internal: dispatch queue-entry time",
+    "_picked_up": "controller-internal: (wall, perf_counter) of handle_in's "
+                  "pickup of a groupby / query request, where its "
+                  "request_decode span starts; popped by the verb, never "
+                  "sent on",
     "_relayed": "controller-internal: fan-out marker on relayed verbs",
     "_obs": "controller-internal: per-query observability state rider",
 }
@@ -314,8 +318,30 @@ SPAN_SCHEMA = {
     "plan": "logical-plan compilation + rewrites inside rpc_groupby",
     "dispatch": "one dispatch ATTEMPT: queue entry -> worker send; tags "
                 "carry worker/retries/backoff_s/hedge so the attribution "
-                "layer can split out retry_backoff and hedge duplicates",
+                "layer can split out retry_backoff and hedge duplicates "
+                "(tag failed: a failed attempt's send -> failover window)",
+    "inflight": "one answered attempt's send -> the reply's pickup at the "
+                "controller (the worker's calc and the wire both ways); "
+                "tag hedge: the hedge duplicate's race window",
+    "request_decode": "controller: handle_in's pickup of the client's "
+                      "frames -> the query's trace state (msg_factory, "
+                      "the flight record, the verb's checks); before the "
+                      "groupby root",
+    "reply_absorb": "controller: handle_in's pickup of a worker's calc "
+                    "reply -> the segment's completion check (spans, "
+                    "payload and timings folded in)",
+    "reply_encode": "controller: the result envelope's build, pickle and "
+                    "send to the client; ends the groupby root",
+    "finalize": "controller: _finalize_query_obs after the client's reply "
+                "(SLO record, attribution, trace store put, slow-query "
+                "check); appended to the stored timeline",
     "demux": "shared-scan bundle reply demultiplex at the controller",
+    # client-side spans (rpc.py; on a timeline only as rpc.trace returns it
+    # to the process that made the call)
+    "client_encode": "client: the request envelope's build -> its send",
+    "client_decode": "client: the reply's receipt -> the finished result "
+                     "(pickle.loads, ResultPayload.from_bytes, hostmerge, "
+                     "payload_to_dataframe)",
     # worker-side spans (public names)
     "calc": "the worker's root span for one CalcMessage",
     "storage_decode": "raw phase 'open': shard open + column decode",

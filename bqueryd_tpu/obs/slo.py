@@ -61,6 +61,15 @@ SPAN_CATEGORIES = {
     "batch_window": "batch_window_wait",
     "plan": "plan",
     "dispatch": "dispatch",             # backoff_s tag splits retry_backoff
+    "inflight": "dispatch",             # send -> reply pickup (hedge tag:
+                                        # hedge_dispatch)
+    "request_decode": "request_decode",   # before the root: 0 in the sweep
+    "reply_absorb": "reply_absorb",
+    "reply_encode": "reply_encode",
+    "finalize": "finalize",             # after the root: 0 in the sweep
+    # the client's own spans (rpc.py; rpc.trace merges them in)
+    "client_encode": "client_encode",
+    "client_decode": "client_deserialize",
     "demux": "bundle_demux",
     "calc": "worker_other",             # worker residue outside any phase
     "storage_decode": "storage_decode",
@@ -103,7 +112,6 @@ SPAN_CATEGORIES = {
 SYNTHETIC_SEGMENTS = (
     "retry_backoff",        # carved out of dispatch spans via tags.backoff_s
     "hedge_dispatch",       # dispatch spans tagged hedge=True
-    "client_deserialize",   # measured client-side, added by RPC.autopsy()
     "unattributed",         # the honest remainder
 )
 
@@ -134,6 +142,8 @@ SEGMENT_PRIORITY = (
     "mem_sample",
     "table_keys",
     "worker_other",
+    "reply_absorb",
+    "reply_encode",
     "bundle_demux",
     "retry_backoff",
     "hedge_dispatch",
@@ -141,6 +151,9 @@ SEGMENT_PRIORITY = (
     "plan",
     "batch_window_wait",
     "admission_wait",
+    "request_decode",
+    "finalize",
+    "client_encode",
     "client_deserialize",
     "query",
 )
@@ -289,10 +302,6 @@ def attribute(timeline):
         if span.get("name") != "dispatch":
             continue
         tags = span.get("tags") or {}
-        if tags.get("wait"):
-            # the send→reply / hedge-race transit windows (one per reply):
-            # covered time, not attempts of their own
-            continue
         if tags.get("failed"):
             # a failed attempt's in-flight window: an ANNOTATION of the
             # attempt its queue-entry span already represents, folded in

@@ -19,10 +19,12 @@ single-thread lockstep, exactly like the reference client: concurrent
 callers must each hold their own instance (they are cheap — one ping).
 """
 
+import collections
 import logging
 import os
 import pickle
 import random
+import threading
 import time
 
 import zmq
@@ -31,6 +33,31 @@ import bqueryd_tpu
 from bqueryd_tpu import backoff, chaos
 from bqueryd_tpu.coordination import coordination_store
 from bqueryd_tpu.messages import ErrorMessage, RPCMessage, msg_factory
+
+
+#: the verbs whose calls the controller keeps a timeline of
+QUERY_VERBS = ("groupby", "query")
+#: client spans of this process's latest query calls, by trace id: what
+#: ``RPC.trace`` merges into the controller's timeline and ``RPC.autopsy``
+#: reads its client segment from.  Process-wide, since each thread holds
+#: an ``RPC`` of its own; as many as the controller's ring keeps by default
+CALL_SPANS_KEPT = 256
+_call_spans = collections.OrderedDict()
+_call_spans_lock = threading.Lock()
+
+
+def _keep_call_spans(trace_id, spans):
+    with _call_spans_lock:
+        _call_spans[trace_id] = spans
+        while len(_call_spans) > CALL_SPANS_KEPT:
+            _call_spans.popitem(last=False)
+
+
+def call_spans(trace_id):
+    """The client spans this process recorded for one query call (a
+    list, empty when none)."""
+    with _call_spans_lock:
+        return list(_call_spans.get(trace_id, ()))
 
 
 class RPCError(Exception):
@@ -104,11 +131,12 @@ class RPC:
         #: None against a pre-PR-16 controller.
         self.last_call_answer_source = None
         self.last_call_subsumed_from = None
-        #: client-side deserialize+merge wall of the most recent groupby —
-        #: the one segment the controller cannot see; ``autopsy()`` folds it
-        #: into the fetched attribution record
-        self.last_call_client_merge_s = None
-        self._client_merge_by_trace = {}   # trace_id -> seconds (bounded)
+        #: the most recent call's own spans, always on: ``client_encode``
+        #: (the request's build -> its send) and ``client_decode`` (the
+        #: reply's receipt -> the finished result), wall start + duration
+        #: like every span; a query call's are also kept by trace id
+        #: (``call_spans``) for ``trace()`` and ``autopsy()``
+        self.last_call_spans = []
         self.identity = os.urandom(8).hex()
         self.store = coordination_store(
             coordination_url or redis_url or bqueryd_tpu.DEFAULT_COORDINATION_URL
@@ -170,6 +198,7 @@ class RPC:
         # process's elapsed time, and an NTP step mid-call used to make it
         # negative (the reference's quirk, reference bqueryd/rpc.py:128-129)
         started = time.perf_counter()
+        started_ts = time.time()
         if name == "groupby" and self.legacy_merge:
             # the sum-of-shard-means quirk needs per-shard payloads: disable
             # the controller's batched (pre-merged) shard-group dispatch
@@ -191,13 +220,14 @@ class RPC:
         # end-to-end tracing: every call mints a root TraceContext; the
         # controller parents its query spans to it and keeps the assembled
         # timeline retrievable via rpc.trace(rpc.last_trace_id)
-        from bqueryd_tpu.obs.trace import TraceContext
+        from bqueryd_tpu.obs.trace import TraceContext, make_span
 
         ctx = TraceContext.new_root()
         msg.set_trace(ctx)
         self.last_trace_id = ctx.trace_id
         msg.set_args_kwargs(list(args), kwargs)
         wire = msg.to_json().encode()
+        spans = self.last_call_spans = []
         last_error = None
         for attempt in range(1, self.retries + 1):
             self.last_call_attempts = attempt
@@ -213,6 +243,12 @@ class RPC:
                 if fault is not None and fault.action == "disconnect":
                     self._close_socket()
                     raise zmq.ZMQError(zmq.ENOTCONN, "chaos: disconnected")
+                if not spans:   # up to the first send: the send is the wire's
+                    spans.append(make_span(
+                        ctx.trace_id, "client_encode", started_ts,
+                        time.perf_counter() - started,
+                        parent_span_id=ctx.span_id,
+                    ))
                 self.socket.send(wire)
                 timed_out = not self.socket.poll(
                     int(self.timeout * 1000), zmq.POLLIN
@@ -221,6 +257,7 @@ class RPC:
                     timed_out = True  # pretend the reply never arrived
                 if not timed_out:
                     reply = self.socket.recv()
+                    received_ts, received = time.time(), time.perf_counter()
                     try:
                         result = self._parse_reply(name, reply)
                     except RPCBusyError:
@@ -238,6 +275,13 @@ class RPC:
                         )
                         time.sleep(self._backoff_delay(attempt))
                         continue
+                    spans.append(make_span(
+                        ctx.trace_id, "client_decode", received_ts,
+                        time.perf_counter() - received,
+                        parent_span_id=ctx.span_id,
+                    ))
+                    if name in QUERY_VERBS:
+                        _keep_call_spans(ctx.trace_id, spans)
                     self.last_call_duration = time.perf_counter() - started
                     return result
                 last_error = f"timeout after {self.timeout}s"
@@ -320,10 +364,6 @@ class RPC:
             err.error_class = error_class
             err.attempts = attempts
             raise err
-        # client deserialize + merge: the one critical-path segment that
-        # happens after the controller sealed the trace — measured here,
-        # keyed by trace id, folded into autopsy() records on demand
-        merge_clock = time.perf_counter()
         payloads = [ResultPayload.from_bytes(b) for b in envelope["payloads"]]
         self.last_call_timings = envelope.get("timings")
         self.last_call_strategies = envelope.get("strategies")
@@ -336,15 +376,6 @@ class RPC:
         else:
             merged = hostmerge.merge_payloads(payloads)
             result = hostmerge.payload_to_dataframe(merged)
-        self.last_call_client_merge_s = time.perf_counter() - merge_clock
-        if self.last_trace_id:
-            self._client_merge_by_trace[self.last_trace_id] = (
-                self.last_call_client_merge_s
-            )
-            while len(self._client_merge_by_trace) > 32:
-                self._client_merge_by_trace.pop(
-                    next(iter(self._client_merge_by_trace))
-                )
         return result
 
     def _legacy_merge_frames(self, payloads):
@@ -412,19 +443,37 @@ class RPC:
             kwargs["deadline"] = deadline
         return self._rpc("append", (filename, data), kwargs)
 
-    # -- query autopsy -----------------------------------------------------
+    # -- query trace and autopsy -------------------------------------------
+    def trace(self, trace_id=None):
+        """The controller's assembled timeline of one query (None once it
+        fell out of the ring), with the spans this process recorded for the
+        call merged in (``client_encode``, ``client_decode``: the client's
+        part, which the controller cannot see)."""
+        timeline = self._rpc("trace", (trace_id,), {})
+        local = call_spans(trace_id)
+        if isinstance(timeline, dict) and local:
+            timeline["spans"] = sorted(
+                list(timeline.get("spans") or []) + local,
+                key=lambda s: s.get("start_ts", 0.0),
+            )
+        return timeline
+
     def autopsy(self, trace_id=None):
         """The attributed critical-path breakdown for one query (default:
         the controller's newest trace): named non-overlapping segments,
         coverage accounting, per-attempt dispatch history.  When this
-        client executed the query's merge itself (the usual groupby path),
-        the locally measured ``client_deserialize`` segment — invisible to
-        the controller, which seals the trace before the client unpickles —
-        is folded in and the coverage recomputed over the extended wall."""
+        process made the call (the usual groupby path), its
+        ``client_decode`` span — invisible to the controller, which seals
+        the trace before the client unpickles — is folded in as the
+        ``client_deserialize`` segment and the coverage recomputed over the
+        extended wall."""
         record = self._rpc("autopsy", (trace_id,) if trace_id else (), {})
         if not isinstance(record, dict):
             return record
-        merge_s = self._client_merge_by_trace.get(record.get("trace_id"))
+        merge_s = sum(
+            s["duration_s"] for s in call_spans(record.get("trace_id"))
+            if s["name"] == "client_decode"
+        )
         if merge_s:
             segments = record.setdefault("segments", {})
             segments["client_deserialize"] = round(merge_s, 6)

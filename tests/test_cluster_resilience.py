@@ -1312,7 +1312,12 @@ def test_duplicated_reply_is_deduped_by_query_token(tmp_path, mem_store_url):
         })
         _, got = _ask_sum(mem_store_url, shards)
         assert got == expected, "duplicated reply must not double-merge"
-        assert controller.counters["duplicate_replies"] >= 1
+        # the replay runs after the first copy completed the query and its
+        # reply went out: the client may return before it is counted
+        wait_until(
+            lambda: controller.counters["duplicate_replies"] >= 1,
+            timeout=10, desc="the duplicate counted",
+        )
     finally:
         chaos.disarm()
         _stop([controller] + workers, threads)

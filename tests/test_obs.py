@@ -419,13 +419,19 @@ def test_trace_waterfall_covers_required_spans(obs_cluster):
     # worker-side phases came along too
     assert {"calc", "storage_decode", "h2d_transfer"} <= names, names
     # parent/child links: every span's parent is another span in the
-    # timeline, except the controller's root "groupby" span whose parent is
-    # the CLIENT's root span (not part of the controller-held timeline)
+    # timeline, except the spans under the CLIENT's root span (not part of
+    # the timeline): the controller's root "groupby" span, its
+    # request_decode before and finalize after it, and the client's own
+    # spans, which rpc.trace() merges in
     by_id = {s["span_id"]: s for s in spans}
     orphans = [
         s for s in spans if s["parent_span_id"] not in by_id
     ]
-    assert [s["name"] for s in orphans] == ["groupby"]
+    assert sorted(s["name"] for s in orphans) == [
+        "client_decode", "client_encode", "finalize", "groupby",
+        "request_decode",
+    ]
+    assert len({s["parent_span_id"] for s in orphans}) == 1
     # chain: kernel -> calc -> dispatch -> groupby
     kernel = next(s for s in spans if s["name"] == "kernel")
     calc = by_id[kernel["parent_span_id"]]

@@ -108,16 +108,17 @@ def test_attribute_splits_backoff_out_of_retry_dispatch():
 
 def test_attribute_hedge_dispatch_tagged():
     """The controller emits a zero-length hedge MARKER at dispatch time
-    (listed in attempts) plus the hedge-race window at reply time (tagged
-    hedge+wait: a segment, not an attempt) — mirror both here."""
+    (listed in attempts) plus the hedge-race window at reply time (an
+    inflight span tagged hedge: a segment, not an attempt) — mirror both
+    here."""
     t0 = 0.0
     record = slo.attribute(timeline([
         span("groupby", t0, 1.0),
         span("dispatch", t0, 0.4, tags={"worker": "w1"}),
         span("dispatch", t0 + 0.4, 0.0,
              tags={"worker": "w2", "hedge": True}),
-        span("dispatch", t0 + 0.4, 0.2,
-             tags={"worker": "w2", "hedge": True, "wait": True}),
+        span("inflight", t0 + 0.4, 0.2,
+             tags={"worker": "w2", "hedge": True}),
         span("calc", t0 + 0.7, 0.3),
     ]))
     assert record["segments"]["hedge_dispatch"] == pytest.approx(0.2)
@@ -586,6 +587,12 @@ def test_slo_classes_and_margins_e2e(slo_cluster):
         slo_cluster["shards"], ["g"], [["v", "sum", "s"]], [],
         deadline=30,
     )
+    # the SLO record and the slow-query entry are the controller's
+    # finalize, which runs after the client's reply was sent
+    wait_until(
+        lambda: controller.slow_queries.entry_for(rpc.last_trace_id),
+        timeout=10, desc="the query's finalize",
+    )
     after = controller.slo.snapshot()
     assert after["interactive"]["queries"] == (
         before["interactive"]["queries"] + 1
@@ -605,8 +612,10 @@ def test_slo_classes_and_margins_e2e(slo_cluster):
         loglevel=logging.WARNING, slo_class="not_a_class",
     )
     rpc2.groupby(slo_cluster["shards"], ["g"], [["v", "sum", "s"]], [])
-    assert controller.slo.snapshot()["default"]["queries"] > (
-        before["default"]["queries"]
+    wait_until(
+        lambda: controller.slo.snapshot()["default"]["queries"]
+        > before["default"]["queries"],
+        timeout=10, desc="the default class's SLO record",
     )
 
 
@@ -722,8 +731,11 @@ def test_bundle_member_shares_scale_slow_query_timings(
     assert controller.counters["plan_bundles"] > bundles_before
     shares = []
     for i in trace_ids:
-        entry = controller.slow_queries.entry_for(trace_ids[i])
-        assert entry is not None
+        # the entry is the controller's finalize, after the reply
+        entry = wait_until(
+            lambda i=i: controller.slow_queries.entry_for(trace_ids[i]),
+            timeout=10, desc="the member's finalize",
+        )
         for timings in entry["phase_timings"].values():
             assert "_member_share" in timings
             shares.append(timings["_member_share"])
@@ -760,5 +772,8 @@ def test_window_flight_events_recorded(slo_cluster, monkeypatch):
     # a solo flush fused nothing
     assert flush["fused"] == 0
     # the staged member's autopsy shows the window wait as its own segment
-    record = controller.build_autopsy(rpc.last_trace_id)
+    record = wait_until(   # stored by the finalize, after the reply
+        lambda: controller.build_autopsy(rpc.last_trace_id),
+        timeout=10, desc="the query's stored timeline",
+    )
     assert "batch_window_wait" in record["segments"]
