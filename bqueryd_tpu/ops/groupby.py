@@ -164,6 +164,50 @@ _DENSE_SUM_GROUPS = 2048
 KERNEL_STRATEGIES = ("auto", "matmul", "scatter", "sort")
 
 
+#: keys a level of the boundary search (:func:`_group_ends`) reads as one
+#: row.  OBSERVED: standalone at 11 010 048 rows x 73 728 groups on a TPU
+#: v5e (PERF.md section 6) the search takes 1.12 ms at 128 (1.07 with
+#: a top level of 672 keys), 1.38 at 256, 1.79 at 512 and 3.13 at 1 024
+#: (one level, 10 752 keys compared at the top), where ``searchsorted``'s
+#: loop took about 13 inside the launch
+_SEARCH_ROW = 128
+
+
+def _group_ends(key_s, n_groups, row=None):
+    """int32[n_groups]: one past the last row of group ``g`` in ``key_s``,
+    the keys sorted ascending, each in ``[0, n_groups]`` (``n_groups`` the
+    rows that count for no group) — ``searchsorted(key_s, arange(n_groups),
+    side="right")`` element for element, read a row of keys at a time.
+
+    The keys are viewed as rows of ``row`` (padded with ``n_groups``, which
+    no group counts), and each row's last key makes the level above, until
+    one row's worth is left; those few keys are compared with every group.
+    Going down, the keys up to ``g`` are ``row`` times the rows whose last
+    key is up to ``g`` — the count the level above gave — plus those of the
+    next row, ONE gathered row a group.  So the levels follow the rows
+    (three at 11 M: 86 016 rows of 128, then 672 keys, then 6), each one
+    gather of contiguous rows, not a loop of dependent gathers of one key
+    a group."""
+    row = _SEARCH_ROW if row is None else row
+    groups = jnp.arange(n_groups, dtype=jnp.int32)[:, None]
+    levels = []
+    keys = key_s
+    while keys.shape[0] > row:
+        n_rows = -(-keys.shape[0] // row)
+        rows = jnp.pad(
+            keys, (0, n_rows * row - keys.shape[0]), constant_values=n_groups
+        ).reshape(n_rows, row)
+        levels.append(rows)
+        keys = lax.index_in_dim(rows, row - 1, axis=1, keepdims=False)
+    count = (keys[None, :] <= groups).sum(axis=1, dtype=jnp.int32)
+    for rows in reversed(levels):
+        # every row before it lies up to g; a count of all of them reads the
+        # last row, whose every key is up to g too
+        at = jnp.minimum(count, rows.shape[0] - 1)
+        count = at * row + (rows[at] <= groups).sum(axis=1, dtype=jnp.int32)
+    return count
+
+
 def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
     """Per-group sums without a wide scatter: sort rows by group code,
     prefix-sum the sorted values in ``acc_dtype``, and difference the prefix
@@ -189,9 +233,7 @@ def _sorted_segment_sum(values, safe, n_groups, acc_dtype=jnp.int64):
     v_s = values[order].astype(acc_dtype)
     prefix = jnp.cumsum(v_s)
     # one past the last row of each group (== prefix index of its total)
-    ends = jnp.searchsorted(
-        codes_s, jnp.arange(n_groups, dtype=codes_s.dtype), side="right"
-    )
+    ends = _group_ends(codes_s, n_groups)
     zero = jnp.zeros(1, acc_dtype)
     bounds = jnp.concatenate([zero, prefix])[ends]
     return jnp.diff(jnp.concatenate([zero, bounds]))
@@ -206,7 +248,9 @@ class _SortedGroups:
     exact prefix sum read at the boundaries and differenced in wrapping 64
     bits — bit-exact mod 2^64 for the full int64 range, like the blocked
     limb scatter it stands in for.  No scatter, no ``arange`` operand, no
-    gather of rows: the only gathers read ``n_groups`` elements.
+    gather of rows: the boundaries are :func:`_group_ends`' one gathered
+    row of 128 sorted keys a group and level, and every other gather reads
+    ``n_groups`` elements.
 
     Rows that do not count (null key, filtered out) are keyed past the last
     group, so they sort off the end where no boundary reads them and no
@@ -234,10 +278,7 @@ class _SortedGroups:
                 (self._key, *self._words), num_keys=1, is_stable=False
             )
             # ends[g]: one past the last sorted row of group g
-            ends = jnp.searchsorted(
-                key_s, jnp.arange(self._n_groups, dtype=jnp.int32),
-                side="right",
-            )
+            ends = _group_ends(key_s, self._n_groups)
             self._sorted = ends, words_s
             self._key_s = key_s
         return self._sorted
@@ -431,7 +472,11 @@ def _int_sums_sort(n, n_groups):
     costs 22.1, 36.9 and 53.2 ms — about 20 ms for the sort and the scans
     plus 0.235 ms per 1 000 groups for the boundary search and the reads
     (21.9 ms at 6 656 groups, 81.1 at 262 144, against 220.4 and 374.4).
-    It still wins at 1 048 576 rows x 73 728 groups, 14 rows a group
+    The search was most of that: the row search (:func:`_group_ends`)
+    reads 1.12 ms at 73 728 groups and 0.64 at 2 304 where
+    ``searchsorted`` read 1.49 at 2 304, and the launch of an int64 sum at
+    11 010 048 rows x 73 728 groups takes 40.7 ms, not 53.0.  The
+    sorted form still wins at 1 048 576 rows x 73 728 groups, 14 rows a group
     (14.8 against 22.2 ms); below that the two are a few ms apart and no
     reading says which is ahead."""
     if jax.default_backend() != "cpu":

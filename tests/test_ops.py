@@ -335,7 +335,8 @@ def test_sorted_form_matches_add_at_bit_for_bit(case):
 def test_sorted_form_lowers_to_one_sort_and_no_scatter():
     """What the form is for, read off the lowered module of a two-sum
     integer query under ``sort``: exactly one sort, whatever the number of
-    sums, no scatter, and no gather but of one element a group."""
+    sums, no scatter, and no gather but of one element or one row of sorted
+    keys a group (the boundary search, ``_group_ends``)."""
     import re
 
     import jax
