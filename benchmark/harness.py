@@ -63,6 +63,11 @@ def load_cell(workload, home=REPO):
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     file = entry["file"]
     config = load_json(os.path.join(home, file))
+    refused = reference.unanswerable(config)
+    if refused:   # refused here, before a shard is written or a process started
+        raise cl.RunFailure(f"{file}: the plain reference has no rule for " + "; ".join(
+            f"the {kind} {name!r} of query {query!r}" for query, kind, name in refused
+        ))
     mix = traffic.read_mix(os.path.join(root, "traffic", cell["traffic"] + ".json"))
 
     def mine(metric):

@@ -2,12 +2,19 @@
 
 A copy of ``chip_smoke.reference_answer``: the same query as a pandas
 groupby over the same seeded frames.  It imports nothing of ``bqueryd_tpu``
-and takes nothing the program made.  ``compare`` returns numbers, each held
-against a limit of its own (the configuration's ``check_limits``):
+and takes nothing the program made.  It answers every aggregation op and
+every filter operator that ``rpc.groupby`` takes, under the program's own
+names (``AGGS``, ``OPS``: copied, not imported), so a configuration's
+queries go unchanged to the program and to the reference; ``unanswerable``
+names what it cannot answer, before a run starts.  ``compare`` returns
+numbers, each held against a limit of its own (the configuration's
+``check_limits``):
 
-``int_mismatch``  group keys and int64 aggregates that are not bit for bit
-                  the reference's (a missing or extra group counts too)
-``f32_mean_rel``  widest relative gap of a mean over a float32 column
+``int_mismatch``  group keys, integer and datetime aggregates (every count
+                  among them) that are not bit for bit the reference's (a
+                  missing or extra group counts too)
+``f32_mean_rel``  widest relative gap of a float aggregate over a float32
+                  column (a mean, a sum, an extreme)
 ``f64_mean_rel``  the same over a float64 column
 ``unanswered``    sampled queries of the window that never got an answer
 """
@@ -15,8 +22,67 @@ against a limit of its own (the configuration's ``check_limits``):
 import numpy as np
 import pandas as pd
 
+#: the program's filter operators (``WHERE_OPS`` of ``bqueryd_tpu.ops.predicates``):
+#: a term keeps the rows where ``OPS[op](column, value)`` holds; ``in`` and
+#: ``not in`` take a list
 OPS = {">": np.greater, ">=": np.greater_equal, "<": np.less,
-       "<=": np.less_equal, "==": np.equal, "!=": np.not_equal}
+       "<=": np.less_equal, "==": np.equal, "!=": np.not_equal,
+       "in": np.isin, "not in": lambda values, value: ~np.isin(values, value)}
+
+
+def _count_na(df, gcols, col, file_of):
+    """1 for a null value (NaN, NaT); an integer column has none."""
+    return df[col].isna().to_numpy()
+
+
+def _count_distinct(df, gcols, col, file_of):
+    """1 for the first row of each (keys, value) pair with a value that is
+    not null: the group's sum is its number of distinct values, nulls
+    dropped (pandas' ``nunique``), over every file the query names."""
+    return df[col].notna().to_numpy() & ~df.duplicated(list(gcols) + [col]).to_numpy()
+
+
+def _sorted_count_distinct(df, gcols, col, file_of):
+    """1 where a run of equal values begins: a row whose file, keys or value
+    differ from those of the row before it among the rows that passed the
+    filter (NaN differs from NaN).  So a group's sum is its runs in each
+    file's stored order, added over the files: bquery's
+    ``sorted_count_distinct`` assumes each file sorted, and counts per file."""
+    new = np.ones(len(df), dtype=bool)
+    same = file_of[1:] == file_of[:-1]
+    for column in list(gcols) + [col]:
+        values = df[column].to_numpy()
+        same &= values[1:] == values[:-1]
+    new[1:] = ~same
+    return new
+
+
+#: the program's aggregation ops (``AGG_OPS`` of ``bqueryd_tpu.models.query``),
+#: each to a pandas aggregation of the filtered rows, or to a rule that
+#: flags rows (``rule(df, gcols, col, file_of)``, ``file_of`` the index of
+#: each row's file among the query's) whose flags the group sums
+AGGS = {"sum": "sum", "mean": "mean", "count": "count", "min": "min", "max": "max",
+        "count_na": _count_na, "count_distinct": _count_distinct,
+        "sorted_count_distinct": _sorted_count_distinct}
+#: what the control passes through from the exact reference: nothing is
+#: accumulated, so no precision applies
+CONTROL_EXACT = ("count", "count_na", "count_distinct", "sorted_count_distinct",
+                 "min", "max")
+
+
+def unanswerable(config):
+    """``[(query, "op" | "filter operator", name)]`` for every name in the
+    configuration's queries, and in its slot's term, that the reference
+    has no rule for."""
+    out = []
+    for name, query in config["queries"].items():
+        out += [(name, "op", op) for _col, op, _out in query["aggs"] if op not in AGGS]
+        out += [(name, "filter operator", op) for _col, op, _value in query["where"]
+                if op not in OPS]
+    slot = config.get("slot")
+    if slot and slot["op"] not in OPS:
+        out.append(("the slot", "filter operator", slot["op"]))
+    return out
 
 
 class Reference:
@@ -27,12 +93,16 @@ class Reference:
         self._concat = {}
 
     def _frame(self, files, columns):
+        """The files' rows in the columns asked, one after another, and
+        where each file's rows start."""
         key = (tuple(files), tuple(columns))
         if key not in self._concat:
             if len(self._concat) > 8:
                 self._concat.clear()
-            self._concat[key] = pd.concat(
-                [self.frames[f][list(columns)] for f in files], ignore_index=True
+            parts = [self.frames[f][list(columns)] for f in files]
+            self._concat[key] = (
+                pd.concat(parts, ignore_index=True),
+                np.cumsum([0] + [len(p) for p in parts[:-1]]),
             )
         return self._concat[key]
 
@@ -45,16 +115,27 @@ class Reference:
         columns = list(dict.fromkeys(
             list(gcols) + [a[0] for a in aggs] + [w[0] for w in where]
         ))
-        df = self._frame(files, columns)
+        df, starts = self._frame(files, columns)
         for col, op, value in where:
             df = df[OPS[op](df[col].to_numpy(), value)]
+        rules = [(col, AGGS[op], out) for col, op, out in aggs]
+        flagged = [(col, rule, out) for col, rule, out in rules if not isinstance(rule, str)]
+        if flagged:
+            df = df[df[list(gcols)].notna().all(axis=1)]   # a null key is in no group
+            file_of = np.searchsorted(starts, df.index.to_numpy(), side="right") - 1
+            df = df.assign(**{
+                "flags:" + out: rule(df, gcols, col, file_of).astype(np.int64)
+                for col, rule, out in flagged
+            })
+        named = {out: (col, rule) if isinstance(rule, str) else ("flags:" + out, "sum")
+                 for col, rule, out in rules}
+        exact = df.groupby(list(gcols), as_index=False).agg(**named)
         if accumulate is None:
-            named = {out: (col, op) for col, op, out in aggs}
-            return df.groupby(list(gcols), as_index=False).agg(**named)
-        return _lower_precision_groupby(df, gcols, aggs, np.dtype(accumulate))
+            return exact
+        return _lower_precision_groupby(df, gcols, aggs, np.dtype(accumulate), exact)
 
 
-def _lower_precision_groupby(df, gcols, aggs, dtype):
+def _lower_precision_groupby(df, gcols, aggs, dtype, exact):
     df = df.sort_values(list(gcols), kind="stable")
     keys = df[list(gcols)].to_numpy()
     first = np.ones(len(df), dtype=bool)
@@ -64,8 +145,8 @@ def _lower_precision_groupby(df, gcols, aggs, dtype):
     out = {c: df[c].to_numpy()[starts] for c in gcols}
     counts = (ends - starts + 1).astype(np.int64)
     for col, op, name in aggs:
-        if op == "count":
-            out[name] = counts
+        if op in CONTROL_EXACT:
+            out[name] = exact[name].to_numpy()
             continue
         source = df[col].to_numpy()
         running = np.cumsum(source.astype(dtype), dtype=dtype)
@@ -76,7 +157,7 @@ def _lower_precision_groupby(df, gcols, aggs, dtype):
         elif op == "mean":
             out[name] = sums.astype(np.float64) / counts
         else:
-            raise ValueError(f"the control has no operator {op!r}")
+            raise ValueError(f"the control has no rule for the op {op!r}")
     return pd.DataFrame(out)
 
 
@@ -98,8 +179,8 @@ def compare(args, got, expected, column_dtypes):
             numbers["int_mismatch"] += len(expected)
             continue
         g, e = got[col].to_numpy(), expected[col].to_numpy()
-        if e.dtype.kind in "iu":
-            if g.dtype.kind not in "iu":
+        if e.dtype.kind in "iuM":
+            if g.dtype.kind not in ("iu" if e.dtype.kind in "iu" else "M"):
                 numbers["int_mismatch"] += len(e)
             else:
                 numbers["int_mismatch"] += int(np.count_nonzero(g != e))

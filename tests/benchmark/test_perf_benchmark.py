@@ -25,7 +25,23 @@ from benchmark import data, harness, in_worker, readers, reference, roofline, tr
 BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-TINY = {"taxi-1chip": "taxi-tiny.json", "taxi-4chip": "taxi-tiny4.json"}
+
+
+def tiny_twins():
+    """The tiny twin of each configuration, by its name: the configuration
+    files here that name it under ``twin_of``.  A new configuration's twin
+    is registered by adding its file."""
+    twins = {}
+    for name in sorted(os.listdir(HERE)):
+        if name.endswith(".json"):
+            twin_of = json.load(open(os.path.join(HERE, name))).get("twin_of")
+            if twin_of is not None:
+                assert twin_of not in twins, f"{name} and {twins[twin_of]} both twin {twin_of}"
+                twins[twin_of] = name
+    return twins
+
+
+TINY = tiny_twins()
 
 
 #: a repeating mix (the shipped mixes never repeat): three streams over two
@@ -75,7 +91,6 @@ def tiny_home(tmp_path, extra=None, copy=False):
     swapped for its tiny twin and the cells of ``REHEARSED`` added, beside
     the benchmark's own traffic and metric files."""
     bench = json.loads(json.dumps(BENCH))
-    bench["configs"].append({"name": "taxi-4chip"})
     for config in bench["configs"]:
         config["file"] = os.path.join(HERE, TINY[config["name"]])
     heavy_layers = [m["name"] for m in bench["per_layer"]]
@@ -149,6 +164,19 @@ def test_every_cell_finds_its_files_and_reports_what_the_contract_asks():
         assert metric["moves"] in e2e and set(metric["workloads"]) <= cells
         movers = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
         assert set(metric["workloads"]) <= set(movers.get("workloads", cells))
+
+
+def test_every_configuration_has_a_tiny_twin_that_asks_what_it_asks():
+    """The twin differs in its size alone, so a rehearsal of the twin runs
+    the configuration's own queries under its own limits."""
+    assert set(TINY) >= {c["name"] for c in BENCH["configs"]}
+    for entry in BENCH["configs"]:
+        config = harness.load_json(os.path.join(REPO, entry["file"]))
+        twin = harness.load_json(os.path.join(HERE, TINY[entry["name"]]))
+        for key in ("queries", "columns", "slot", "months", "shards", "chips", "workers"):
+            assert twin[key] == config[key], (entry["name"], key)
+        assert twin["guarantees"]["check_limits"] == config["guarantees"]["check_limits"]
+        assert twin["rows"] < config["rows"]
 
 
 # -- traffic and data ----------------------------------------------------------------

@@ -4,7 +4,7 @@ dollars, at 2 650 and 70 225 groups), and the first of the waiting cells,
 ``taxi-1chip.adhoc-lowcard`` (a mix the benchmark had), which ISSUE 38
 ships only if its six runs are steady enough.  Both are files over what
 the harness had; the dollars configuration's tiny twin is registered by
-``tests/conftest.py`` (a session fixture: ``TINY`` here is not this PR's to edit).  The shipped dollars cell is rehearsed, traced and with
+its own file (``twin_of``, ``test_perf_benchmark.TINY``).  The shipped dollars cell is rehearsed, traced and with
 the float32 control, on the CPU backend at the tiny twin's size.
 
 Only the cell's own ``end_to_end`` is pinned exactly; everything else is
