@@ -915,6 +915,13 @@ class ControllerNode:
         messages (same parent, fresh tokens, retry count carried over)."""
         args, kwargs = msg.get_args_kwargs()
         children = []
+        # a child's payload is one of several: never the whole answer
+        msg = CalcMessage(dict(msg))
+        msg.pop("sole_payload", None)
+        for key in ("plan", "bundle"):
+            if msg.get(key):
+                fragment = dict(msg.get_from_binary(key), sole=False)
+                msg.add_as_binary(key, fragment)
         for filename in msg["filename"]:
             child = CalcMessage(dict(msg))
             child.set_args_kwargs([filename] + list(args[1:]), kwargs)
@@ -2251,16 +2258,19 @@ class ControllerNode:
 
         if picked_up is None or not segment.get("obs") or not obs.enabled():
             return None
-        now = (time.time(), time.perf_counter())
+        # the end on the wall clock is the pickup's plus the elapsed time,
+        # not a second read of time.time(): a thread switched out between
+        # two clock reads would end the span after reply_encode starts
+        now = time.perf_counter()
+        elapsed = now - picked_up[1]
         obs_state = segment["obs"]
         obs_state["spans"].append(
             obs.make_span(
-                obs_state["trace_id"], "reply_absorb", picked_up[0],
-                now[1] - picked_up[1],
+                obs_state["trace_id"], "reply_absorb", picked_up[0], elapsed,
                 parent_span_id=obs_state["qspan_id"], node=self.address,
             )
         )
-        return now
+        return (picked_up[0] + elapsed, now)
 
     def _observe_admission_wait(self, wait_s):
         """Admission's wait hook: queued time before launch."""
@@ -3888,17 +3898,19 @@ class ControllerNode:
                 if deadlines and all(d is not None for d in deadlines)
                 else None
             )
-            sole = len(keep) == 1
             affinity = kwargs0.get("affinity")
-            for group in self._shard_groups(
+            groups = self._shard_groups(
                 keep, groupby_cols, agg_list0, kwargs0
-            ):
+            )
+            # one group holding every kept file: its payload is the answer
+            sole = len(groups) == 1
+            for group in groups:
                 target = group if len(group) > 1 else group[0]
                 for parent in parents:
                     self._count_dispatched_shards(parent, len(group))
                 shard = CalcMessage({"payload": "groupby"})
                 if sole:
-                    shard["sole_shard"] = True
+                    shard["sole_payload"] = True
                 # reference-shaped params carry the FIRST member's query so
                 # _split_batch re-splitting keeps working; the bundle
                 # fragment is authoritative on capable workers (MIGRATION:
@@ -3972,14 +3984,15 @@ class ControllerNode:
         groupby_cols = list(plan.groupby.keys)
         agg_list = plan.physical_agg_list()
         where_terms = plan.where_terms
-        # single-shard queries produce exactly one payload with no merge
-        # downstream: workers may finalize representation-heavy aggregations
-        # (count_distinct) on device instead of shipping mergeable sets
-        sole = len(keep) == 1 and plan.aggregate_rows
+        groups = self._shard_groups(keep, groupby_cols, agg_list, kwargs)
+        # one dispatch group holding every kept file produces exactly one
+        # payload with no merge downstream: the worker may finalize
+        # representation-heavy aggregations (count_distinct) on device
+        # instead of shipping mergeable sets.  Two groups never: their
+        # counts would be added, a value held by both counted twice
+        sole = len(groups) == 1 and plan.aggregate_rows
         plan_sig = plan.signature()  # group-invariant: computed once
-        for group in self._shard_groups(
-            keep, groupby_cols, agg_list, kwargs
-        ):
+        for group in groups:
             target = group if len(group) > 1 else group[0]
             self._count_dispatched_shards(parent_token, len(group))
             # multi-query batching: identical pending work is joined, not
@@ -3998,7 +4011,7 @@ class ControllerNode:
 
             shard = CalcMessage({"payload": "groupby"})
             if sole:
-                shard["sole_shard"] = True
+                shard["sole_payload"] = True
             shard.set_args_kwargs(
                 [target, groupby_cols, agg_list, where_terms],
                 {
@@ -4055,9 +4068,14 @@ class ControllerNode:
         ``plan.dag.groupby_equivalent`` already gates on the part kinds and
         the ``BQUERYD_TPU_DAG_BATCH`` kill switch) — the extended top-k /
         quantile-sketch ops the worker's mesh fast path merges on device.
-        Distinct-count and raw-rows queries keep per-shard dispatch.
-        ``batch=False`` forces the reference's one-message-per-shard
-        behaviour (finer retry granularity).
+        A query whose other ops are ``count_distinct`` is one group only
+        where every file it keeps has one placement, so that one payload is
+        the whole answer (``_dispatch_plan``'s ``sole``); where the
+        placement splits its files, it keeps per-shard dispatch and ships
+        value sets, as raw-rows and ``sorted_count_distinct`` queries
+        always do (a run is counted per file).  ``batch=False`` forces the
+        reference's one-message-per-shard behaviour (finer retry
+        granularity).
         """
         from bqueryd_tpu.models.query import MERGEABLE_OPS, GroupByQuery
         from bqueryd_tpu.plan.dag import is_extended_op
@@ -4068,24 +4086,28 @@ class ControllerNode:
         from bqueryd_tpu.parallel import devicemerge
 
         dag_riding = kwargs.get("dag") is not None
-        batchable = (
+        per_shard = [[f] for f in filenames]
+        if not (
             kwargs.get("batch", True)
             and probe.aggregate
-            and all(
-                op in MERGEABLE_OPS
-                or (dag_riding and is_extended_op(op))
-                for op in probe.ops
-            )
             # BQUERYD_TPU_DEVICE_MERGE=0: the merge stays host-side end to
             # end — per-shard dispatch so every shard's partial table rides
             # the wire and merges via hostmerge (the measurable host-gather
             # baseline the device-resident merge is judged against)
             and devicemerge.device_merge_enabled()
-        )
-        if not batchable:
-            return [[f] for f in filenames]
+        ):
+            return per_shard
         groups = {}
         for f in filenames:
             placement = tuple(sorted(self.files_map.get(f, ())))
             groups.setdefault(placement, []).append(f)
+        mergeable = all(
+            op in MERGEABLE_OPS or (dag_riding and is_extended_op(op))
+            for op in probe.ops
+        )
+        whole = len(groups) == 1 and not dag_riding and all(
+            op in MERGEABLE_OPS or op == "count_distinct" for op in probe.ops
+        )
+        if not (mergeable or whole):
+            return per_shard
         return list(groups.values())

@@ -93,7 +93,9 @@ ENVELOPE_SCHEMA = {
     "parent_token": "client query a shard CalcMessage belongs to",
     "filename": "shard rootdir(s) this work unit covers",
     "affinity": "pin dispatch to one worker id",
-    "sole_shard": "single-shard query: worker may finalize on device",
+    "sole_payload": "the unit holds every file its query keeps, and no "
+                    "other unit does: its payload is the whole answer, so "
+                    "the worker may finalize distinct counts",
     "plan": "base64-pickled plan fragment (query + predicates + strategy)",
     "bundle": "base64-pickled shared-scan bundle fragment: shared shard "
               "group + group-key columns plus one record per member query "
@@ -413,6 +415,11 @@ SPAN_SCHEMA = {
                       "for a launch whose float64 sums took a form other "
                       "than dense (tagged form: segmented) - the device's "
                       "time is then mostly the float sum's",
+    "distinct_wait": "detail span in kernel: what a count_distinct costs - "
+                     "in aggregate_wait the wait for a launch that counts "
+                     "distinct values, on the engine path the count or the "
+                     "value sets of one shard; tagged wire (counts | sets, "
+                     "the payload's form) and rows (the unit's)",
     "send": "detail annotation: the reply's send; its seconds ride the "
             "next calc reply's phase_timings['post_prev']",
     "post": "detail annotation: Done + throttled gc.collect() + RSS check "

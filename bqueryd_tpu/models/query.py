@@ -30,7 +30,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from bqueryd_tpu.utils import devicehealth
+from bqueryd_tpu.utils import devicehealth, tracing
 
 PAYLOAD_FORMAT = "bqueryd-tpu-result-1"
 
@@ -118,10 +118,12 @@ class GroupByQuery:
     where_terms: list = field(default_factory=list)
     aggregate: bool = True
     expand_filter_column: str = None
-    #: controller-set hint: this shard's payload is the WHOLE query (single
-    #: shard fan-out), so no cross-payload merge will happen — count_distinct
-    #: may ship final per-group counts (computed by the device sort kernel)
-    #: instead of the distinct value sets an exact cross-shard union needs
+    #: controller-set hint: this unit's payload is the WHOLE query (one
+    #: dispatch group holds every file it keeps), so no cross-payload merge
+    #: will happen — count_distinct may ship final per-group counts
+    #: (computed by the device sort) instead of the distinct value sets an
+    #: exact cross-shard union needs.  Whoever splits the unit into
+    #: payloads of its own clears it
     sole_payload: bool = False
 
     def signature(self):
@@ -195,6 +197,19 @@ def filter_distinct_part(part, present):
     new_offsets = np.zeros(len(sel) + 1, dtype=np.int64)
     np.cumsum(sel, out=new_offsets[1:])
     return {"distinct_values": values[idx], "distinct_offsets": new_offsets}
+
+
+def distinct_wire(payload):
+    """The form a payload's ``count_distinct`` leaves its worker in:
+    ``"counts"`` (final per-group counts: the payload is the whole answer)
+    or ``"sets"`` (per-group value sets, unioned downstream); None where it
+    holds no distinct count."""
+    if payload.get("kind") != "partials":
+        return None
+    for op, part in zip(payload["ops"], payload["aggs"]):
+        if op == "count_distinct":
+            return "counts" if "distinct" in part else "sets"
+    return None
 
 
 class ResultPayload(dict):
@@ -762,74 +777,17 @@ class QueryEngine:
                     )[:n_groups]
             for i, agg in distinct:
                 in_col, op, _out = agg
-                vals = table.column_raw(in_col)
-                counts = None
-                if (
-                    op == "count_distinct"
-                    and query.sole_payload
-                    # wedged backend: fall through to the host set-shipping
-                    # branch below instead of hanging on the device sort
-                    and not devicehealth.backend_wedged()
-                ):
-                    # single-shard query: this payload IS the final result,
-                    # so the device sort kernel's per-group counts suffice
-                    # (a device radix sort beats host np.unique at scale)
-                    vcodes, vuniques = self._key_codes(table, in_col)
-                    try:
-                        counts = ops.groupby_count_distinct(
-                            dense.astype(np.int32),
-                            np.asarray(vcodes),
-                            ops.program_bucket(n_groups),
-                            # bucketing n_values keeps the composite
-                            # mapping injective (codes < actual < bucket),
-                            # so distinct counts are unchanged while the
-                            # program shape survives cardinality drift
-                            ops.program_bucket(max(len(vuniques), 1)),
-                            mask_arr,
+                if op == "count_distinct":
+                    # what the distinct count costs, counts or sets (the
+                    # worker tags the span with the payload's wire form)
+                    with tracing.detail("distinct_wait", self.timer):
+                        agg_parts[i] = self._distinct_part(
+                            table, query, in_col, dense, n_groups, mask_arr
                         )
-                    except ops.CompositeOverflow:
-                        # (group, value) space past int64: the set-shipping
-                        # branch below answers exactly without packing
-                        pass
-                if counts is not None:
-                    agg_parts[i] = {
-                        "distinct": np.asarray(counts)[:n_groups]
-                    }
-                elif op == "count_distinct":
-                    # ship the per-group distinct VALUE SETS, not counts:
-                    # sets union exactly across shards/workers, where the
-                    # reference's forced-'sum' client merge double-counts
-                    # values that span shards (reference bqueryd/rpc.py:171).
-                    # _key_codes resolves dict-encoded and datetime columns
-                    # to their actual values — per-shard dictionary codes
-                    # live in incompatible code spaces and must never cross
-                    # a shard boundary raw.
-                    vcodes, vuniques = self._key_codes(table, in_col)
-                    values, offsets = _group_distinct_flat(
-                        np.asarray(dense), np.asarray(vcodes),
-                        np.asarray(vuniques), n_groups, mask_arr,
-                    )
-                    # exact cross-shard merge requires shipping the sets, so
-                    # payload size grows with total distinct values (worst
-                    # case ~ the whole column); a configurable cap keeps a
-                    # pathological query from exhausting worker/client memory
-                    limit = int(os.environ.get(
-                        "BQUERYD_TPU_DISTINCT_VALUES_LIMIT", 5_000_000
-                    ))
-                    if limit and len(values) > limit:
-                        raise ValueError(
-                            f"count_distinct on {in_col!r}: {len(values)} "
-                            f"(group, value) pairs exceeds the payload cap "
-                            f"{limit}; raise "
-                            f"BQUERYD_TPU_DISTINCT_VALUES_LIMIT to allow"
-                        )
-                    agg_parts[i] = {
-                        "distinct_values": values,
-                        "distinct_offsets": offsets,
-                    }
                 elif op == "sorted_count_distinct":
                     # run-boundary counts are inherently per-shard (the sort
                     # order is local); cross-shard merge stays additive
+                    vals = table.column_raw(in_col)
                     if devicehealth.backend_wedged():
                         # numpy twin with identical run-leader semantics:
                         # the last device-only op also survives a wedge
@@ -886,6 +844,48 @@ class QueryEngine:
                 value_kinds=[_value_kind_for(table, a[0])
                              for a in query.agg_list],
             )
+
+    def _distinct_part(self, table, query, in_col, dense, n_groups,
+                       mask_arr):
+        """One ``count_distinct`` part: the per-group counts where this
+        payload is the whole answer (``query.sole_payload``), else the
+        per-group distinct value SETS, which union exactly across payloads
+        where added counts would count a value held by two shards twice
+        (the reference's forced-'sum' merge, reference bqueryd/rpc.py:171).
+        ``_key_codes`` resolves dict-encoded and datetime columns to their
+        actual values: per-shard dictionary codes live in incompatible code
+        spaces and never cross a shard boundary raw."""
+        from bqueryd_tpu import ops
+
+        vcodes, vuniques = self._key_codes(table, in_col)
+        # a wedged backend ships the sets instead of hanging on the sort
+        if query.sole_payload and not devicehealth.backend_wedged():
+            try:
+                counts = ops.groupby_count_distinct(
+                    dense.astype(np.int32), np.asarray(vcodes),
+                    ops.program_bucket(n_groups), mask_arr,
+                )
+                return {"distinct": np.asarray(counts)[:n_groups]}
+            except ops.CompositeOverflow:
+                pass  # groups past the int32 sort key: the sets below
+        values, offsets = _group_distinct_flat(
+            np.asarray(dense), np.asarray(vcodes), np.asarray(vuniques),
+            n_groups, mask_arr,
+        )
+        # the sets grow with the distinct values (worst case ~ the whole
+        # column); a configurable cap keeps a pathological query from
+        # exhausting worker/client memory
+        limit = int(os.environ.get(
+            "BQUERYD_TPU_DISTINCT_VALUES_LIMIT", 5_000_000
+        ))
+        if limit and len(values) > limit:
+            raise ValueError(
+                f"count_distinct on {in_col!r}: {len(values)} "
+                f"(group, value) pairs exceeds the payload cap "
+                f"{limit}; raise "
+                f"BQUERYD_TPU_DISTINCT_VALUES_LIMIT to allow"
+            )
+        return {"distinct_values": values, "distinct_offsets": offsets}
 
     def _raw_rows(self, table, query, mask):
         column_list = list(query.groupby_cols) + list(query.in_cols)

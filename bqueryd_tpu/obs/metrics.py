@@ -360,6 +360,28 @@ class MetricsRegistry:
         return problems
 
 
+#: the two forms a ``count_distinct`` payload leaves a worker in
+#: (``models.query.distinct_wire``): the label set of
+#: ``bqueryd_tpu_distinct_payloads_total``, and all of it
+DISTINCT_WIRES = ("counts", "sets")
+
+
+def distinct_payload_counters(registry):
+    """``{wire: Counter}`` of ``bqueryd_tpu_distinct_payloads_total`` in a
+    worker's registry, one per form of :data:`DISTINCT_WIRES`."""
+    return {
+        wire: registry.counter(
+            "bqueryd_tpu_distinct_payloads_total",
+            "calc payloads holding a count_distinct, by the form it "
+            "leaves in: counts = final per-group counts (the payload is "
+            "the whole answer); sets = per-group value sets, unioned "
+            "downstream",
+            labels={"wire": wire},
+        )
+        for wire in DISTINCT_WIRES
+    }
+
+
 def readme_coverage_problems(registries, readme_text):
     """Doc-coverage lint (run from tests alongside :meth:`MetricsRegistry.lint`
     against live node registries): every registered metric family must be

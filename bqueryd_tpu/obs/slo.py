@@ -98,6 +98,7 @@ SPAN_CATEGORIES = {
     "aggregate_launch": "aggregate_launch",
     "aggregate_wait": "aggregate_wait",
     "float_sum_wait": "aggregate_wait",
+    "distinct_wait": "kernel",
     # annotation-only detail names (after the reply / the worker's loop):
     # on no timeline, declared for the lint
     "send": "worker_other",

@@ -255,16 +255,30 @@ class _SortedGroups:
     Rows that do not count (null key, filtered out) are keyed past the last
     group, so they sort off the end where no boundary reads them and no
     operand needs a mask of its own.  Contributions are registered first
-    (:meth:`count`, :meth:`total`; each returns a thunk) and the one sort
-    runs when the first thunk is called, so every reduction of a query
-    shares it."""
+    (:meth:`count`, :meth:`total`, :meth:`distinct`; each returns a thunk)
+    and the one sort runs when the first thunk is called, so every
+    reduction of a query shares it.
+
+    A distinct count makes the sort one of two int32 keys, (group, value):
+    a sort by both is a sort by the group too, so every other reduction
+    rides it unchanged, and no composite of the two is ever packed — no
+    64-bit key and no bound on groups times values."""
 
     def __init__(self, valid, codes, n_groups):
+        if n_groups >= np.iinfo(np.int32).max:
+            # the rows that count for no group are keyed n_groups
+            from bqueryd_tpu.ops.factorize import CompositeOverflow
+
+            raise CompositeOverflow(
+                f"{n_groups} groups exceed the int32 sort key"
+            )
         self._key = jnp.where(valid, codes, n_groups).astype(jnp.int32)
         self._n_groups = n_groups
         self._words = []     # 32-bit operands carried through the sort
+        self._value = None   # the second sort key, a distinct count's codes
         self._sorted = None  # (ends, sorted words), once the sort has run
         self._key_s = None   # the sorted key, for the segmented float sums
+        self._value_s = None  # the sorted second key
 
     def _carry(self, word):
         if self._sorted is not None:
@@ -274,9 +288,14 @@ class _SortedGroups:
 
     def _run(self):
         if self._sorted is None:
-            key_s, *words_s = lax.sort(
-                (self._key, *self._words), num_keys=1, is_stable=False
+            keys = (self._key,) if self._value is None else (
+                self._key, self._value
             )
+            key_s, *words_s = lax.sort(
+                (*keys, *self._words), num_keys=len(keys), is_stable=False
+            )
+            if self._value is not None:
+                self._value_s = words_s.pop(0)
             # ends[g]: one past the last sorted row of group g
             ends = _group_ends(key_s, self._n_groups)
             self._sorted = ends, words_s
@@ -362,6 +381,40 @@ class _SortedGroups:
             if len(slots) == 2:
                 at = at + (self._prefix_at_ends(words[slots[1]]) << 32)
             return jnp.diff(at, prepend=0)
+        return resolve
+
+    def distinct(self, value_codes):
+        """Thunk of the per-group count of distinct values among the valid
+        rows, ``value_codes`` dense int32 codes of the values (negative =
+        null, counted by no group).  The codes become the sort's second
+        key; a sorted row starts a new (group, value) pair where either
+        key differs from the row before it, and each group's count is an
+        int32 prefix of those starts read at its boundaries.  A second
+        distinct count of the same rows takes a sort of its own."""
+        if self._value is not None:
+            return _SortedGroups(
+                self._key < self._n_groups, self._key, self._n_groups
+            ).distinct(value_codes)
+        if self._sorted is not None:
+            raise RuntimeError("contribution registered after the sort ran")
+        self._value = value_codes.astype(jnp.int32)
+
+        def resolve():
+            ends, _ = self._run()
+            key_s, value_s = self._key_s, self._value_s
+            first = jnp.ones(1, jnp.bool_)
+            new_pair = (value_s >= 0) & jnp.concatenate([
+                first,
+                (key_s[1:] != key_s[:-1]) | (value_s[1:] != value_s[:-1]),
+            ])
+            # n < 2^31 rows a dispatch: a flat int32 prefix cannot wrap;
+            # its leading 0 is what the rows before row 0 (and an empty
+            # shard) hold
+            prefix = jnp.concatenate([
+                jnp.zeros(1, jnp.int32),
+                jnp.cumsum(new_pair.astype(jnp.int32)),
+            ])
+            return jnp.diff(prefix[ends], prepend=0).astype(jnp.int64)
         return resolve
 
     def float_total(self, contrib):
@@ -670,7 +723,7 @@ def _matmul_profitable(measures, ops, n, n_groups):
     for the two-row dot from 10 groups to the top of the allowed range:
     PERF.md section 6, PR 31), and the sum itself is
     :func:`_float64_segment_sum` on either route."""
-    if not matmul_route_allowed(n, n_groups):
+    if not matmul_route_allowed(n, n_groups) or "count_distinct" in ops:
         return False
     if not measures:
         return True  # rows-count-only query still benefits
@@ -690,7 +743,7 @@ def _hicard_matmul_profitable(measures, ops, n, n_groups):
     realistic MXU utilization."""
     from bqueryd_tpu.ops import pallas_groupby
 
-    if not pallas_groupby.pallas_enabled():
+    if not pallas_groupby.pallas_enabled() or "count_distinct" in ops:
         return False
     if (
         jax.default_backend() == "cpu"
@@ -731,7 +784,9 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
     codes:    int[n] dense group codes in [0, n_groups); negative = null key
               (row dropped, matching pandas groupby's NaN-key behaviour)
     measures: tuple of value arrays [n], one per aggregation
-    ops:      static tuple of op names aligned with measures (MERGEABLE_OPS)
+    ops:      static tuple of op names aligned with measures (MERGEABLE_OPS,
+              and ``count_distinct``, whose measure is the values' dense
+              int32 codes, negative = null)
     mask:     optional bool[n] row filter (where_terms pushdown)
     null_sentinels: optional tuple aligned with measures; an int entry marks
               that integer value as the measure's missing-data encoding
@@ -749,6 +804,12 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
     takes the MXU route for its counts too (``auto`` and ``"matmul"`` are
     then one traced program) and sums by
     :func:`_float64_segment_sum` on whichever route it goes.
+
+    A ``count_distinct`` takes the scatter entry on every backend, its
+    part ``{"distinct": int64[n_groups]}`` counted by the sort of
+    :meth:`_SortedGroups.distinct`, which the query's other integer
+    reductions ride.  Its counts do not add across tables: only a caller
+    whose rows are the whole answer may ask for one.
 
     ``strategy`` (:data:`KERNEL_STRATEGIES`) forces one route for a test:
     ``"scatter"`` goes straight to the blocked scatters, ``"sort"`` to the
@@ -773,15 +834,11 @@ def partial_tables(codes, measures, ops, n_groups, mask=None,
             )
     if strategy is not None and strategy not in KERNEL_STRATEGIES:
         raise ValueError(f"unknown kernel strategy {strategy!r}")
-    if strategy == "scatter":
+    if strategy in ("scatter", "sort") or "count_distinct" in ops:
         return _partial_tables_scatter(
             codes, measures, ops, int(n_groups), mask,
-            null_sentinels=null_sentinels, force_sort=False,
-        )
-    if strategy == "sort":
-        return _partial_tables_scatter(
-            codes, measures, ops, int(n_groups), mask,
-            null_sentinels=null_sentinels, force_sort=True,
+            null_sentinels=null_sentinels,
+            force_sort={"scatter": False, "sort": True}.get(strategy),
         )
     if _matmul_profitable(measures, ops, int(codes.shape[0]), int(n_groups)):
         # env flags are read HERE, outside jit, so toggling them takes effect
@@ -1039,7 +1096,7 @@ def kernel_route(strategy, measures, ops, n, n_groups):
     n, n_groups = int(n), int(n_groups)
     if strategy == "scatter":
         return "scatter"
-    if strategy == "sort":
+    if strategy == "sort" or "count_distinct" in ops:
         return "sort"
     if _matmul_profitable(measures, tuple(ops), n, n_groups):
         return "matmul"
@@ -1385,7 +1442,9 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
     query (the form follows the backend, read at trace time), True under
     the binding "sort" hint (the sorted form on any backend, float64 sums
     segmented over the same sort) and False under the binding "scatter"
-    hint (the blocked scatters on any backend)."""
+    hint (the blocked scatters on any backend).  A ``count_distinct``
+    (:meth:`_SortedGroups.distinct`) sorts on any backend, and where the
+    form follows the backend the integer reductions ride that sort."""
     valid = codes >= 0
     if mask is not None:
         valid = valid & mask
@@ -1398,7 +1457,8 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
     # (the accelerator reading is the first line of _int_sums_sort; its
     # second, a CPU backend past the budget, is _int64_segment_sum's own)
     sort_ints = (
-        jax.default_backend() != "cpu" if force_sort is None else force_sort
+        jax.default_backend() != "cpu" or "count_distinct" in ops
+        if force_sort is None else force_sort
     )
     sort_floats = force_sort is True
     # the integer reductions below are values in the blocked form and
@@ -1423,14 +1483,17 @@ def _partial_tables_scatter(codes, measures, ops, n_groups, mask=None,
 
     @functools.cache
     def sorted_groups():
-        # a segmented float64 sum rides the integer reductions' one sort;
-        # where they scatter (a binding "scatter" hint on an accelerator)
-        # the float sums share a sort of their own
+        # a segmented float64 sum and a distinct count ride the integer
+        # reductions' one sort; where they scatter (a binding "scatter"
+        # hint) they share a sort of their own
         return by_sort if sort_ints else _SortedGroups(valid, codes, n_groups)
 
     sentinels = _normalize_sentinels(null_sentinels, len(measures))
     aggs = []
     for values, op, sentinel in zip(measures, ops, sentinels):
+        if op == "count_distinct":
+            aggs.append({"distinct": sorted_groups().distinct(values)})
+            continue
         if op not in MERGEABLE_OPS:
             raise ValueError(
                 f"op {op!r} has no mergeable partial; use the dedicated kernel"
@@ -1797,44 +1860,21 @@ def groupby_aggregate(codes, measures, ops, n_groups, mask=None):
     return finalize(partials, ops), partials["rows"]
 
 
-@functools.partial(jax.jit, static_argnames=("n_groups", "n_values"))
-def groupby_count_distinct(codes, value_codes, n_groups, n_values, mask=None):
-    """Distinct-value count per group via sort + boundary detection.
+@functools.partial(jax.jit, static_argnames=("n_groups",))
+def groupby_count_distinct(codes, value_codes, n_groups, mask=None):
+    """Distinct-value count per group, ``int64[n_groups]``: one sort of the
+    rows by two int32 keys, (group, value), and an int32 prefix of the
+    rows that start a new pair read at the group boundaries
+    (:meth:`_SortedGroups.distinct`) — no 64-bit composite, so no bound on
+    groups times values.
 
-    ``value_codes`` are dense codes of the measure values (host-factorized).
-    Static shapes throughout: sort of [n], then a segment_sum of boundary
-    flags.  O(n log n) but bandwidth-friendly on TPU."""
-    from bqueryd_tpu.ops.factorize import (
-        MAX_COMPOSITE,
-        CompositeOverflow,
-        total_cardinality,
-    )
-
-    if total_cardinality((n_groups, n_values)) >= MAX_COMPOSITE:
-        # static args: raises at trace time.  Both factors are bounded by
-        # row count, so this needs ~3e9-row single shards to fire — but a
-        # wrapped (group, value) composite would undercount distincts
-        # silently, which is never acceptable.  The engine degrades to the
-        # distinct-value-set path on this error.
-        raise CompositeOverflow(
-            f"count_distinct composite space {n_groups}x{n_values} "
-            "exceeds int64"
-        )
-    valid = (codes >= 0) & (value_codes >= 0)
+    ``value_codes`` are dense codes of the values (host-factorized; the
+    caller's codes must mean one value wherever they are read together).
+    Null keys, null values (code < 0) and masked-out rows count nowhere."""
+    valid = codes >= 0
     if mask is not None:
         valid = valid & mask
-    composite = jnp.where(
-        valid, codes.astype(jnp.int64) * n_values + value_codes, jnp.int64(-1)
-    )
-    ordered = jnp.sort(composite)
-    first = jnp.concatenate(
-        [jnp.array([True]), ordered[1:] != ordered[:-1]]
-    )
-    is_new = first & (ordered >= 0)
-    group_of = jnp.where(is_new, ordered // n_values, 0).astype(jnp.int32)
-    return jax.ops.segment_sum(
-        is_new.astype(jnp.int64), group_of, num_segments=n_groups
-    )
+    return _SortedGroups(valid, codes, n_groups).distinct(value_codes)()
 
 
 groupby_count_distinct = _obsprofile.instrument(

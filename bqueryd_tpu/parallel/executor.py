@@ -259,8 +259,10 @@ class MeshQueryExecutor:
     device mesh, merging per-shard partials inside the compiled program
     (``parallel.devicemerge``).
 
-    Handles the mergeable aggregation set (``ops.MERGEABLE_OPS``); the worker
-    falls back to per-shard execution for distinct-count ops and raw rows.
+    Handles the mergeable aggregation set (``ops.MERGEABLE_OPS``) and, on a
+    one-device mesh, ``count_distinct`` beside them where the payload is the
+    whole answer (:meth:`takes`); the worker falls back to per-shard
+    execution for every other distinct count and for raw rows.
     """
 
     def __init__(self, mesh=None, axis_name="shards", timer=None,
@@ -368,10 +370,34 @@ class MeshQueryExecutor:
 
     @staticmethod
     def supports(query: GroupByQuery):
+        """Every op merges elementwise across devices and payloads: what
+        the bundle and DAG paths take."""
         from bqueryd_tpu import ops
 
         return query.aggregate and all(
             op in ops.MERGEABLE_OPS for op in query.ops
+        )
+
+    def takes(self, query: GroupByQuery):
+        """What :meth:`execute` serves: :meth:`supports`, and besides a
+        query whose other ops are ``count_distinct`` where its payload is
+        the whole answer (``query.sole_payload``) and the mesh is one
+        device.  A distinct count is then final: per-device or per-payload
+        counts cannot be added (a value held by two counts twice), so a
+        larger mesh, or a payload that is merged with others, ships the
+        engine's value sets instead."""
+        from bqueryd_tpu import ops
+
+        if self.supports(query):
+            return True
+        return (
+            query.aggregate
+            and query.sole_payload
+            and all(
+                op in ops.MERGEABLE_OPS or op == "count_distinct"
+                for op in query.ops
+            )
+            and int(self.mesh.devices.size) == 1
         )
 
     # -- key alignment (host-side, dictionary-sized work only) --------------
@@ -677,10 +703,12 @@ class MeshQueryExecutor:
             # than refuse
             strategy = None
 
-        if not self.supports(query):
+        if not self.takes(query):
             raise ValueError(
-                "MeshQueryExecutor handles mergeable aggregations only; "
-                "route distinct-count / raw-rows queries per shard"
+                "MeshQueryExecutor handles mergeable aggregations, and "
+                "count_distinct only where its one-device payload is the "
+                "whole answer; route other distinct-count / raw-rows "
+                "queries per shard"
             )
         # datetime measures ride the mesh as raw int64 with NaT (int64 min)
         # declared as a null sentinel so NaT rows skip counts and extrema
@@ -731,14 +759,19 @@ class MeshQueryExecutor:
         # fused multi-agg gather: sum+count+mean over the same column pack,
         # upload and feed ONE device block; measure_index maps each agg
         # back to its slot inside the compiled program, so codes are
-        # gathered against each distinct column exactly once
-        unique_cols = list(dict.fromkeys(query.in_cols))
-        measure_index = tuple(
-            unique_cols.index(col) for col in query.in_cols
-        )
+        # gathered against each distinct column exactly once.  A
+        # count_distinct reads its column's value codes, global over the
+        # unit, from a slot of its own: ``(col,)`` beside the column ``col``
+        slot_of = [
+            (col,) if op == "count_distinct" else col
+            for col, op in zip(query.in_cols, query.ops)
+        ]
+        unique_cols = list(dict.fromkeys(slot_of))
+        measure_index = tuple(unique_cols.index(slot) for slot in slot_of)
         missing_cols = [
             col for col in unique_cols
-            if (tables_key, "col", col, n_dev) not in self._hbm_cache
+            if isinstance(col, str)
+            and (tables_key, "col", col, n_dev) not in self._hbm_cache
         ]
         align_warm = (tables_key, cols_key) in self._align_cache
 
@@ -896,7 +929,7 @@ class MeshQueryExecutor:
             # (the two dominate cold latency and use disjoint resources)
             missing = [
                 col
-                for col in unique_cols
+                for col in missing_cols
                 if (tables_key, "col", col, n_dev) not in self._hbm_cache
             ]
             futures = {}
@@ -918,6 +951,11 @@ class MeshQueryExecutor:
                 submit_next()
             measures_d = []
             for col in unique_cols:
+                if not isinstance(col, str):
+                    measures_d.append(self._value_codes_block(
+                        tables, tables_key, col[0], engine, n_dev, sharding
+                    ))
+                    continue
                 mkey = (tables_key, "col", col, n_dev)
                 arr = self._hbm_cache.get(mkey)
                 if arr is None:
@@ -936,8 +974,9 @@ class MeshQueryExecutor:
 
         with self._phase("aggregate"), pipeline.stage("kernel"):
             sentinels = tuple(
-                np.iinfo(np.int64).min if k == "datetime" else None
-                for k in measure_kinds
+                np.iinfo(np.int64).min
+                if k == "datetime" and op != "count_distinct" else None
+                for k, op in zip(measure_kinds, query.ops)
             )
             # returns host numpy partials; with packed fetch (default) the
             # whole merged pytree comes back as ONE device buffer — per-leaf
@@ -983,6 +1022,7 @@ class MeshQueryExecutor:
                         merge_mode=merge_mode,
                         timer=self.timer,
                         float_form=self.last_float_sum,
+                        distinct="count_distinct" in query.ops,
                     )
                     break
                 except jax.errors.JaxRuntimeError as exc:
@@ -1008,6 +1048,37 @@ class MeshQueryExecutor:
                 merged, merge_mode, int(n_dev), query, tables,
                 combos, cards, key_values, measure_kinds,
             )
+
+    def _value_codes_block(self, tables, tables_key, col, engine, n_dev,
+                           sharding):
+        """The device block of a ``count_distinct`` column's value codes,
+        int32 and global over the unit as a group key's are
+        (:meth:`_global_key_space`: each shard's own factorization, the
+        sorted union of the shards' dictionaries, a dictionary-sized
+        local->global map; per-shard codes never cross a shard boundary
+        raw), packed like the group codes and kept in the ``blocks``
+        segment as a measure column is: built once per table set, so a
+        steady query reads it from the device."""
+        from bqueryd_tpu.parallel import pipeline
+
+        key = (tables_key, "distinct", col, n_dev)
+        arr = self._hbm_cache.get(key)
+        if arr is None:
+            self.workingset.evict_under_pressure()
+            with pipeline.stage("align"), tracing.detail(
+                "layout_pack", self.timer
+            ):
+                # a value column's codes are a key's: global over the unit
+                dense = self._global_key_space(
+                    tables, GroupByQuery([col], []), engine
+                )[0]
+                packed = self._pack(dense, n_dev, -1, dtype=np.int32)
+            with pipeline.stage("h2d"), tracing.detail(
+                "layout_h2d", self.timer
+            ):
+                arr = _put(packed, sharding)
+            self._hbm_cache.put(key, arr)
+        return arr
 
     def _collect_payload(self, partial_table, query, tables, combos, cards,
                          key_values, measure_kinds):
@@ -2260,7 +2331,7 @@ def _mesh_bundle_program(mesh, axis, n_groups, in_dtypes, in_width, pack,
 
 
 def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what,
-                  float_form=None):
+                  float_form=None, distinct=False):
     """The ONE packed-fetch scaffold shared by the three mesh fetch paths
     (:func:`_mesh_partials`, :func:`_mesh_bundle_partials`,
     :func:`_mesh_dag_partials`): run the packed program and fetch one byte
@@ -2280,7 +2351,8 @@ def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what,
     caller's layout normalization + merge-byte accounting.  ``float_form``
     (``ops.float_sum_route``, under the profile switch only) names the form
     the launch's float64 sums took: other than ``dense`` it gets the detail
-    span ``float_sum_wait`` inside ``aggregate_wait``."""
+    span ``float_sum_wait`` inside ``aggregate_wait``; ``distinct`` (the
+    launch counts distinct values) the detail span ``distinct_wait``."""
     global _packed_fetch_broken, _packed_transient_count
     import jax
 
@@ -2295,7 +2367,10 @@ def _fetch_merged(run, call, merge_mode, n_dev, finish, timer, latch, what,
                 with tracing.detail("aggregate_launch", timer):
                     out = call(program)
                 with tracing.detail("aggregate_wait", timer):
-                    with _float_sum_wait(float_form, timer):
+                    with _float_sum_wait(float_form, timer), (
+                        tracing.detail("distinct_wait", timer)
+                        if distinct else contextlib.nullcontext()
+                    ):
                         jax.block_until_ready(out)
                 with _fetch_phase(timer):
                     flat = np.asarray(jax.device_get(out))
@@ -2739,7 +2814,8 @@ def _fetch_phase(timer):
 
 def _mesh_partials(mesh, axis, agg_ops, n_groups, codes_d, measures_d,
                    null_sentinels=None, strategy=None, measure_index=None,
-                   merge_mode="psum", timer=None, float_form=None):
+                   merge_mode="psum", timer=None, float_form=None,
+                   distinct=False):
     """Run the mesh program and return the merged partials pytree ON HOST
     (numpy leaves) — fetching one packed buffer when packing is enabled.
     ``measures_d`` holds one device block per DISTINCT measure column;
@@ -2750,7 +2826,8 @@ def _mesh_partials(mesh, axis, agg_ops, n_groups, codes_d, measures_d,
     partials (leaves ``[n_dev, n_groups]``) for the hostmerge fallback.
 
     ``timer``: optional PhaseTimer; the device→host fetch is carved into
-    its own "fetch" phase so attribution can split kernel wall from D2H."""
+    its own "fetch" phase so attribution can split kernel wall from D2H.
+    ``distinct``: the launch counts distinct values (``distinct_wait``)."""
     import jax
 
     from bqueryd_tpu.parallel import devicemerge
@@ -2798,5 +2875,5 @@ def _mesh_partials(mesh, axis, agg_ops, n_groups, codes_d, measures_d,
     return _fetch_merged(
         run, lambda program: program(codes_d, *measures_d), merge_mode,
         n_dev, finish, timer, latch=True, what="query",
-        float_form=float_form,
+        float_form=float_form, distinct=distinct,
     )

@@ -23,6 +23,7 @@ Control-plane behaviour keeps the reference's observable contract:
   a device-memory watermark check the reference has no analogue for.
 """
 
+import dataclasses
 import gc
 import importlib
 import os
@@ -40,6 +41,7 @@ from bqueryd_tpu.utils import devicehealth
 
 import bqueryd_tpu
 from bqueryd_tpu import chaos, messages
+from bqueryd_tpu.obs import metrics as obs_metrics
 from bqueryd_tpu.coordination import chaos_store, coordination_store
 from bqueryd_tpu.messages import (
     BusyMessage,
@@ -973,6 +975,9 @@ class WorkerNode(WorkerBase):
             )
             for form in ("joined", "resolved")
         }
+        self._distinct_payloads = obs_metrics.distinct_payload_counters(
+            self.metrics
+        )
         # device-health gauges: read-only snapshots (never launch a probe
         # from a metrics scrape) — operators see the wedge latch and its
         # probe debt wherever they already scrape worker metrics
@@ -1582,13 +1587,18 @@ class WorkerNode(WorkerBase):
         return reply
 
     def _execute(self, tables, query, timer, identities=None):
-        """Psum-mergeable aggregations (any shard count) -> mesh executor
-        (on-device merge + HBM-resident caches); distinct-count / raw-rows
-        single shard -> single-device engine; other multi-shard shapes ->
-        per-shard engine + host value-keyed merge.  Always returns ONE
-        payload per CalcMessage.  The kernel route is the kernel
-        dispatcher's (``ops.groupby.kernel_route``); what it took is kept
-        in ``_last_effective_strategy`` for the reply.  ``identities``:
+        """Psum-mergeable aggregations (any shard count), and distinct
+        counts where the payload is the whole answer on a one-device mesh
+        (``MeshQueryExecutor.takes``) -> mesh executor (on-device merge +
+        HBM-resident caches); other distinct-count / raw-rows single shard
+        -> single-device engine; other multi-shard shapes -> per-shard
+        engine + host value-keyed merge, each shard's query stripped of
+        ``sole_payload`` (a shard's payload is never the whole answer of a
+        unit of several: its distinct counts would be added to the other
+        shards').  Always returns ONE payload per CalcMessage.  The kernel
+        route is the kernel dispatcher's (``ops.groupby.kernel_route``);
+        what it took is kept in ``_last_effective_strategy`` for the
+        reply.  ``identities``:
         what the unit's open read of each table (``_open_identified``),
         handed on to the mesh executor; None (bare tables) leaves the
         executor to ask the filesystem itself."""
@@ -1646,8 +1656,10 @@ class WorkerNode(WorkerBase):
         # A wedged accelerator backend skips the mesh outright: the engine
         # path below host-routes everything (host_kernel_rows returns its
         # wedged sentinel) instead of hanging on a device dispatch.
-        if not devicehealth.backend_wedged() and MeshQueryExecutor.supports(
-            query
+        if not devicehealth.backend_wedged() and (
+            # a mergeable query asks nothing of the mesh
+            MeshQueryExecutor.supports(query)
+            or self.mesh_executor.takes(query)
         ) and total_rows > host_kernel_rows(
             max(
                 (
@@ -1714,8 +1726,9 @@ class WorkerNode(WorkerBase):
         # deterministic (bit-identical to the serial path)
         from bqueryd_tpu.parallel import pipeline
 
+        shard_query = dataclasses.replace(query, sole_payload=False)
         payloads = pipeline.map_ordered(
-            lambda t: self.engine.execute_local(t, query),
+            lambda t: self.engine.execute_local(t, shard_query),
             tables,
         )
         # shards share one query shape, so the engine's last route speaks
@@ -1899,7 +1912,7 @@ class WorkerNode(WorkerBase):
             return self._handle_bundle(msg)
 
         from bqueryd_tpu import obs
-        from bqueryd_tpu.models.query import GroupByQuery
+        from bqueryd_tpu.models.query import GroupByQuery, distinct_wire
         from bqueryd_tpu.obs import profile as obs_profile
 
         # distributed tracing: phases double as spans (PhaseTimer records
@@ -1933,7 +1946,7 @@ class WorkerNode(WorkerBase):
             dag = None
             if msg.get("dag"):
                 dag = dagmod.OperatorDAG.from_wire(msg.get_from_binary("dag"))
-                dag.sole_payload = bool(msg.get("sole_shard"))
+                dag.sole_payload = bool(msg.get("sole_payload"))
                 query = dag.plain_groupby_query()
             else:
                 # a planning controller ships the compiled plan fragment
@@ -1957,7 +1970,7 @@ class WorkerNode(WorkerBase):
                         expand_filter_column=kwargs.get(
                             "expand_filter_column"
                         ),
-                        sole_payload=bool(msg.get("sole_shard")),
+                        sole_payload=bool(msg.get("sole_payload")),
                     )
                 # round-trip through the DAG layer: compile, then rebuild the
                 # query from the compiled form — the pair is field-exact, so
@@ -2030,6 +2043,17 @@ class WorkerNode(WorkerBase):
                 getattr(self, "_last_float_sum", None)
                 if query is not None else None
             )
+            # the form this payload's distinct counts leave in: final
+            # counts, or value sets for a merge downstream
+            wire = distinct_wire(payload)
+            if wire is not None:
+                self._distinct_payloads[wire].inc()
+                for span in recorder.spans if recorder is not None else ():
+                    if span.get("name") == "distinct_wait":
+                        span.setdefault("tags", {}).update(
+                            wire=wire,
+                            rows=sum(int(t.nrows) for t in tables),
+                        )
             if recorder is not None and effective:
                 # the kernel span carries what the executor actually
                 # compiled post-guards — rpc.trace() waterfalls can now

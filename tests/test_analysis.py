@@ -451,7 +451,7 @@ def test_wire_family_detects_each_violation(tmp_path):
         "controller.py": (
             "def handle(msg):\n"
             "    msg[\"brand_new_key\"] = 1\n"       # undeclared
-            "    msg[\"sole_shard\"] = True\n"       # written, never read here
+            "    msg[\"sole_payload\"] = True\n"     # written, never read here
             "    return msg.get(\"payload\")\n"
         ),
         "worker.py": "def noop(msg):\n    msg[\"payload\"] = \"ok\"\n",
@@ -460,7 +460,7 @@ def test_wire_family_detects_each_violation(tmp_path):
     result = core_run_suite(project=project, analyzers=[WireSchemaAnalyzer()])
     got = rules_of(result)
     assert "wire-undeclared-key" in got
-    assert "wire-one-sided-key" in got       # sole_shard written, never read
+    assert "wire-one-sided-key" in got       # sole_payload written, never read
     assert "wire-dead-key" in got            # e.g. token: declared, untouched
     assert any(
         f.rule == "wire-undeclared-key" and f.symbol == "brand_new_key"
@@ -696,9 +696,9 @@ def test_lockorder_instrumented_pipeline_map_ordered_clean():
 
     recorder = LockOrderRecorder()
     restore_pool = recorder.instrument_module_lock(pipeline, "_pool_lock")
-    clock_wrapped = recorder.instrument_object(
-        pipeline.clock(), prefix="StageClock"
-    )
+    clock = pipeline.clock()
+    clock_locks = dict(vars(clock))
+    clock_wrapped = recorder.instrument_object(clock, prefix="StageClock")
     try:
         assert clock_wrapped
 
@@ -712,6 +712,10 @@ def test_lockorder_instrumented_pipeline_map_ordered_clean():
         assert recorder.cycles() == [], recorder.report()
     finally:
         restore_pool()
+        # the process-wide clock outlives the test: a tracked lock left on
+        # it would walk the stack (and stat every frame's file) on every
+        # pipeline stage of every later test in this process
+        vars(clock).update(clock_locks)
 
 
 # -- root/readme robustness ---------------------------------------------------

@@ -158,6 +158,28 @@ def test_the_controller_children_do_not_overlap(cluster):
         assert named <= root_hi - root_lo + len(intervals) * ROUNDING_S
 
 
+def test_reply_encode_starts_where_reply_absorb_ends_on_the_wall_clock():
+    """The instant ``_record_reply_absorb`` hands to ``reply_encode`` is the
+    absorb span's end, read off the pickup's wall time and the elapsed
+    ``perf_counter``: a second read of ``time.time()`` lies later wherever
+    the thread was switched out between the two reads, and the spans
+    overlapped.  A pickup whose wall stamp is far from ``time.time()``
+    shows it: the handed instant follows the pickup, not the wall clock."""
+    import time
+    from types import SimpleNamespace
+
+    from bqueryd_tpu.controller import ControllerNode
+
+    segment = {"obs": {"trace_id": "t", "qspan_id": "q", "spans": []}}
+    picked_up = (time.time() - 100.0, time.perf_counter() - 0.01)
+    began = ControllerNode._record_reply_absorb(
+        SimpleNamespace(address="controller"), segment, picked_up)
+    (absorb,) = segment["obs"]["spans"]
+    assert absorb["name"] == "reply_absorb"
+    assert began[0] == pytest.approx(_interval(absorb)[1], abs=ROUNDING_S)
+    assert began[0] - picked_up[0] == pytest.approx(began[1] - picked_up[1], abs=1e-6)
+
+
 def test_the_client_keeps_its_spans_for_every_call_and_by_trace_for_queries(cluster):
     rpc = cluster["rpc"]
     rpc.info()

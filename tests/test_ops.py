@@ -461,9 +461,7 @@ def test_count_distinct_matches_pandas():
     df = taxi_like_df()
     gcodes, guniques = fz.factorize(df["payment_type"].to_numpy())
     vcodes, vuniques = fz.factorize(df["passenger_count"].to_numpy())
-    got = gb.groupby_count_distinct(
-        gcodes, vcodes, n_groups=len(guniques), n_values=len(vuniques)
-    )
+    got = gb.groupby_count_distinct(gcodes, vcodes, n_groups=len(guniques))
     expected = df.groupby("payment_type")["passenger_count"].nunique()
     got_series = pd.Series(np.asarray(got), index=guniques).sort_index()
     pd.testing.assert_series_equal(
@@ -1352,13 +1350,15 @@ def test_hicard_kernel_rejects_row_tile_off_the_codes_grid(monkeypatch):
 
 
 def test_count_distinct_refuses_composite_overflow():
+    """The sort keys are int32: groups past them are refused, never
+    wrapped (the engine then ships value sets); values are never packed
+    with the groups, so no bound applies to their product."""
     from bqueryd_tpu import ops
 
-    with pytest.raises(ops.CompositeOverflow, match="exceeds int64"):
+    with pytest.raises(ops.CompositeOverflow, match="int32 sort key"):
         ops.groupby_count_distinct(
             np.zeros(4, dtype=np.int32),
             np.zeros(4, dtype=np.int32),
-            2**32,
             2**32,
         )
 

@@ -103,15 +103,30 @@ def test_vanished_file_aborts_parent_fast(controller):
 
 
 def test_batch_respects_non_mergeable_ops(controller):
+    """Run counts are per file, so a sorted_count_distinct keeps per-shard
+    dispatch.  A count_distinct whose files one worker holds is one group,
+    flagged as the whole answer; where the placement splits its files, per
+    shard, and no payload is flagged."""
     register(controller, "w1", ["a.bcolzs", "b.bcolzs"])
-    msg = RPCMessage({"payload": "groupby", "token": "00"})
-    msg.set_args_kwargs(
-        [["a.bcolzs", "b.bcolzs"], ["k"], [["v", "count_distinct", "v"]], []],
-        {},
-    )
-    controller.rpc_groupby(msg)
-    assert sorted(m["filename"] for m in queued(controller)) == [
-        "a.bcolzs", "b.bcolzs",
+    register(controller, "w2", ["c.bcolzs"])
+
+    def dispatched(files, op):
+        controller.worker_out_messages.clear()
+        msg = RPCMessage({"payload": "groupby", "token": os.urandom(2).hex()})
+        msg.set_args_kwargs([files, ["k"], [["v", op, "v"]], []], {})
+        controller.rpc_groupby(msg)
+        return sorted(
+            (str(m["filename"]), bool(m.get("sole_payload")))
+            for m in queued(controller)
+        )
+
+    ab = ["a.bcolzs", "b.bcolzs"]
+    assert dispatched(ab, "sorted_count_distinct") == [
+        ("a.bcolzs", False), ("b.bcolzs", False),
+    ]
+    assert dispatched(ab, "count_distinct") == [(str(ab), True)]
+    assert dispatched(ab + ["c.bcolzs"], "count_distinct") == [
+        ("a.bcolzs", False), ("b.bcolzs", False), ("c.bcolzs", False),
     ]
 
 
